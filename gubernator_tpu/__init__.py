@@ -26,35 +26,29 @@ if os.environ.get("GUBERNATOR_TPU_X64", "1") != "0":  # pragma: no branch
     jax.config.update("jax_enable_x64", True)
 
 # Persistent XLA compilation cache: daemon warmup precompiles a ladder
-# of batch widths (engine.warmup), and a TPU compile costs 5-40s each —
-# the cache makes every process after the first start in seconds.
+# of batch widths (engine.warmup), so every process after the first
+# starts from cached executables.  Where JAX_COMPILATION_CACHE_DIR is
+# set, jax has already read it and no directory is set here; otherwise
+# the cache lives at <checkout>/.jax_cache, computed from this file's
+# location (the path is part of what makes a cache findable again, so
+# it never depends on $HOME, a pid or the time).  Every program is
+# cached, however quickly it compiled: the warmup ladder is ~75 small
+# programs and a restart should recompile none of them.  On the CPU
+# backend the cache is switched off
+# (platform_guard.disable_cpu_persistent_cache).
 # Opt out with GUBERNATOR_TPU_COMPILE_CACHE=0.
 if os.environ.get("GUBERNATOR_TPU_COMPILE_CACHE", "1") != "0":
     import jax
 
-    # NOTE: the cache is for the multi-second TPU compiles; whenever
-    # the effective backend turns out to be CPU it is switched OFF
-    # (platform_guard.disable_cpu_persistent_cache) — serializing some
-    # XLA:CPU executables segfaults jaxlib's AOT export, and entries
-    # written by a different CPU model abort on load.
-    _repo_root = os.path.dirname(os.path.dirname(__file__))
-    _cache_dir = os.environ.get("GUBERNATOR_TPU_COMPILE_CACHE_DIR") or (
-        os.path.join(_repo_root, ".jax_cache")
-        # Source checkout: cache next to the code.  Installed package:
-        # the parent is site-packages — use the user cache dir instead.
-        if os.path.isdir(os.path.join(_repo_root, ".git"))
-        else os.path.join(
-            os.environ.get("XDG_CACHE_HOME")
-            or os.path.join(os.path.expanduser("~"), ".cache"),
-            "gubernator_tpu",
-            "jax",
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir",
+            os.path.join(
+                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                ".jax_cache",
+            ),
         )
-    )
-    try:
-        jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:  # noqa: BLE001 — older jax without the knobs
-        pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 from gubernator_tpu._version import __version__
 from gubernator_tpu.types import (

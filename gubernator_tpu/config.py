@@ -187,9 +187,7 @@ class Config:
 
 KNOWN_ENV_KNOBS = (
     # Engine / device plane.
-    "GUBER_PLATFORM",         # daemon.py: jax platform override (cpu/tpu)
-    "GUBER_BACKEND_PROBE",    # daemon.py: probe the backend in a subprocess
-    "GUBER_BACKEND_PROBE_TIMEOUT",  # daemon.py: probe wall budget, seconds
+    "GUBER_PLATFORM",         # daemon.py: `cpu` forces the host backend
     "GUBER_PUMP",             # core/engine.py: step-pump mode override
     "GUBER_PUMP_SCAN",        # core/pump.py: fused-scan round loop toggle
     "GUBER_FUSED",            # core/engine.py: fused-step impl select

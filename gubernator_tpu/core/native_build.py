@@ -71,6 +71,20 @@ _EXTRA_SOURCES = {
 }
 
 
+# What serves instead when a library cannot be built — said in the
+# warning, because the Python tier answers correctly and nothing else
+# would tell an operator the native one is gone.
+_TIER_LOST = {
+    "intern_table": "the C++ intern table and its batch schedule(); the "
+    "Python InternTable (a per-key dict walk) serves",
+    "wire_codec": "the native wire decode/encode; every RPC takes the "
+    "protobuf + dataclass path",
+    "h2_server": "the native h2 front, decision plane and columnar "
+    "feeder (GUBER_H2_FAST_ADDRESS cannot be served)",
+    "h2_client": "the native h2 load client (bench/herd modes only)",
+}
+
+
 def ensure_built(stem: str = "intern_table") -> Optional[Path]:
     """Compile `native/<stem>.cpp` (plus any _EXTRA_SOURCES companions)
     if needed; returns the .so path or None on failure."""
@@ -112,8 +126,9 @@ def ensure_built(stem: str = "intern_table") -> Optional[Path]:
     except (subprocess.CalledProcessError, subprocess.TimeoutExpired, OSError) as e:
         detail = getattr(e, "stderr", b"")
         log.warning(
-            "native %s build failed (falling back to Python): %s %s",
+            "native %s build failed — LOST: %s: %s %s",
             stem,
+            _TIER_LOST.get(stem, "this native tier"),
             e,
             detail.decode(errors="replace") if detail else "",
         )

@@ -71,55 +71,17 @@ class Daemon:
 
     # ------------------------------------------------------------------
 
-    def _probe_backend(self) -> None:
-        """Apply the operator platform escape hatch and fail FAST when
-        the accelerator plugin is wedged, instead of hanging backend
-        init forever.
-
-        GUBER_PLATFORM=cpu (honored HERE so every entry point —
-        binary, spawn_daemon, harness — gets it, not just
-        cmd/daemon.py) forces the host backend before any backend
-        touch.  Otherwise, when no backend is initialized yet, probe
-        it in a throwaway subprocess with a hard timeout
-        (platform_guard.probe_backend_subprocess — process-group kill)
-        and raise a clear error naming the escape hatch on failure.
-        GUBER_BACKEND_PROBE=0 disables the probe;
-        GUBER_BACKEND_PROBE_TIMEOUT takes Go-style durations."""
-        import sys
-
+    def _apply_platform_override(self) -> None:
+        """GUBER_PLATFORM=cpu forces the host backend before any
+        backend touch — honored HERE so every entry point (binary,
+        spawn_daemon, harness) gets it, not just cmd/daemon.py.  Any
+        other value leaves the choice to jax (JAX_PLATFORMS).  One
+        process per chip: the backend initializes in this process, and
+        one that cannot start fails with its own error."""
         if os.environ.get("GUBER_PLATFORM", "").lower() == "cpu":
             from gubernator_tpu.platform_guard import force_cpu_platform
 
             force_cpu_platform(self.conf.device_count or None)
-            return
-        if os.environ.get("GUBER_BACKEND_PROBE", "1") == "0":
-            return
-        if "jax" in sys.modules:
-            # Importing jax does NOT initialize a backend (the package
-            # __init__ pulls jax in), so module presence alone must not
-            # skip the probe — but a forced-CPU platform or an
-            # already-initialized backend means there is nothing left
-            # to hang on.
-            import jax
-            from jax._src import xla_bridge
-
-            if (jax.config.jax_platforms or "") == "cpu":
-                return
-            if getattr(xla_bridge, "_backends", None):
-                return
-        from gubernator_tpu.config import _env_float_seconds
-        from gubernator_tpu.platform_guard import probe_backend_subprocess
-
-        timeout = _env_float_seconds(
-            {}, "GUBER_BACKEND_PROBE_TIMEOUT", 120.0
-        )
-        ok, detail = probe_backend_subprocess(timeout)
-        if not ok:
-            raise RuntimeError(
-                f"accelerator backend failed to initialize: {detail}; "
-                "set GUBER_PLATFORM=cpu to serve on the host backend, "
-                "or GUBER_BACKEND_PROBE=0 to wait indefinitely"
-            )
 
     def _build_engine(self):
         if self._engine is not None:
@@ -157,8 +119,9 @@ class Daemon:
         from gubernator_tpu.utils import jit_guard
 
         jit_guard.install()
-        self._probe_backend()
+        self._apply_platform_override()
         engine = self._build_engine()
+        self._log_device(engine)
         self._warmup(engine)
         if self._loader is not None:
             # Restore persisted buckets before serving
@@ -418,6 +381,27 @@ class Daemon:
 
                 record_swallowed("daemon.sweep")
                 log.exception("expiry sweep failed")
+
+    @staticmethod
+    def _log_device(engine) -> None:
+        """Say what this daemon serves on (the same block /debug/vars
+        carries as `device`)."""
+        from gubernator_tpu.core import device_info
+
+        info = device_info.describe(engine)
+        log.info(
+            "serving on platform=%s device_kind=%s devices=%d engine=%s "
+            "rows=%d fused_mode=%s pump=%s pump_scan=%s probes=%s",
+            info["platform"], info["device_kind"], info["device_count"],
+            info["engine"], info["rows"], info["fused_mode"],
+            info["pump"], info["pump_scan"], info["probes"],
+        )
+        if info["cpu_unrequested"]:
+            log.warning(
+                "jax resolved to the CPU backend without having been "
+                "told to (no GUBER_PLATFORM=cpu / JAX_PLATFORMS=cpu): "
+                "decisions are NOT served from an accelerator"
+            )
 
     def _warmup(self, engine) -> None:
         """Pay the kernel jit compiles before serving, not on the first

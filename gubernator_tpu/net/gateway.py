@@ -245,6 +245,12 @@ class _Handler(BaseHTTPRequestHandler):
             "broadcasts": inst.global_mgr.broadcasts,
         }
         out["cache_size"] = inst.engine.cache_size()
+        # What this node serves on: platform, device kind and count,
+        # engine class, step form, pump/scan state and each compile
+        # probe's verdict with its reason (core/device_info.py).
+        from gubernator_tpu.core import device_info
+
+        out["device"] = device_info.describe(inst.engine)
         return out
 
     def _read_json(self, msg):

@@ -21,11 +21,12 @@ the same fill-0 / drop semantics as the XLA gather/scatter flags.
 This is the "Ragged Paged Attention" shape (PAPERS.md): scalar-driven
 irregular access feeding wide vector math.
 
-Backend reality (PERF.md §24): the leaky-bucket math needs f64
-(32.32 fixed-point reconstruction), which Pallas TPU does not lower
-today, so on TPU hardware the compiled probe can fail and the engine
-falls back to the fused XLA program — same single-dispatch shape,
-same math.  In interpret mode (`interpret=True`) the kernel runs as
+Backend reality (PERF.md, "Bring-up on the chip"): the shared lane
+math is int64 and float64, and the TPU lowering refuses 64-bit types
+outright.  The probe (`pallas_step_ok`) records the refusal and
+`GUBER_FUSED=auto` serves the fused XLA program — same
+single-dispatch shape, same math — while `GUBER_FUSED=pallas` raises.
+In interpret mode (`interpret=True`) the kernel runs as
 traced jax ops under jit on ANY backend, which is how CPU CI pins the
 kernel bit-equal to `models/spec.py` (tests/test_fused_parity.py)
 without TPU hardware.  `GUBER_FUSED` selects the mode (core/engine).
@@ -53,9 +54,11 @@ from gubernator_tpu.ops.bucket_kernel import (
     PACKED_OUT_ROWS,
     BucketState,
     GatheredSlots,
+    ProbeVerdict,
     _pack_out,
     _unpack_in,
     encode_slot_values,
+    first_line,
     update_lanes,
 )
 
@@ -168,20 +171,29 @@ def pallas_fused_step(
     return _jitted_step(cap, width, dtypes, interpret)(state, pin)
 
 
-@functools.lru_cache(maxsize=None)
-def pallas_step_ok(cap: int, width: int = 64) -> bool:
-    """Probe whether the COMPILED kernel lowers on this backend (TPU
-    today: no — f64 in the leaky math; the engine then serves the
-    fused XLA program instead).  Interpret mode needs no probe."""
-    try:
-        from gubernator_tpu.ops.bucket_kernel import make_state
+def compile_pallas_step(cap: int, width: int = 64) -> None:
+    """Lower and compile the COMPILED (non-interpret) kernel on the
+    current backend; raises the compiler's own error where it refuses.
+    `GUBER_FUSED=pallas` calls this so a refusal is an error, never a
+    quiet switch to interpret mode."""
+    from gubernator_tpu.ops.bucket_kernel import make_state
 
-        state_sds = jax.eval_shape(lambda: make_state(cap))
-        dtypes = tuple(np.dtype(l.dtype).name for l in state_sds)
-        pin_sds = jax.ShapeDtypeStruct((PACKED_IN_ROWS, width), jnp.int32)
-        _jitted_step(cap, width, dtypes, False).lower(
-            state_sds, pin_sds
-        ).compile()
-        return True
-    except Exception:  # noqa: BLE001 — any lowering failure = no
-        return False
+    state_sds = jax.eval_shape(lambda: make_state(cap))
+    dtypes = tuple(np.dtype(l.dtype).name for l in state_sds)
+    pin_sds = jax.ShapeDtypeStruct((PACKED_IN_ROWS, width), jnp.int32)
+    _jitted_step(cap, width, dtypes, False).lower(
+        state_sds, pin_sds
+    ).compile()
+
+
+@functools.lru_cache(maxsize=None)
+def pallas_step_ok(cap: int, width: int = 64) -> ProbeVerdict:
+    """Probe whether the COMPILED kernel lowers on this backend; the
+    reason on a no is the first line of the compiler's refusal (the
+    engine then serves the fused XLA program and says so).  Interpret
+    mode needs no probe."""
+    try:
+        compile_pallas_step(cap, width)
+    except Exception as e:  # noqa: BLE001 — the refusal is the verdict
+        return ProbeVerdict(False, first_line(e))
+    return ProbeVerdict(True, "compiled")

@@ -215,10 +215,11 @@ class SketchLimiter:
 
         self._lock = threading.Lock()
         # guberlint: shapes pin [rows, W] with W on the sketch pad ladder; depth static
+        def sketch_step(s, pin, cur):
+            return _sketch_step_impl(s, pin, depth, cur)
+
         self._step = jax.jit(
-            lambda s, pin, cur: _sketch_step_impl(s, pin, depth, cur),
-            donate_argnums=(0,),
-            static_argnums=(2,),
+            sketch_step, donate_argnums=(0,), static_argnums=(2,)
         )
         # Host mirrors of the state's window epoch and current plane:
         # apply() triggers the rotation program only when the window

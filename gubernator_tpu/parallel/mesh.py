@@ -12,17 +12,10 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401 — re-exported for the mesh tier
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 KEYS_AXIS = "keys"
-
-# jax.shard_map stabilized out of jax.experimental between minor jax
-# releases; resolve whichever this jax ships so the mesh tier works on
-# both (the CI image carries the experimental-only version).
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - depends on jax version
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def replicated_sharding(mesh: Mesh) -> NamedSharding:

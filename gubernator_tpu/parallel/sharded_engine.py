@@ -35,6 +35,7 @@ from gubernator_tpu.gregorian import (
 from gubernator_tpu.hashing import fnv1a_64, fnv1a_64_batch, pack_keys
 from gubernator_tpu.ops.bucket_kernel import (
     BucketState,
+    fused_step_ok,
     make_state,
 )
 from gubernator_tpu.core.native import make_intern_table
@@ -156,29 +157,27 @@ class ShardedDecisionEngine:
 
         self.readback = ReadbackCombiner()
 
-        if self._single_program:
-            # All shard blocks on one device; the vmapped step keeps
-            # per-shard isolation inside one XLA program.
-            dev0 = next(iter(self.mesh.devices.flat))
-            self._state: BucketState = jax.tree.map(
-                lambda leaf: jax.device_put(
-                    jnp.tile(leaf[None], (self.n_shards, 1)), dev0
-                ),
-                make_state(shard_capacity),
-            )
-        else:
-            state_spec = jax.tree.map(
-                lambda _: keys_sharding(self.mesh), make_state(0)
-            )
-            # Allocate the sharded state: [n_shards, shard_capacity]
-            # blocks, one per mesh device.
-            self._state: BucketState = jax.tree.map(
-                lambda leaf, sh: jax.device_put(
-                    jnp.tile(leaf[None], (self.n_shards, 1)), sh
-                ),
-                make_state(shard_capacity),
-                state_spec,
-            )
+        # Where a [n_shards, ...] array lives: one block per mesh
+        # device (shard_map), or everything on the first device with
+        # the vmapped step keeping per-shard isolation inside one XLA
+        # program (single-program).  The state is allocated THERE —
+        # each shard's block on its own device, never staged whole
+        # through one chip — and every round's packed input is placed
+        # the same way (`_put`), host → owning device.
+        self._placement = (
+            next(iter(self.mesh.devices.flat))
+            if self._single_program
+            else keys_sharding(self.mesh)
+        )
+        # Every field gets its own buffer (the step donates the state).
+        self._state: BucketState = jax.tree.map(
+            lambda leaf: jnp.zeros(
+                (self.n_shards,) + leaf.shape,
+                leaf.dtype,
+                device=self._placement,
+            ),
+            jax.eval_shape(lambda: make_state(shard_capacity)),
+        )
         self._build_step()
 
     # ------------------------------------------------------------------
@@ -215,7 +214,6 @@ class ShardedDecisionEngine:
             _fused_step_core,
             _packed_compute_core,
             _scatter_values,
-            fused_step_ok,
         )
 
         # Packed columnar mesh step (see bucket_kernel PACKED_IN_ROWS):
@@ -314,11 +312,22 @@ class ShardedDecisionEngine:
             ),
             donate_argnums=(0,),
         )
-        # The per-shard program is the same computation as the
-        # single-device fused step, so its copy-insertion behavior
-        # probes identically at shard capacity.
-        self._fused = fused_step_ok(self.shard_capacity)
+        self._select_step()
         self._flat_ok = False  # flat dispatch is single-program-only
+
+    def _select_step(self) -> None:
+        """Fused or split, by the compile probe.  The per-shard
+        program is the same computation as the single-device fused
+        step, so its copy-insertion behavior probes identically at
+        shard capacity."""
+        from gubernator_tpu.core.engine import record_probe
+
+        self.probes: dict = {}
+        self._fused = record_probe(
+            self.probes, "fused_step", fused_step_ok(self.shard_capacity),
+            "the split compute+scatter pair",
+        )
+        self.fused_mode = "xla" if self._fused else "split"
 
     def _build_step_single_program(self):
         """One vmapped XLA program over the [n_shards, ...] leading
@@ -332,7 +341,6 @@ class ShardedDecisionEngine:
             _load_slots_impl,
             _packed_compute_core,
             _scatter_values,
-            fused_step_ok,
         )
 
         self._clear_step = jax.jit(jax.vmap(_clear_occupied_impl))
@@ -355,7 +363,7 @@ class ShardedDecisionEngine:
         self._load_step = jax.jit(
             jax.vmap(_load_slots_impl), donate_argnums=(0,)
         )
-        self._fused = fused_step_ok(self.shard_capacity)
+        self._select_step()
 
         # Flat executors: the hot columnar path globalizes slots
         # (shard*cap + slot) and runs the WHOLE batch as one
@@ -416,6 +424,11 @@ class ShardedDecisionEngine:
     def shard_of(self, key: str) -> int:
         return fnv1a_64(key.encode()) % self.n_shards
 
+    def _put(self, host: np.ndarray) -> jax.Array:
+        """One host buffer with a leading shard axis → device, each
+        shard's block straight to the device that owns it."""
+        return jax.device_put(host, self._placement)
+
     def _apply_shard_clears(self, clears: List[List[int]]) -> None:
         """Eviction clears, one padded [n_shards, csize] scatter.
         `clears[sh]` lists slots to scrub on shard sh."""
@@ -431,7 +444,7 @@ class ShardedDecisionEngine:
         for sh in range(self.n_shards):
             c[sh, : len(clears[sh])] = clears[sh]
         self._state = self._state._replace(
-            meta=self._clear_step(self._state.meta, jnp.asarray(c))
+            meta=self._clear_step(self._state.meta, self._put(c))
         )
         self.dispatches_total += 1
 
@@ -452,7 +465,7 @@ class ShardedDecisionEngine:
                 cols.setdefault(name, []).append(arr)
         rec_stacked = SlotRecord(
             **{
-                name: jnp.asarray(np.stack(arrs))
+                name: self._put(np.stack(arrs))
                 for name, arrs in cols.items()
             }
         )
@@ -684,7 +697,7 @@ class ShardedDecisionEngine:
         import time as _time
 
         t0 = _time.monotonic()
-        pin = jnp.asarray(buf)
+        pin = self._put(buf)
         if self._fused:
             self._state, pout = self._packed_fused(self._state, pin)
             self.dispatches_total += 1
@@ -851,7 +864,7 @@ class ShardedDecisionEngine:
             csize = 16
             cap = self.shard_capacity
             while csize <= max_width:
-                dummy = jnp.asarray(
+                dummy = self._put(
                     np.tile(
                         np.arange(cap, cap + csize, dtype=_I64).astype(_I32),
                         (self.n_shards, 1),
@@ -890,17 +903,16 @@ class ShardedDecisionEngine:
                         # cache keys on input shardings, and a host-
                         # committed dummy would warm a program the
                         # serve path never hits.
-                        pout = jax.device_put(
+                        pout = self._put(
                             np.zeros(
                                 (self.n_shards, PACKED_OUT_ROWS, width),
                                 dtype=np.int32,
-                            ),
-                            keys_sharding(self.mesh),
+                            )
                         )
                         pos = np.full(
                             (self.n_shards, width), n_pad, dtype=_I32
                         )
-                        np.asarray(prog(pout, jnp.asarray(pos)))
+                        np.asarray(prog(pout, self._put(pos)))
                         self.readback.warmup_stacks(
                             (PACKED_OUT_ROWS, n_pad), jnp.int32
                         )
@@ -1479,7 +1491,7 @@ class ShardedDecisionEngine:
             import time as _time
 
             t0 = _time.monotonic()
-            pin = jnp.asarray(buf)
+            pin = self._put(buf)
             if flat:
                 if self._fused:
                     self._state, pout = self._flat_collapsed_fused(
@@ -1605,7 +1617,7 @@ class ShardedDecisionEngine:
         import time as _time
 
         t0 = _time.monotonic()
-        pin = jnp.asarray(buf)
+        pin = self._put(buf)
         if flat:
             if self._fused:
                 self._state, pout = self._flat_fused(self._state, pin)
@@ -1630,7 +1642,7 @@ class ShardedDecisionEngine:
             for sh in range(n_sh):
                 if len(dst_rows[sh]):
                     pos[sh, : len(dst_rows[sh])] = dst_rows[sh]
-            merged = self._merge_prog(n_pad, width)(pout, jnp.asarray(pos))
+            merged = self._merge_prog(n_pad, width)(pout, self._put(pos))
             self.dispatches_total += 1
             self.round_duration.observe(_time.monotonic() - t0)
             return (
@@ -1653,7 +1665,6 @@ class ShardedDecisionEngine:
     def load(self, loader) -> int:
         """Restore a CacheItem stream into the sharded state."""
         from gubernator_tpu.store import LeakyBucketItem, TokenBucketItem
-        from gubernator_tpu.parallel.mesh import keys_sharding
 
         from gubernator_tpu.ops.bucket_kernel import (
             pack_state_host,
@@ -1709,16 +1720,8 @@ class ShardedDecisionEngine:
                     host["burst"][sh, slot] = v.burst
                 count += 1
             packed = pack_state_host(host)
-            placement = (
-                next(iter(self.mesh.devices.flat))
-                if self._single_program
-                else keys_sharding(self.mesh)
-            )
             self._state = BucketState(
-                **{
-                    f: jax.device_put(a, placement)
-                    for f, a in packed.items()
-                }
+                **{f: self._put(a) for f, a in packed.items()}
             )
         return count
 

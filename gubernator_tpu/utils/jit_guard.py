@@ -11,11 +11,17 @@ compiles via jax's monitoring events and exports the count as the
 test_recompile_guard.py) or a production scrape can assert the count
 stays flat after warmup.
 
-The hook is jax's semi-private ``jax._src.monitoring`` listener API
-(the '/jax/core/compile/backend_compile_duration' duration event fires
-once per backend compile, never on cache hits — pinned by a test).
-If the API moves, install() degrades to unavailable and the metric
-reports 0; the guard test skips.
+The hook is jax's semi-private ``jax._src.monitoring`` listener API.
+The '/jax/core/compile/backend_compile_duration' duration event fires
+once per program the backend is asked for, never on in-memory jit
+cache hits — pinned by a test — but it wraps the persistent-cache
+lookup too, so a program loaded from the persistent compilation cache
+still counts.  Three plain events tell those apart: every lookup
+records '/jax/compilation_cache/compile_requests_use_cache', every hit
+'/jax/compilation_cache/cache_hits', and every executable the backend
+really compiled and stored '/jax/compilation_cache/cache_misses' (a
+compile the backend refused is a request that is neither).  A restarted
+daemon on a warm cache shows zero misses (chip_smoke.py asserts it).
 """
 
 from __future__ import annotations
@@ -24,17 +30,34 @@ import threading
 
 _lock = threading.Lock()
 _count = 0  # guberlint: guarded-by _lock
+_by_name: dict = {}  # guberlint: guarded-by _lock
 _installed = False
 _available = False
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# Persistent-cache events by the name cache_stats() reports them under.
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "requests",
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+_cache = dict.fromkeys(_CACHE_EVENTS.values(), 0)  # guberlint: guarded-by _lock
 
 
 def _on_event_duration(event: str, duration: float, **kwargs) -> None:
     global _count
     if event == _COMPILE_EVENT:
+        name = kwargs.get("fun_name", "")
         with _lock:
             _count += 1
+            _by_name[name] = _by_name.get(name, 0) + 1
+
+
+def _on_event(event: str, **kwargs) -> None:
+    name = _CACHE_EVENTS.get(event)
+    if name is not None:
+        with _lock:
+            _cache[name] += 1
 
 
 def install() -> bool:
@@ -53,6 +76,7 @@ def install() -> bool:
         record_swallowed("jit_guard.install")
         return False
     monitoring.register_event_duration_secs_listener(_on_event_duration)
+    monitoring.register_event_listener(_on_event)
     with _lock:
         _available = True
     return True
@@ -67,3 +91,17 @@ def compile_count() -> int:
     """Backend compiles observed since install() (0 if unavailable)."""
     with _lock:
         return _count
+
+
+def cache_stats() -> dict:
+    """Persistent-compilation-cache lookups, hits and misses since
+    install(); `misses` is what the backend really compiled."""
+    with _lock:
+        return dict(_cache)
+
+
+def compiled_programs() -> dict:
+    """Backend compile requests by jitted function name since
+    install() — which program families this process has built."""
+    with _lock:
+        return dict(_by_name)

@@ -6,19 +6,19 @@ opening a dozen files by hand.  This script normalizes them all into
 
   * BENCH_TREND.json — {config: {round: {value, p50_ms, p99_ms,
     dispatches_per_decision, native_answered, platform, file}}}
-  * a config × round markdown table replaced in PERF.md between the
+  * a config × round markdown table replaced in PERF_HISTORY.md between the
     `<!-- bench-trend:begin -->` / `<!-- bench-trend:end -->` markers
     (appended to the end when absent), so the trajectory is readable
     in one screen.
 
 Naming convention handled: BENCH_r06_cpu_herd.json (round r06, config
-herd), BENCH_r04_default.json (no platform tag), BENCH_r01.json (the
-round-1 headline wrapper with n/cmd/rc/parsed — config "default").
+herd), BENCH_r04_default.json (no platform tag), BENCH_r03.json (a
+driver headline wrapper with n/cmd/rc/parsed — config "default").
 A/B companions (*_ledger0, *_native0, *_seedbaseline) keep their
 suffix as part of the config name so each pair shows as two columns.
 
 Usage: python scripts/bench_trend.py [--check]
-  --check: exit 1 if BENCH_TREND.json or the PERF.md table is stale
+  --check: exit 1 if BENCH_TREND.json or the PERF_HISTORY.md table is stale
   (CI can keep the trend honest without rewriting files).
 """
 
@@ -31,7 +31,7 @@ import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TREND_PATH = os.path.join(ROOT, "BENCH_TREND.json")
-PERF_PATH = os.path.join(ROOT, "PERF.md")
+PERF_PATH = os.path.join(ROOT, "PERF_HISTORY.md")
 BEGIN = "<!-- bench-trend:begin -->"
 END = "<!-- bench-trend:end -->"
 

@@ -126,3 +126,22 @@ def test_kernel_matches_spec_single_key_long_stream():
         assert g.remaining == w.remaining, ctx
         assert g.reset_time == w.reset_time, ctx
         clock.advance(ms=rng.choice([0, 1, 2, 500, 1500, 61000]))
+
+
+def test_rate_and_truncation_helpers_match_the_reference():
+    """`rate_int` (exact integer division) and `trunc_i64` (truncate
+    in float first) exist because an accelerator's float64 is not IEEE
+    double; on every backend they must equal Go's int64(float64(..))
+    — the spec's `_trunc` — including the quotient a v5e got wrong
+    (7 per 30 days) and negative operands (toward zero, not floor)."""
+    import jax.numpy as jnp
+
+    from gubernator_tpu.ops.bucket_kernel import rate_int, trunc_i64
+
+    D = [2_592_000_000, 31_536_000_000, 1000, -5, 5, 0, 9000]
+    L = [7, 7, 3, 2, 2, 5, 7]
+    got = rate_int(jnp.asarray(D), jnp.asarray(L), jnp.asarray(True))
+    assert got.tolist() == [int(d / l) for d, l in zip(D, L)]
+    assert rate_int(jnp.asarray(D), jnp.asarray(L), jnp.asarray(False)).sum() == 0
+    v = [3.9999999997671694, -3.9999999997671694, 1000.999999, 0.0, 5.0]
+    assert trunc_i64(jnp.asarray(v)).tolist() == [int(x) for x in v]
