@@ -31,8 +31,11 @@ One process uses the chip at a time: this parent imports the package
 (which imports jax) but never initializes a backend, starts one child
 at a time and waits for it to exit before the next.  Any phase that
 fails raises, so the exit code is non-zero and no result line is
-printed.  The last stdout line of a passing run is one JSON object
-beginning `{"ok": true, "device": {...}}`.
+printed.  A passing run ends with two JSON lines on stdout: the full
+summary (also written to `chiprun_out/chip_smoke/summary.json`), then,
+last, the result line with exactly these keys and the device as jax
+reported it to the daemon:
+`{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}`.
 
 `--rehearse-cpu` is the only way to run it on the CPU: a tiny size,
 `"platform": "cpu"` in the result, for debugging the script itself.
@@ -108,6 +111,20 @@ def say(msg: str) -> None:
 def check(cond: bool, what: str) -> None:
     if not cond:
         raise SmokeFailure(what)
+
+
+def result_line(summary: dict) -> str:
+    """The last stdout line: `ok` and `device` and nothing else — the
+    checker that reads it takes no other key."""
+    dev = summary["device"]
+    return json.dumps({
+        "ok": summary["ok"],
+        "device": {
+            "platform": str(dev["platform"]),
+            "kind": str(dev["kind"]),
+            "count": int(dev["count"]),
+        },
+    })
 
 
 _T0 = time.monotonic()
@@ -1077,7 +1094,11 @@ def main(argv=None) -> int:
         "seconds": round(time.monotonic() - _T0, 1),
         "claim": None,
     }
-    print(json.dumps(summary), flush=True)
+    text = json.dumps(summary)
+    with open(os.path.join(OUT_DIR, "summary.json"), "w") as f:
+        f.write(text + "\n")
+    print(text, flush=True)
+    print(result_line(summary), flush=True)
     return 0
 
 

@@ -157,6 +157,25 @@ def test_chip_smoke_without_a_tpu_fails_and_names_the_platform(children):
     assert out.returncode != 0 and '"ok"' not in out.stdout
 
 
+def test_chip_smoke_result_line_has_exactly_the_contract_keys():
+    import json
+
+    sys.path.insert(0, ROOT)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(ROOT)
+    summary = {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+        "engine": "DecisionEngine", "claim": None,
+    }
+    assert json.loads(chip_smoke.result_line(summary)) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+    }
+
+
 def test_bench_exits_nonzero_without_a_chip_or_on_a_failed_run(children):
     out = children["bench"]
     assert out.returncode != 0
