@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import logging
 import threading
+import time as _time
 from contextlib import nullcontext
 from typing import List, Optional, Sequence
 
@@ -53,6 +54,7 @@ from gubernator_tpu.ops.bucket_kernel import (
 )
 from gubernator_tpu.ops.expiry import windowed_sweep
 from gubernator_tpu.core.interning import InternTable
+from gubernator_tpu.utils.metrics import DurationStat, engine_stages, stage
 from gubernator_tpu.utils.tracing import span
 from gubernator_tpu.types import (
     Algorithm,
@@ -174,33 +176,37 @@ class PendingColumnar:
         from gubernator_tpu.ops.bucket_kernel import unpack_out_host
 
         n = self._n
-        o_status = np.empty(n, dtype=np.int32)
-        o_remaining = np.empty(n, dtype=_I64)
-        o_reset = np.empty(n, dtype=_I64)
-        for piece in self._pieces:
-            packed, dst_idx, m, _size = piece[:4]
-            # Narrow-format pieces carry their own unpacker (uniform
-            # batches, bucket_kernel.unpack_uniform_out_host).
-            unpack = piece[4] if len(piece) > 4 else unpack_out_host
-            arr = packed.fetch()  # combined transfer (core/readback.py)
-            if isinstance(dst_idx, list):
-                # Sharded piece: arr is [n_shards, PACKED_OUT_ROWS,
-                # width]; dst_idx/m are per-shard request-index rows /
-                # lane counts.
-                for sh, idxs in enumerate(dst_idx):
-                    mm = m[sh]
-                    if mm == 0:
-                        continue
-                    st, rem, rst = unpack_out_host(arr[sh], mm)
-                    o_status[idxs] = st
-                    o_remaining[idxs] = rem
-                    o_reset[idxs] = rst
-            else:
-                st, rem, rst = unpack(arr, m)
-                o_status[dst_idx] = st
-                o_remaining[dst_idx] = rem
-                o_reset[dst_idx] = rst
-        over = int(np.sum(o_status == int(Status.OVER_LIMIT)))
+        # The waits first (combined transfers, core/readback.py; each
+        # observes device.readback), then ONE engine.unpack for the
+        # RPC: a work annotation never spans a blocking read.
+        arrs = [piece[0].fetch() for piece in self._pieces]
+        with self._engine._stage("engine.unpack"):
+            o_status = np.empty(n, dtype=np.int32)
+            o_remaining = np.empty(n, dtype=_I64)
+            o_reset = np.empty(n, dtype=_I64)
+            for piece, arr in zip(self._pieces, arrs):
+                _packed, dst_idx, m, _size = piece[:4]
+                # Narrow-format pieces carry their own unpacker (uniform
+                # batches, bucket_kernel.unpack_uniform_out_host).
+                unpack = piece[4] if len(piece) > 4 else unpack_out_host
+                if isinstance(dst_idx, list):
+                    # Sharded piece: arr is [n_shards, PACKED_OUT_ROWS,
+                    # width]; dst_idx/m are per-shard request-index
+                    # rows / lane counts.
+                    for sh, idxs in enumerate(dst_idx):
+                        mm = m[sh]
+                        if mm == 0:
+                            continue
+                        st, rem, rst = unpack_out_host(arr[sh], mm)
+                        o_status[idxs] = st
+                        o_remaining[idxs] = rem
+                        o_reset[idxs] = rst
+                else:
+                    st, rem, rst = unpack(arr, m)
+                    o_status[dst_idx] = st
+                    o_remaining[dst_idx] = rem
+                    o_reset[dst_idx] = rst
+            over = int(np.sum(o_status == int(Status.OVER_LIMIT)))
         with self._engine._lock:
             # Counted at materialization; a dropped PendingColumnar
             # (fire-and-forget caller) does not contribute.
@@ -510,9 +516,11 @@ class DecisionEngine:
         # stacks) — the numerator of the dispatches-per-batch gauge the
         # fused plane pins to 1 in steady state (PERF.md §24).
         self.dispatches_total = 0  # guberlint: guarded-by _lock
-        from gubernator_tpu.utils.metrics import DurationStat
-
+        # device.step: the host's ENQUEUE wall of one dispatch (h2d +
+        # launch, both staged below) — not device time.
         self.round_duration = DurationStat()
+        # The served path's stages (utils/metrics.ENGINE_STAGES).
+        self.stages = engine_stages()
         # Engine-wide d2h transfer batching (core/readback.py): every
         # dispatched output registers a ticket; readers share one
         # stacked transfer instead of paying a device→host read each.
@@ -522,6 +530,32 @@ class DecisionEngine:
 
     def _probe(self, name: str, verdict, otherwise: str) -> bool:
         return record_probe(self.probes, name, verdict, otherwise)
+
+    def _stage(self, name: str, work: bool = True) -> stage:
+        return stage(name, self.stages[name], work)
+
+    def _h2d(self, buf):
+        """Upload one packed round; a round the pump pre-staged is on
+        the device already and passes through."""
+        if isinstance(buf, jax.Array):
+            return buf
+        with self._stage("device.h2d"):
+            return jnp.asarray(buf)
+
+    def _step(self, fused_fn, compute_fn, pin):  # guberlint: holds _lock
+        """One round's program(s) — the fused donated step, or the
+        split compute + scatter pair — as ONE device.launch: the
+        jitted call returning (the enqueue, not the device's run) and
+        the donated state's old buffers let go."""
+        with self._stage("device.launch"):
+            if self._fused:
+                self._state, pout = fused_fn(self._state, pin)
+                self.dispatches_total += 1
+            else:
+                slot_dev, vals, pout = compute_fn(self._state, pin)
+                self._state = scatter_store(self._state, slot_dev, vals)
+                self.dispatches_total += 2
+        return pout
 
     # ------------------------------------------------------------------
 
@@ -701,17 +735,8 @@ class DecisionEngine:
         """One device round: single h2d of the packed buffer, then the
         fused donated kernel (or the split compute + scatter pair);
         returns the packed output (caller starts the async readback)."""
-        import time as _time
-
         t0 = _time.monotonic()
-        pin = jnp.asarray(buf)
-        if self._fused:
-            self._state, pout = fused_fn(self._state, pin)
-            self.dispatches_total += 1
-        else:
-            slot_dev, vals, pout = compute_fn(self._state, pin)
-            self._state = scatter_store(self._state, slot_dev, vals)
-            self.dispatches_total += 2
+        pout = self._step(fused_fn, compute_fn, self._h2d(buf))
         self.round_duration.observe(_time.monotonic() - t0)
         return pout
 
@@ -724,14 +749,13 @@ class DecisionEngine:
     def _dispatch_uniform(self, buf: np.ndarray):  # guberlint: holds _lock
         """Narrow uniform-batch step (pump-only: requires the fused
         in-place program family)."""
-        import time as _time
-
         from gubernator_tpu.ops.bucket_kernel import uniform_step
 
         t0 = _time.monotonic()
-        pin = jnp.asarray(buf)
-        self._state, pout = uniform_step(self._state, pin)
-        self.dispatches_total += 1
+        pin = self._h2d(buf)
+        with self._stage("device.launch"):
+            self._state, pout = uniform_step(self._state, pin)
+            self.dispatches_total += 1
         self.round_duration.observe(_time.monotonic() - t0)
         return pout
 
@@ -744,16 +768,15 @@ class DecisionEngine:
         """The Pallas single-kernel step (ops/pallas_step.py): the
         whole gather→update→scatter→pack round as ONE device program
         over the in-place-aliased state columns."""
-        import time as _time
-
         from gubernator_tpu.ops.pallas_step import pallas_fused_step
 
         t0 = _time.monotonic()
-        pin = jnp.asarray(buf)
-        self._state, pout = pallas_fused_step(
-            self._state, pin, interpret=self._pallas_interpret
-        )
-        self.dispatches_total += 1
+        pin = self._h2d(buf)
+        with self._stage("device.launch"):
+            self._state, pout = pallas_fused_step(
+                self._state, pin, interpret=self._pallas_interpret
+            )
+            self.dispatches_total += 1
         self.round_duration.observe(_time.monotonic() - t0)
         return pout
 
@@ -788,10 +811,12 @@ class DecisionEngine:
             self.capacity, self.capacity + csize, dtype=np.int64
         ).astype(_I32)
         c[: len(cleared)] = cleared
-        self._state = self._state._replace(
-            meta=clear_occupied(self._state.meta, jnp.asarray(c))
-        )
-        self.dispatches_total += 1
+        c_dev = self._h2d(c)
+        with self._stage("device.launch"):
+            self._state = self._state._replace(
+                meta=clear_occupied(self._state.meta, c_dev)
+            )
+            self.dispatches_total += 1
 
     def _apply_restores(self, restores: List[tuple]) -> None:  # guberlint: holds _lock
         """Hydrate store-provided bucket values into fresh slots —
@@ -960,19 +985,25 @@ class DecisionEngine:
                 self.table.release_slots(freed_slots)
             return c
 
-        with self._lock, span("engine.sweep") as s:
+        with self._lock:
+            # Queued rounds dispatch through their own leaf stages
+            # first: engine.sweep never encloses another annotation.
             self._flush_pump()
-            freed = windowed_sweep(self, self.capacity, now_ms, max_windows, release)
-            if self.paging is not None:
-                # Non-resident pages never reach the device sweep; the
-                # host copy tracks their TTLs (core/paging.sweep_host)
-                # so cold expired rows free WITHOUT faulting in.
-                host_freed = self.paging.sweep_host(now_ms)
-                if len(host_freed):
-                    self.table.release_slots(host_freed)
-                    freed += len(host_freed)
-            if s is not None:
-                s.set_attribute("freed", freed)
+            with self._stage("engine.sweep") as st:
+                freed = windowed_sweep(
+                    self, self.capacity, now_ms, max_windows, release
+                )
+                if self.paging is not None:
+                    # Non-resident pages never reach the device sweep;
+                    # the host copy tracks their TTLs
+                    # (core/paging.sweep_host) so cold expired rows
+                    # free WITHOUT faulting in.
+                    host_freed = self.paging.sweep_host(now_ms)
+                    if len(host_freed):
+                        self.table.release_slots(host_freed)
+                        freed += len(host_freed)
+                if st.span is not None:
+                    st.span.set_attribute("freed", freed)
             return freed
 
     # ------------------------------------------------------------------
@@ -1034,14 +1065,25 @@ class DecisionEngine:
                 greg_dur[i] = gregorian_duration(now_dt, int(duration[i]))
                 greg_exp[i] = gregorian_expiration(now_dt, int(duration[i]))
 
-        with self._lock, span("engine.columnar", batch=n):
-            pending = self._apply_columnar_locked(
-                keys, algo, behavior, hits, limit, duration, burst,
-                greg_dur, greg_exp, greg_mask, now_ms,
-            )
-            if count_decisions:
-                self.requests_total += n
-                self.batches_total += 1
+        wait = self._stage("engine.lock_wait", work=False).start()
+        with self._lock:
+            wait.stop()
+            t_held = _time.monotonic()
+            try:
+                with span("engine.columnar", batch=n):
+                    pending = self._apply_columnar_locked(
+                        keys, algo, behavior, hits, limit, duration,
+                        burst, greg_dur, greg_exp, greg_mask, now_ms,
+                    )
+                    if count_decisions:
+                        self.requests_total += n
+                        self.batches_total += 1
+            finally:
+                # The serial section: histogram only — it is the parent
+                # of the leaf stages that tile it.
+                self.stages["engine.lock_hold"].observe(
+                    _time.monotonic() - t_held
+                )
         return pending if want_async else pending.get()
 
     def _apply_columnar_locked(
@@ -1053,8 +1095,11 @@ class DecisionEngine:
         # working set fits the resident frames (mirrors _apply_valid;
         # pieces from sub-batches re-offset into the caller's lanes).
         if self.paging is not None and n > self.paging.frames:
-            key_list = keys.to_list() if isinstance(keys, PackedKeys) else keys
-            segs = _segments_by_unique_keys(key_list, self.paging.frames)
+            with self._stage("engine.intern"):  # a pass over the keys
+                key_list = (
+                    keys.to_list() if isinstance(keys, PackedKeys) else keys
+                )
+                segs = _segments_by_unique_keys(key_list, self.paging.frames)
             if len(segs) > 1:
                 pieces: List[tuple] = []
                 for lo, hi in segs:
@@ -1070,49 +1115,55 @@ class DecisionEngine:
                         pieces.append((p[0], p[1] + lo) + p[2:])
                 return PendingColumnar(self, pieces, limit, n)
 
-        if isinstance(keys, PackedKeys) and hasattr(self.table, "schedule_packed"):
-            slots, rounds_arr, evicted, evict_rounds = self.table.schedule_packed(
-                keys.buf, keys.offsets, now_ms
-            )
-        elif hasattr(self.table, "schedule"):
-            if isinstance(keys, PackedKeys):
-                keys = keys.to_list()
-            slots, rounds_arr, evicted, evict_rounds = self.table.schedule(
-                keys, now_ms
-            )
-        else:
-            if isinstance(keys, PackedKeys):
-                keys = keys.to_list()
-            slots = np.empty(n, dtype=_I32)
-            rounds_arr = np.empty(n, dtype=_I32)
-            seq: dict[int, int] = {}
-            ev_list: List[int] = []
-            ev_rounds: List[int] = []
-            for j, key in enumerate(keys):
-                cleared: List[int] = []
-                slot = self.table.intern(key.decode(), now_ms, cleared)
-                for es in cleared:
-                    ev_list.append(es)
-                    ev_rounds.append(seq.get(es, 0))
-                k = seq.get(slot, 0)
-                seq[slot] = k + 1
-                slots[j] = slot
-                rounds_arr[j] = k
-            evicted = np.asarray(ev_list, dtype=_I32)
-            evict_rounds = np.asarray(ev_rounds, dtype=_I32)
+        with self._stage("engine.intern"):
+            table = self.table
+            if isinstance(keys, PackedKeys) and hasattr(
+                table, "schedule_packed"
+            ):
+                slots, rounds_arr, evicted, evict_rounds = (
+                    table.schedule_packed(keys.buf, keys.offsets, now_ms)
+                )
+            elif hasattr(table, "schedule"):
+                if isinstance(keys, PackedKeys):
+                    keys = keys.to_list()
+                slots, rounds_arr, evicted, evict_rounds = table.schedule(
+                    keys, now_ms
+                )
+            else:
+                if isinstance(keys, PackedKeys):
+                    keys = keys.to_list()
+                slots = np.empty(n, dtype=_I32)
+                rounds_arr = np.empty(n, dtype=_I32)
+                seq: dict[int, int] = {}
+                ev_list: List[int] = []
+                ev_rounds: List[int] = []
+                for j, key in enumerate(keys):
+                    cleared: List[int] = []
+                    slot = table.intern(key.decode(), now_ms, cleared)
+                    for es in cleared:
+                        ev_list.append(es)
+                        ev_rounds.append(seq.get(es, 0))
+                    k = seq.get(slot, 0)
+                    seq[slot] = k + 1
+                    slots[j] = slot
+                    rounds_arr[j] = k
+                evicted = np.asarray(ev_list, dtype=_I32)
+                evict_rounds = np.asarray(ev_rounds, dtype=_I32)
 
-        if greg_dur is None:
-            greg_dur = _ZEROS_CACHE.get(n)
-            greg_exp = greg_dur
+            if greg_dur is None:
+                greg_dur = _ZEROS_CACHE.get(n)
+                greg_exp = greg_dur
+            max_round = int(rounds_arr.max()) if n else 0
 
         # Paged translation (see _apply_valid): collapse/dispatch pack
         # DEVICE rows; the intern table keeps LOGICAL slots.  Eviction
-        # clears stay logical — _apply_clears owns that split.
+        # clears stay logical — _apply_clears owns that split.  Outside
+        # engine.intern: a fault flushes the pump and moves pages
+        # (device.page_fault, and the dispatch's own leaf stages).
         lslots = slots
         if self.paging is not None:
             slots = self.paging.translate(self, slots)
 
-        max_round = int(rounds_arr.max()) if n else 0
         pieces: Optional[List[tuple]] = None
         if max_round > 0:
             # Hot-key batches: one dispatch per duplicate would be the
@@ -1130,9 +1181,10 @@ class DecisionEngine:
                 evicted, evict_rounds, n,
             )
 
-        expires = np.where(greg_mask, greg_exp, now_ms + duration)
-        self.table.set_expiry(lslots, expires.astype(_I64))
-        return PendingColumnar(self, pieces, limit, n)
+        with self._stage("engine.set_expiry"):
+            expires = np.where(greg_mask, greg_exp, now_ms + duration)
+            self.table.set_expiry(lslots, expires.astype(_I64))
+            return PendingColumnar(self, pieces, limit, n)
 
     def _uniform_params(
         self, algo, behavior, hits, limit, duration, burst
@@ -1172,39 +1224,49 @@ class DecisionEngine:
         duration, burst, greg_dur, greg_exp, now_ms, evicted,
         evict_rounds, n,
     ) -> List[tuple]:
-        if max_round == 0:
-            round_members = [(0, None)]  # None = all lanes, no gather
-        else:
-            order = np.argsort(rounds_arr, kind="stable")
-            sorted_rounds = rounds_arr[order]
-            uniq, starts = np.unique(sorted_rounds, return_index=True)
-            bounds = list(starts) + [n]
-            round_members = [
-                (int(k), order[bounds[i] : bounds[i + 1]])
-                for i, k in enumerate(uniq)
-            ]
+        # engine.pack is observed per slice of host work (the plan, a
+        # round's gather, a chunk's sort + pack), never around a
+        # dispatch: chunk k+1 packs while the device runs chunk k.
+        with self._stage("engine.pack"):
+            if max_round == 0:
+                round_members = [(0, None)]  # None = all lanes, no gather
+            else:
+                order = np.argsort(rounds_arr, kind="stable")
+                sorted_rounds = rounds_arr[order]
+                uniq, starts = np.unique(sorted_rounds, return_index=True)
+                bounds = list(starts) + [n]
+                round_members = [
+                    (int(k), order[bounds[i] : bounds[i + 1]])
+                    for i, k in enumerate(uniq)
+                ]
 
-        clear_by_round: dict[int, List[int]] = {}
-        for es, k in zip(evicted.tolist(), evict_rounds.tolist()):
-            clear_by_round.setdefault(k, []).append(es)
+            clear_by_round: dict[int, List[int]] = {}
+            for es, k in zip(evicted.tolist(), evict_rounds.tolist()):
+                clear_by_round.setdefault(k, []).append(es)
 
-        # Dispatch: host presorts each chunk by slot (the sort the
-        # device kernel would otherwise pay a sorting network for),
-        # packs the whole round into ONE int32 buffer (one h2d op on a
-        # dispatch-bound backend — see bucket_kernel PACKED_IN_ROWS),
-        # runs the fused (or split) kernel, and starts an async copy of
-        # the packed outputs.  Materialization happens in
-        # PendingColumnar.get(), so the caller can overlap this batch's
-        # readback with the next batch's dispatch.
-        uni = self._uniform_params(algo, behavior, hits, limit, duration, burst)
-        if uni is not None:
-            from gubernator_tpu.ops.bucket_kernel import (
-                pack_uniform_host,
-                unpack_uniform_out_host,
+            # Dispatch: host presorts each chunk by slot (the sort the
+            # device kernel would otherwise pay a sorting network for),
+            # packs the whole round into ONE int32 buffer (one h2d op
+            # on a dispatch-bound backend — see bucket_kernel
+            # PACKED_IN_ROWS), runs the fused (or split) kernel, and
+            # starts an async copy of the packed outputs.
+            # Materialization happens in PendingColumnar.get(), so the
+            # caller can overlap this batch's readback with the next
+            # batch's dispatch.
+            uni = self._uniform_params(
+                algo, behavior, hits, limit, duration, burst
             )
+            if uni is not None:
+                from gubernator_tpu.ops.bucket_kernel import (
+                    pack_uniform_host,
+                    unpack_uniform_out_host,
+                )
 
-            def unpack_uni(arr, m, _now=now_ms):
-                return unpack_uniform_out_host(arr, m, _now)
+                def unpack_uni(arr, m, _now=now_ms):
+                    return unpack_uniform_out_host(arr, m, _now)
+
+            all_cols = (algo, behavior, hits, limit, duration, burst,
+                        greg_dur, greg_exp)
 
         pieces: List[tuple] = []
         for k, members in round_members:
@@ -1212,55 +1274,43 @@ class DecisionEngine:
             if cleared:
                 self._apply_clears(np.asarray(cleared, dtype=_I32))
             if members is None:
-                c_slot = slots
-                cols = (algo, behavior, hits, limit, duration, burst,
-                        greg_dur, greg_exp)
+                c_slot, cols = slots, all_cols
             else:
-                c_slot = slots[members]
-                cols = tuple(
-                    a[members]
-                    for a in (algo, behavior, hits, limit, duration, burst,
-                              greg_dur, greg_exp)
-                )
+                with self._stage("engine.pack"):
+                    c_slot = slots[members]
+                    cols = tuple(a[members] for a in all_cols)
             m_total = len(c_slot)
             for lo in range(0, m_total, self.max_kernel_width):
-                hi = min(lo + self.max_kernel_width, m_total)
-                m = hi - lo
-                size = _pad_size(m)
-                sort_idx = np.argsort(c_slot[lo:hi], kind="stable")
-                if uni is not None:
-                    buf = pack_uniform_host(
-                        size,
-                        now_ms,
-                        self.capacity,
-                        np.ascontiguousarray(
-                            c_slot[lo:hi][sort_idx], dtype=_I32
-                        ),
-                        *uni,
+                with self._stage("engine.pack"):
+                    hi = min(lo + self.max_kernel_width, m_total)
+                    m = hi - lo
+                    size = _pad_size(m)
+                    sort_idx = np.argsort(c_slot[lo:hi], kind="stable")
+                    c_sorted = np.ascontiguousarray(
+                        c_slot[lo:hi][sort_idx], dtype=_I32
                     )
+                    if uni is not None:
+                        buf = pack_uniform_host(
+                            size, now_ms, self.capacity, c_sorted, *uni
+                        )
+                    else:
+                        buf = pack_batch_host(
+                            size, now_ms, self.capacity, c_sorted,
+                            *(a[lo:hi][sort_idx] for a in cols),
+                        )
+                    # Request indices of the sorted lanes, for
+                    # unpermuting.
+                    if members is None:
+                        dst_idx = sort_idx + lo if lo else sort_idx
+                    else:
+                        dst_idx = members[lo:hi][sort_idx]
+                if self._pump is not None:  # the uniform format implies it
                     ticket = self._pump.submit(buf)
                 else:
-                    buf = pack_batch_host(
-                        size,
-                        now_ms,
-                        self.capacity,
-                        np.ascontiguousarray(
-                            c_slot[lo:hi][sort_idx], dtype=_I32
-                        ),
-                        *(a[lo:hi][sort_idx] for a in cols),
+                    ticket = self.readback.register(
+                        self._dispatch_packed(buf)
                     )
-                    if self._pump is not None:
-                        ticket = self._pump.submit(buf)
-                    else:
-                        ticket = self.readback.register(
-                            self._dispatch_packed(buf)
-                        )
                 self.rounds_total += 1
-                # Request indices of the sorted lanes, for unpermuting.
-                if members is None:
-                    dst_idx = sort_idx + lo if lo else sort_idx
-                else:
-                    dst_idx = members[lo:hi][sort_idx]
                 if uni is not None:
                     pieces.append((ticket, dst_idx, m, size, unpack_uni))
                 else:
@@ -1344,75 +1394,79 @@ class DecisionEngine:
         chunk; returns pieces, or None when the batch needs rounds
         (non-uniform duplicate fields, RESET_REMAINING on a duplicate,
         or a mid-batch slot reuse via eviction)."""
-        # Mid-batch eviction reuse (a slot freed after use and handed
-        # to ANOTHER key in the same batch) breaks the one-key-per-
-        # segment invariant.
-        if len(evict_rounds) and int(evict_rounds.max()) > 0:
-            return None
-        n = len(slots)
-        order = np.argsort(slots, kind="stable")  # stable = arrival order
-        sorted_slots = slots[order]
-        uniq, seg_start, counts = np.unique(
-            sorted_slots, return_index=True, return_counts=True
-        )
-        seg_of = np.repeat(np.arange(len(uniq), dtype=np.int64), counts)
-        dup_lane = counts[seg_of] > 1
-        cols = (algo, behavior, hits, limit, duration, burst,
-                greg_dur, greg_exp)
-        for col in cols:
-            cs = col[order]
-            if not np.array_equal(
-                cs[dup_lane], cs[seg_start][seg_of][dup_lane]
+        # engine.pack, slice by slice (the gate, then each chunk's
+        # pack), never around a dispatch: chunk k+1 packs while the
+        # device runs chunk k.
+        with self._stage("engine.pack"):
+            # Mid-batch eviction reuse (a slot freed after use and handed
+            # to ANOTHER key in the same batch) breaks the one-key-per-
+            # segment invariant.
+            if len(evict_rounds) and int(evict_rounds.max()) > 0:
+                return None
+            n = len(slots)
+            order = np.argsort(slots, kind="stable")  # stable = arrival order
+            sorted_slots = slots[order]
+            uniq, seg_start, counts = np.unique(
+                sorted_slots, return_index=True, return_counts=True
+            )
+            seg_of = np.repeat(np.arange(len(uniq), dtype=np.int64), counts)
+            dup_lane = counts[seg_of] > 1
+            cols = (algo, behavior, hits, limit, duration, burst,
+                    greg_dur, greg_exp)
+            for col in cols:
+                cs = col[order]
+                if not np.array_equal(
+                    cs[dup_lane], cs[seg_start][seg_of][dup_lane]
+                ):
+                    return None
+            beh_sorted = behavior[order]
+            reset = (beh_sorted & int(Behavior.RESET_REMAINING)) != 0
+            if bool(reset[dup_lane].any()):
+                return None
+            # Sequential leaky semantics re-clamp remaining to burst on
+            # EVERY gather; with negative hits the closed form would skip
+            # the intermediate clamps — keep those (rare) on the rounds
+            # path.
+            if bool(
+                (
+                    (algo[order] == int(Algorithm.LEAKY_BUCKET))
+                    & (hits[order] < 0)
+                )[dup_lane].any()
             ):
                 return None
-        beh_sorted = behavior[order]
-        if bool(
-            ((beh_sorted & int(Behavior.RESET_REMAINING)) != 0)[dup_lane].any()
-        ):
-            return None
-        # Sequential leaky semantics re-clamp remaining to burst on
-        # EVERY gather; with negative hits the closed form would skip
-        # the intermediate clamps — keep those (rare) on the rounds
-        # path.
-        if bool(
-            (
-                (algo[order] == int(Algorithm.LEAKY_BUCKET))
-                & (hits[order] < 0)
-            )[dup_lane].any()
-        ):
-            return None
+            sorted_cols = tuple(col[order] for col in cols)
 
         # All clears are round 0 here: run them before dispatching.
         if len(evicted):
             self._apply_clears(np.asarray(evicted, dtype=_I32))
 
-        sorted_cols = tuple(col[order] for col in cols)
         pieces: List[tuple] = []
         for lo in range(0, n, self.max_kernel_width):
-            hi = min(lo + self.max_kernel_width, n)
-            m = hi - lo
-            # Per-chunk segments (a segment split across chunks is
-            # fine: the next chunk's first occurrence re-gathers the
-            # post-scatter state — still exact).
-            c_slots = sorted_slots[lo:hi]
-            c_uniq, c_start, c_counts = np.unique(
-                c_slots, return_index=True, return_counts=True
-            )
-            c_seg_of = np.repeat(
-                np.arange(len(c_uniq), dtype=np.int64), c_counts
-            )
-            c_pos = np.arange(m, dtype=np.int64) - c_start[c_seg_of]
-            size = _pad_size(m)
-            buf = pack_collapsed_host(
-                size,
-                now_ms,
-                self.capacity,
-                np.ascontiguousarray(c_uniq, dtype=_I32),
-                c_counts.astype(np.int64),
-                tuple(c[lo:hi][c_start] for c in sorted_cols),
-                c_seg_of.astype(_I32),
-                c_pos.astype(_I32),
-            )
+            with self._stage("engine.pack"):
+                hi = min(lo + self.max_kernel_width, n)
+                m = hi - lo
+                # Per-chunk segments (a segment split across chunks is
+                # fine: the next chunk's first occurrence re-gathers
+                # the post-scatter state — still exact).
+                c_slots = sorted_slots[lo:hi]
+                c_uniq, c_start, c_counts = np.unique(
+                    c_slots, return_index=True, return_counts=True
+                )
+                c_seg_of = np.repeat(
+                    np.arange(len(c_uniq), dtype=np.int64), c_counts
+                )
+                c_pos = np.arange(m, dtype=np.int64) - c_start[c_seg_of]
+                size = _pad_size(m)
+                buf = pack_collapsed_host(
+                    size,
+                    now_ms,
+                    self.capacity,
+                    np.ascontiguousarray(c_uniq, dtype=_I32),
+                    c_counts.astype(np.int64),
+                    tuple(c[lo:hi][c_start] for c in sorted_cols),
+                    c_seg_of.astype(_I32),
+                    c_pos.astype(_I32),
+                )
             pout = self._dispatch_collapsed(buf)
             self.rounds_total += 1
             pieces.append(

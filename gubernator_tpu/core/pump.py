@@ -38,23 +38,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from gubernator_tpu.core.readback import read_wait
+
 MAX_GROUP = 16
 
 
 class _Group:
     """Shared host-side result of one flushed multi-step dispatch."""
 
-    __slots__ = ("handle", "host", "error", "lock")
+    __slots__ = ("handle", "wait_stat", "host", "error", "lock")
 
-    def __init__(self, handle) -> None:
+    def __init__(self, handle, wait_stat) -> None:
         self.handle = handle  # device [R, 5, W] (or [5, W] singles)
+        self.wait_stat = wait_stat  # the engine's device.readback
         self.host: Optional[np.ndarray] = None
         self.error: Optional[BaseException] = None
         self.lock = threading.Lock()
 
     def materialize(self) -> np.ndarray:
         if self.host is None and self.error is None:
-            with self.lock:
+            # The first thread reads; the others of a fused group wait
+            # for it on the lock: every one of them observes its wait.
+            with read_wait(self.wait_stat), self.lock:
                 if self.host is None and self.error is None:
                     try:
                         # Prefetched at flush: usually a cache hit.
@@ -161,7 +166,9 @@ class StepPump:
             # Start the h2d NOW: the transfer rides the device queue
             # behind the currently executing group, so upload(N+1)
             # overlaps compute(N) instead of serializing at flush.
-            t.dev = jax.device_put(buf)
+            # guberlint: ok drift — the pump uploads on the engine's behalf: engine.py's device.h2d stage, the engine's stat
+            with self.engine._stage("device.h2d"):
+                t.dev = jax.device_put(buf)
             self.prestaged += 1
         self._queue.append(t)
         self.submitted += 1
@@ -255,6 +262,7 @@ class StepPump:
         )
 
         eng = self.engine
+        wait_stat = eng.readback.transfer_duration
         self.flushes += 1
         now_mono = _time.monotonic()
         for t in group:
@@ -272,7 +280,7 @@ class StepPump:
                 t.index = None
                 t.buf = None
                 t.dev = None
-                t.group = _Group(pout)
+                t.group = _Group(pout, wait_stat)
             return
         k = len(group)
         r = 2
@@ -284,22 +292,26 @@ class StepPump:
             # stack there — no h2d on the flush critical path at all.
             devs = [t.dev for t in group]
             devs += [self._noop_dev_buf(shape)] * (r - k)
-            pins = self._dev_stack(r, shape)(*devs)
-            eng.dispatches_total += 1  # the stack program
+            stack = self._dev_stack(r, shape)
+            # guberlint: ok drift — the pump dispatches on the engine's behalf: engine.py's device.launch stage, the engine's stat
+            with eng._stage("device.launch"):
+                pins = stack(*devs)
+                eng.dispatches_total += 1  # the stack program
         else:
             # Mixed staging (some rounds past the pre-stage depth):
             # one host stack + h2d; a ticket's host buf is always
             # retained until its flush, so no d2h round trip here.
             bufs = [t.buf for t in group]
             bufs += [self._noop_buf(shape)] * (r - k)
-            pins = jnp.asarray(np.stack(bufs))
+            pins = eng._h2d(np.stack(bufs))
         step = multi_uniform_step if is_uniform else multi_fused_step
-        eng._state, pouts = step(eng._state, pins)
-        eng.dispatches_total += 1
+        with eng._stage("device.launch"):
+            eng._state, pouts = step(eng._state, pins)
+            eng.dispatches_total += 1
         eng.round_duration.observe(_time.monotonic() - t0)
         pouts.copy_to_host_async()  # background transfer starts now
         self.fused_rounds += k
-        g = _Group(pouts)
+        g = _Group(pouts, wait_stat)
         for i, t in enumerate(group):
             # index BEFORE group: fetch()'s lock-free fast path keys on
             # `group is not None`, so group must be the LAST field set.

@@ -31,14 +31,25 @@ spill is itself one more same-shape handle in the stack).
 from __future__ import annotations
 
 import threading
-import time as _time
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from gubernator_tpu.utils.metrics import stage
+
 MAX_GROUP = 16
+
+
+def read_wait(wait_stat) -> stage:
+    """The stage of a serving thread blocked on a device array: the
+    leader's transfer and a follower's wait for it, here and in the
+    pump's group read (core/pump.py) — one observation per blocked
+    thread, so an RPC's budget adds up.  A wait: histogram and request
+    span, never a profiler annotation (a waiting thread would claim
+    every idle gap of the device)."""
+    return stage("device.readback", wait_stat, work=False)
 
 
 class Ticket:
@@ -203,7 +214,8 @@ class ReadbackCombiner:
                 # Another leader holds this ticket in its group: its
                 # materialize ALWAYS sets host or error, then the
                 # event.  Wait outside the lock.
-                ticket.event.wait()
+                with read_wait(self.transfer_duration):
+                    ticket.event.wait()
                 continue
             self._materialize_windows([group] + extra)
             # Our group may not have included `ticket` only if shapes
@@ -272,12 +284,10 @@ class ReadbackCombiner:
         return stacked
 
     def _distribute(self, group: List[Ticket], stacked) -> None:
-        # Hot path under feeder-driven load (one call per d2h
-        # transfer): the per-call time import is hoisted to module
-        # level, same as core/pump.py.
-        t0 = _time.monotonic()
-        host = np.asarray(stacked)  # ONE transfer for the whole group
-        self.transfer_duration.observe(_time.monotonic() - t0)
+        # Hot path under feeder-driven load: ONE call, and ONE
+        # transfer, for the whole group.
+        with read_wait(self.transfer_duration):
+            host = np.asarray(stacked)
         if len(group) == 1:
             group[0].host = host
             group[0].handle = None

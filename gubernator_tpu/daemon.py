@@ -32,9 +32,30 @@ from gubernator_tpu.net.grpc_service import (
 from gubernator_tpu.net.server import GrpcPeersV1Adapter, GrpcV1Adapter
 from gubernator_tpu.service import V1Instance
 from gubernator_tpu.types import PeerInfo
-from gubernator_tpu.utils.metrics import build_registry
+from gubernator_tpu.utils.metrics import DurationStat, build_registry
 
 log = logging.getLogger("gubernator_tpu.daemon")
+
+
+class _StampedExecutor(ThreadPoolExecutor):
+    """The gRPC server's handler pool, observing `listener.queue_wait`:
+    how long an RPC waits for one of the `grpc_workers` threads.
+    `submit` stamps the task; its wrapper observes stamp → start on the
+    worker.  Histogram only: the wait ends before the handler's root
+    span exists, and a wait never carries a profiler annotation."""
+
+    def __init__(self, wait_stat: DurationStat, **kwargs) -> None:
+        super().__init__(**kwargs)
+        self._wait_stat = wait_stat
+
+    def submit(self, fn, /, *args, **kwargs):
+        queued = time.monotonic()
+
+        def started():
+            self._wait_stat.observe(time.monotonic() - queued)
+            return fn(*args, **kwargs)
+
+        return super().submit(started)
 
 
 class Daemon:
@@ -221,8 +242,11 @@ class Daemon:
         # gRPC server (both services on one listener; the reference's
         # second loopback server exists only for grpc-gateway's dial,
         # which our native gateway doesn't need).
+        queue_wait = DurationStat()
+        self.instance.stage_timers["listener.queue_wait"] = queue_wait
         self.grpc_server = grpc.server(
-            ThreadPoolExecutor(
+            _StampedExecutor(
+                queue_wait,
                 max_workers=max(1, conf.grpc_workers),
                 thread_name_prefix="guber-grpc",
             ),

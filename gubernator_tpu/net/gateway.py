@@ -15,8 +15,11 @@ byte-compatible with the reference gateway.
 from __future__ import annotations
 
 import json
+import shutil
 import ssl
+import tempfile
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
@@ -29,6 +32,15 @@ from gubernator_tpu.net import serde
 from gubernator_tpu.net.pb import gubernator_pb2 as pb
 from gubernator_tpu.net.pb import peers_pb2 as peers_pb
 from gubernator_tpu.service import ServiceError, V1Instance
+
+
+# jax's profiler is one per process, so is this: a second capture
+# (from any gateway of an in-process cluster) answers 409.
+_PROFILE_LOCK = threading.Lock()
+MAX_PROFILE_SECONDS = 30.0
+# The last capture's directory (4-17 MB at 100 M rows): the next
+# capture removes it, so the process leaves at most one behind.
+_last_profile_dir: Optional[str] = None  # guarded by _PROFILE_LOCK
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -70,6 +82,8 @@ class _Handler(BaseHTTPRequestHandler):
             self._serve_metrics(query)
         elif path == "/debug/trace":
             self._reply(200, json.dumps(self._debug_trace()).encode())
+        elif path == "/debug/profile":
+            self._debug_profile(query)
         elif path == "/debug/hotkeys":
             self._reply(200, json.dumps(self._debug_hotkeys()).encode())
         elif path == "/debug/vars":
@@ -171,6 +185,64 @@ class _Handler(BaseHTTPRequestHandler):
         out = fr.dump()
         out["enabled"] = True
         return out
+
+    def _debug_profile(self, query: str) -> None:
+        """``GET /debug/profile?seconds=N``: capture N (≤ 30) seconds
+        of the jax profiler from inside the process that holds the
+        chip, into a fresh temporary directory.  The stages' `work`
+        annotations (utils/metrics.stage) land on the host plane of
+        the same xplane as the device ops.  Answers with the directory
+        and the /debug/vars `device` block read inside the capture at
+        both ends, so what the device was given while the trace ran is
+        known to the count.  One capture at a time: 409 while one
+        runs.  A capture removes the directory of the one before it:
+        copy what is to be kept before asking again."""
+        global _last_profile_dir
+        from urllib.parse import parse_qs
+
+        try:
+            seconds = float(parse_qs(query).get("seconds", ["3"])[0])
+        except ValueError:
+            seconds = -1.0
+        if not 0 < seconds <= MAX_PROFILE_SECONDS:
+            self._reply_error(
+                400, 3, f"seconds must be in (0, {MAX_PROFILE_SECONDS:g}]"
+            )
+            return
+        if not _PROFILE_LOCK.acquire(blocking=False):
+            self._reply_error(409, 10, "a profile capture is running")
+            return
+        try:
+            import jax
+
+            from gubernator_tpu.core import device_info
+
+            # As the benchmark's launcher captures: the host's Python
+            # frames would fill the file; TraceMe level 2 keeps the
+            # annotations and PJRT's own events.
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 2
+            if _last_profile_dir is not None:
+                shutil.rmtree(_last_profile_dir, ignore_errors=True)
+            _last_profile_dir = tempfile.mkdtemp(prefix="guber-profile-")
+            out = {"path": _last_profile_dir}
+            jax.profiler.start_trace(out["path"], profiler_options=opts)
+            try:
+                out["t_start"] = time.time()
+                start = device_info.describe(self.instance.engine)
+                time.sleep(seconds)
+                stop = device_info.describe(self.instance.engine)
+                out["t_stop"] = time.time()
+            finally:
+                jax.profiler.stop_trace()
+            out["device"] = {"start": start, "stop": stop}
+        except Exception as e:  # noqa: BLE001 — reported to the caller
+            self._reply_error(500, 13, f"{type(e).__name__}: {e}")
+            return
+        finally:
+            _PROFILE_LOCK.release()
+        self._reply(200, json.dumps(out).encode())
 
     def _debug_hotkeys(self) -> dict:
         hk = getattr(self.instance, "hotkeys", None)

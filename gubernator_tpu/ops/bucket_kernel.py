@@ -19,6 +19,17 @@ round a single vectorized device step.
 
 `now_ms` is an explicit input — the device never reads time — so frozen
 clock conformance tests drive the kernel directly (SURVEY.md §4.5).
+
+Names are part of the measurement.  The benchmark finds a step program
+in the device trace by its module name — `jit_` + the name of the
+function handed to `jax.jit` — against the glob patterns of
+`benchmarks/layer_metrics/step.kernel_us_per_dispatch.json` (and
+`mesh.step_us_per_dispatch.json` for the sharded programs):
+`_fused_step_core`, `_multi_fused_core`, `_uniform_step_core`,
+`_multi_uniform_core`, `_collapsed_step_core` here, `local_*_fused` /
+`flat_*_fused` in parallel/sharded_engine.py.  A step that is renamed
+or rewritten under another name turns those metrics to nothing;
+tests/test_step_names.py pins every dispatchable step to a pattern.
 """
 
 from __future__ import annotations

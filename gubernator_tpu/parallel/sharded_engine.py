@@ -18,6 +18,7 @@ scheme of the single-device engine (core/engine.py), applied per shard.
 from __future__ import annotations
 
 import threading
+import time as _time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -46,6 +47,8 @@ from gubernator_tpu.parallel.mesh import (
     shard_map as _shard_map,
 )
 from gubernator_tpu.types import Behavior, RateLimitReq, RateLimitResp, Status
+from gubernator_tpu.utils.metrics import DurationStat, engine_stages, stage
+from gubernator_tpu.utils.tracing import span
 
 _I32 = np.int32
 _I64 = np.int64
@@ -148,9 +151,11 @@ class ShardedDecisionEngine:
             and _os.environ.get("GUBER_PSUM_MERGE", "1") != "0"
         )
         self._merge_progs: Dict[Tuple[int, int], object] = {}
-        from gubernator_tpu.utils.metrics import DurationStat
-
+        # device.step: the host's enqueue wall of one mesh dispatch.
         self.round_duration = DurationStat()
+        # The served path's stages — core/engine.py's vocabulary, plus
+        # the router that exists only here.
+        self.stages = engine_stages(extra=("mesh.route",))
         # Shared d2h transfer batching across concurrent callers
         # (core/readback.py — the mesh outputs combine the same way).
         from gubernator_tpu.core.readback import ReadbackCombiner
@@ -336,6 +341,7 @@ class ShardedDecisionEngine:
         per-device dispatch overhead (see __init__)."""
         from gubernator_tpu.ops.bucket_kernel import (
             _clear_occupied_impl,
+            _collapsed_step_core,
             _collapsed_values,
             _fused_step_core,
             _load_slots_impl,
@@ -352,12 +358,11 @@ class ShardedDecisionEngine:
             jax.vmap(_scatter_values), donate_argnums=(0,)
         )
 
-        def collapsed_fused_one(state, pin):
-            slot, vals2, pout = _collapsed_values(state, pin)
-            return _scatter_values(state, slot, vals2), pout
-
+        # The one-device program itself, vmapped: it keeps the module
+        # name the benchmark's step patterns know
+        # (tests/test_step_names.py).
         self._collapsed_fused = jax.jit(
-            jax.vmap(collapsed_fused_one), donate_argnums=(0,)
+            jax.vmap(_collapsed_step_core), donate_argnums=(0,)
         )
         self._collapsed_compute = jax.jit(jax.vmap(_collapsed_values))
         self._load_step = jax.jit(
@@ -424,10 +429,31 @@ class ShardedDecisionEngine:
     def shard_of(self, key: str) -> int:
         return fnv1a_64(key.encode()) % self.n_shards
 
+    def _stage(self, name: str, work: bool = True) -> stage:
+        return stage(name, self.stages[name], work)
+
     def _put(self, host: np.ndarray) -> jax.Array:
         """One host buffer with a leading shard axis → device, each
         shard's block straight to the device that owns it."""
-        return jax.device_put(host, self._placement)
+        # guberlint: ok drift — sharded twin of engine.py's device.h2d site
+        with self._stage("device.h2d"):
+            return jax.device_put(host, self._placement)
+
+    def _step(self, fused, compute, scatter, pin):
+        """One round's mesh program(s) — the fused donated step, or the
+        split compute + scatter pair — as ONE device.launch: the
+        jitted call returning (the enqueue) and the donated state's
+        old buffers let go.  Returns the packed output."""
+        # guberlint: ok drift — sharded twin of engine.py's device.launch site
+        with self._stage("device.launch"):
+            if self._fused:
+                self._state, pout = fused(self._state, pin)
+                self.dispatches_total += 1
+            else:
+                slot_dev, vals, pout = compute(self._state, pin)
+                self._state = scatter(self._state, slot_dev, vals)
+                self.dispatches_total += 2
+        return pout
 
     def _apply_shard_clears(self, clears: List[List[int]]) -> None:
         """Eviction clears, one padded [n_shards, csize] scatter.
@@ -443,10 +469,12 @@ class ShardedDecisionEngine:
         )
         for sh in range(self.n_shards):
             c[sh, : len(clears[sh])] = clears[sh]
-        self._state = self._state._replace(
-            meta=self._clear_step(self._state.meta, self._put(c))
-        )
-        self.dispatches_total += 1
+        c_dev = self._put(c)
+        with self._stage("device.launch"):
+            self._state = self._state._replace(
+                meta=self._clear_step(self._state.meta, c_dev)
+            )
+            self.dispatches_total += 1
 
     def _apply_shard_restores(self, restores: List[List[tuple]]) -> None:
         """Hydrate store-provided bucket values into fresh slots on
@@ -469,8 +497,9 @@ class ShardedDecisionEngine:
                 for name, arrs in cols.items()
             }
         )
-        self._state = self._load_step(self._state, rec_stacked)
-        self.dispatches_total += 1
+        with self._stage("device.launch"):
+            self._state = self._load_step(self._state, rec_stacked)
+            self.dispatches_total += 1
 
     def get_rate_limits(
         self, requests: Sequence[RateLimitReq], now_ms: Optional[int] = None
@@ -544,8 +573,6 @@ class ShardedDecisionEngine:
                     restore_rounds.setdefault(k, [[] for _ in range(n_sh)])[
                         sh
                     ].append((slot, item))
-
-        from gubernator_tpu.utils.tracing import span
 
         expire_of: Dict[int, int] = {}
         # guberlint: ok drift — sharded twin of engine.py's
@@ -694,17 +721,11 @@ class ShardedDecisionEngine:
             order_of.append(sort_idx)
             limits_of.append(c_limit)
 
-        import time as _time
-
         t0 = _time.monotonic()
-        pin = self._put(buf)
-        if self._fused:
-            self._state, pout = self._packed_fused(self._state, pin)
-            self.dispatches_total += 1
-        else:
-            slot_dev, vals, pout = self._packed_compute(self._state, pin)
-            self._state = self._step_scatter(self._state, slot_dev, vals)
-            self.dispatches_total += 2
+        pout = self._step(
+            self._packed_fused, self._packed_compute, self._step_scatter,
+            self._put(buf),
+        )
         self.round_duration.observe(_time.monotonic() - t0)
 
         arr = self.readback.register(pout).fetch()
@@ -761,10 +782,14 @@ class ShardedDecisionEngine:
                 total += c
             return total
 
-        with self._lock:
-            return windowed_sweep(
+        # guberlint: ok drift — sharded twin of engine.py's engine.sweep site
+        with self._lock, self._stage("engine.sweep") as st:
+            freed = windowed_sweep(
                 self, self.shard_capacity, now_ms, max_windows, release
             )
+            if st.span is not None:
+                st.span.set_attribute("freed", freed)
+            return freed
 
     def warmup(self, max_width: int = 1024) -> None:
         """Pre-compile the sharded step for padded widths up to
@@ -978,17 +1003,27 @@ class ShardedDecisionEngine:
             greg_dur = np.zeros(n, dtype=_I64)
             greg_exp = greg_dur
 
-        from gubernator_tpu.utils.tracing import span
-
-        # guberlint: ok drift — sharded twin of engine.py's
-        # engine.columnar site
-        with self._lock, span("engine.columnar", batch=n):
-            pending = self._apply_columnar_locked(
-                keys, algo, behavior, hits, limit, duration, burst,
-                greg_dur, greg_exp, greg_mask, now_ms, route_hashes,
-            )
-            self.requests_total += n
-            self.batches_total += 1
+        # guberlint: ok drift — sharded twin of engine.py's engine.lock_wait site
+        wait = self._stage("engine.lock_wait", work=False).start()
+        with self._lock:
+            wait.stop()
+            t_held = _time.monotonic()
+            try:
+                # guberlint: ok drift — sharded twin of engine.py's
+                # engine.columnar site
+                with span("engine.columnar", batch=n):
+                    pending = self._apply_columnar_locked(
+                        keys, algo, behavior, hits, limit, duration,
+                        burst, greg_dur, greg_exp, greg_mask, now_ms,
+                        route_hashes,
+                    )
+                    self.requests_total += n
+                    self.batches_total += 1
+            finally:
+                # Histogram only: the parent of the leaf stages.
+                self.stages["engine.lock_hold"].observe(
+                    _time.monotonic() - t_held
+                )
         return pending if want_async else pending.get()
 
     def _apply_columnar_locked(
@@ -1018,64 +1053,69 @@ class ShardedDecisionEngine:
             packed = None
 
         # 1. Vectorized shard routing: one FNV-1a pass over the batch
-        # (or the wire codec's precomputed hashes, when given).
-        if route_hashes is not None:
-            hashes = np.asarray(route_hashes, dtype=np.uint64)
-        else:
-            assert packed is None, "PackedKeys requires route_hashes"
-            padded, lengths = pack_keys(keys)
-            hashes = fnv1a_64_batch(padded, lengths)
-        shards = (hashes % np.uint64(n_sh)).astype(np.int64)
+        # (or the wire codec's precomputed hashes, when given), and the
+        # request indices of every shard's lane.
+        with self._stage("mesh.route"):
+            if route_hashes is not None:
+                hashes = np.asarray(route_hashes, dtype=np.uint64)
+            else:
+                assert packed is None, "PackedKeys requires route_hashes"
+                padded, lengths = pack_keys(keys)
+                hashes = fnv1a_64_batch(padded, lengths)
+            shards = (hashes % np.uint64(n_sh)).astype(np.int64)
+            shard_idx: List[np.ndarray] = [
+                np.nonzero(shards == sh)[0] for sh in range(n_sh)
+            ]
 
         # 2. Per-shard native scheduling.
-        shard_idx: List[np.ndarray] = []  # request indices per shard
-        shard_slots: List[np.ndarray] = []
-        shard_rounds: List[np.ndarray] = []
-        clear_by_round: Dict[int, List[List[int]]] = {}
-        max_round = 0
-        for sh in range(n_sh):
-            idx = np.nonzero(shards == sh)[0]
-            shard_idx.append(idx)
-            if len(idx) == 0:
-                shard_slots.append(np.empty(0, dtype=_I32))
-                shard_rounds.append(np.empty(0, dtype=_I32))
-                continue
-            table = self.tables[sh]
-            if packed is not None:
-                slots, rounds, evicted, evict_rounds = table.schedule_packed(
-                    packed.buf, packed.offsets, now_ms,
-                    idx=idx.astype(np.int64),
-                )
-            elif hasattr(table, "schedule"):
-                slots, rounds, evicted, evict_rounds = table.schedule(
-                    [keys[i] for i in idx], now_ms
-                )
-            else:
-                slots = np.empty(len(idx), dtype=_I32)
-                rounds = np.empty(len(idx), dtype=_I32)
-                seq: Dict[int, int] = {}
-                ev_list: List[int] = []
-                ev_rounds: List[int] = []
-                for j, i in enumerate(idx):
-                    cleared: List[int] = []
-                    slot = table.intern(keys[i].decode(), now_ms, cleared)
-                    for es in cleared:
-                        ev_list.append(es)
-                        ev_rounds.append(seq.get(es, 0))
-                    k = seq.get(slot, 0)
-                    seq[slot] = k + 1
-                    slots[j] = slot
-                    rounds[j] = k
-                evicted = np.asarray(ev_list, dtype=_I32)
-                evict_rounds = np.asarray(ev_rounds, dtype=_I32)
-            shard_slots.append(slots)
-            shard_rounds.append(rounds)
-            if len(rounds):
-                max_round = max(max_round, int(rounds.max()))
-            for es, k in zip(evicted.tolist(), evict_rounds.tolist()):
-                clear_by_round.setdefault(k, [[] for _ in range(n_sh)])[
-                    sh
-                ].append(es)
+        # guberlint: ok drift — sharded twin of engine.py's engine.intern site
+        with self._stage("engine.intern"):
+            shard_slots: List[np.ndarray] = []
+            shard_rounds: List[np.ndarray] = []
+            clear_by_round: Dict[int, List[List[int]]] = {}
+            max_round = 0
+            for sh in range(n_sh):
+                idx = shard_idx[sh]
+                if len(idx) == 0:
+                    shard_slots.append(np.empty(0, dtype=_I32))
+                    shard_rounds.append(np.empty(0, dtype=_I32))
+                    continue
+                table = self.tables[sh]
+                if packed is not None:
+                    slots, rounds, evicted, evict_rounds = table.schedule_packed(
+                        packed.buf, packed.offsets, now_ms,
+                        idx=idx.astype(np.int64),
+                    )
+                elif hasattr(table, "schedule"):
+                    slots, rounds, evicted, evict_rounds = table.schedule(
+                        [keys[i] for i in idx], now_ms
+                    )
+                else:
+                    slots = np.empty(len(idx), dtype=_I32)
+                    rounds = np.empty(len(idx), dtype=_I32)
+                    seq: Dict[int, int] = {}
+                    ev_list: List[int] = []
+                    ev_rounds: List[int] = []
+                    for j, i in enumerate(idx):
+                        cleared: List[int] = []
+                        slot = table.intern(keys[i].decode(), now_ms, cleared)
+                        for es in cleared:
+                            ev_list.append(es)
+                            ev_rounds.append(seq.get(es, 0))
+                        k = seq.get(slot, 0)
+                        seq[slot] = k + 1
+                        slots[j] = slot
+                        rounds[j] = k
+                    evicted = np.asarray(ev_list, dtype=_I32)
+                    evict_rounds = np.asarray(ev_rounds, dtype=_I32)
+                shard_slots.append(slots)
+                shard_rounds.append(rounds)
+                if len(rounds):
+                    max_round = max(max_round, int(rounds.max()))
+                for es, k in zip(evicted.tolist(), evict_rounds.tolist()):
+                    clear_by_round.setdefault(k, [[] for _ in range(n_sh)])[
+                        sh
+                    ].append(es)
 
         # 2b. Hot keys: collapse uniform duplicate segments per shard
         # into one mesh dispatch per chunk (see core.engine
@@ -1087,31 +1127,41 @@ class ShardedDecisionEngine:
                 algo, behavior, hits, limit, duration, burst,
                 greg_dur, greg_exp, now_ms,
             )
-        if pieces is not None:
-            for sh in range(n_sh):
-                if len(shard_idx[sh]):
-                    self.tables[sh].set_expiry(
-                        shard_slots[sh],
-                        np.where(greg_mask, greg_exp, now_ms + duration)
-                        .astype(_I64)[shard_idx[sh]],
-                    )
-            from gubernator_tpu.core.engine import PendingColumnar as _PC
+        from gubernator_tpu.core.engine import PendingColumnar
 
-            return _PC(self, pieces, limit, n)
+        def set_expiry() -> None:
+            # TTL mirror, per shard.
+            # guberlint: ok drift — sharded twin of engine.py's engine.set_expiry site
+            with self._stage("engine.set_expiry"):
+                expires = np.where(
+                    greg_mask, greg_exp, now_ms + duration
+                ).astype(_I64)
+                for sh in range(n_sh):
+                    if len(shard_idx[sh]):
+                        self.tables[sh].set_expiry(
+                            shard_slots[sh], expires[shard_idx[sh]]
+                        )
+
+        if pieces is not None:
+            set_expiry()
+            return PendingColumnar(self, pieces, limit, n)
 
         # 3. One mesh step per round (chunked by max_kernel_width).
         pieces = []
         for k in range(max_round + 1):
-            members = [
-                shard_idx[sh][shard_rounds[sh] == k] if len(shard_idx[sh]) else shard_idx[sh]
-                for sh in range(n_sh)
-            ]
-            m_slots = [
-                shard_slots[sh][shard_rounds[sh] == k]
-                if len(shard_slots[sh])
-                else shard_slots[sh]
-                for sh in range(n_sh)
-            ]
+            # guberlint: ok drift — sharded twin of engine.py's engine.pack site
+            with self._stage("engine.pack"):
+                members = [
+                    shard_idx[sh][shard_rounds[sh] == k]
+                    if len(shard_idx[sh]) else shard_idx[sh]
+                    for sh in range(n_sh)
+                ]
+                m_slots = [
+                    shard_slots[sh][shard_rounds[sh] == k]
+                    if len(shard_slots[sh])
+                    else shard_slots[sh]
+                    for sh in range(n_sh)
+                ]
             if not any(len(m) for m in members) and k not in clear_by_round:
                 continue
             clears = clear_by_round.get(k)
@@ -1147,16 +1197,7 @@ class ShardedDecisionEngine:
                 if all(offset >= len(m) for m in members):
                     break
 
-        # 4. TTL mirror, per shard.
-        expires = np.where(greg_mask, greg_exp, now_ms + duration).astype(_I64)
-        for sh in range(n_sh):
-            if len(shard_idx[sh]):
-                self.tables[sh].set_expiry(
-                    shard_slots[sh], expires[shard_idx[sh]]
-                )
-
-        from gubernator_tpu.core.engine import PendingColumnar
-
+        set_expiry()  # 4.
         return PendingColumnar(self, pieces, limit, n)
 
     def _apply_columnar_native(
@@ -1175,45 +1216,56 @@ class ShardedDecisionEngine:
 
         n_sh = self.n_shards
         n = len(packed.offsets) - 1
-        expires = np.where(
-            greg_mask, greg_exp, np.int64(now_ms) + duration
-        ).astype(_I64)
-        (max_round, _shard, slots, rounds, order, counts,
-         evicted, evict_shard, evict_rounds) = multi_schedule(
-            self.tables, packed.buf, packed.offsets, route_hashes,
-            now_ms, expires,
-        )
-        flat = self._single_program and self._flat_ok
-        if flat:
-            # Globalize slots: shard*cap + slot.  The concatenated
-            # order array is then globally slot-sorted (global slot is
-            # monotone in (shard, slot)), so the whole batch dispatches
-            # as ONE flat program — no per-shard padded blocks.
-            gslots = (
-                slots.astype(np.int64)
-                + _shard.astype(np.int64) * self.shard_capacity
-            ).astype(_I32)
-            segs = [order]
-            seg_slots = gslots
-        else:
-            bounds = np.zeros(n_sh + 1, dtype=np.int64)
-            np.cumsum(counts, out=bounds[1:])
-            segs = [order[bounds[sh]:bounds[sh + 1]] for sh in range(n_sh)]
-            seg_slots = slots
-        clear_by_round: Dict[int, List[List[int]]] = {}
-        for s, sh, k in zip(
-            evicted.tolist(), evict_shard.tolist(), evict_rounds.tolist()
-        ):
-            clear_by_round.setdefault(k, [[] for _ in range(n_sh)])[
-                sh
-            ].append(s)
+        # One FFI call: the TTL mirror's writes ride it, so this route
+        # has no engine.set_expiry of its own.
+        with self._stage("engine.intern"):
+            expires = np.where(
+                greg_mask, greg_exp, np.int64(now_ms) + duration
+            ).astype(_I64)
+            (max_round, _shard, slots, rounds, order, counts,
+             evicted, evict_shard, evict_rounds) = multi_schedule(
+                self.tables, packed.buf, packed.offsets, route_hashes,
+                now_ms, expires,
+            )
+        # The FFI call has hashed and grouped; what is left of the
+        # router on the host is cutting its order into per-shard lanes.
+        with self._stage("mesh.route"):
+            flat = self._single_program and self._flat_ok
+            if flat:
+                # Globalize slots: shard*cap + slot.  The concatenated
+                # order array is then globally slot-sorted (global slot
+                # is monotone in (shard, slot)), so the whole batch
+                # dispatches as ONE flat program — no per-shard padded
+                # blocks.
+                gslots = (
+                    slots.astype(np.int64)
+                    + _shard.astype(np.int64) * self.shard_capacity
+                ).astype(_I32)
+                segs = [order]
+                seg_slots = gslots
+            else:
+                bounds = np.zeros(n_sh + 1, dtype=np.int64)
+                np.cumsum(counts, out=bounds[1:])
+                segs = [
+                    order[bounds[sh]:bounds[sh + 1]] for sh in range(n_sh)
+                ]
+                seg_slots = slots
+            lane_slots = [seg_slots[seg] for seg in segs]
+            clear_by_round: Dict[int, List[List[int]]] = {}
+            for s, sh, k in zip(
+                evicted.tolist(), evict_shard.tolist(), evict_rounds.tolist()
+            ):
+                clear_by_round.setdefault(k, [[] for _ in range(n_sh)])[
+                    sh
+                ].append(s)
 
         if max_round > 0:
-            per_shard = [
-                (seg, seg_slots[seg]) if len(seg) else None for seg in segs
-            ]
             pieces = self._collapse_presorted(
-                per_shard, clear_by_round, algo, behavior, hits, limit,
+                [
+                    (seg, sl) if len(seg) else None
+                    for seg, sl in zip(segs, lane_slots)
+                ],
+                clear_by_round, algo, behavior, hits, limit,
                 duration, burst, greg_dur, greg_exp, now_ms, flat=flat,
             )
             if pieces is not None:
@@ -1222,19 +1274,20 @@ class ShardedDecisionEngine:
         pieces = []
         for k in range(max_round + 1):
             if max_round == 0:
-                members = segs
+                members, m_slots = segs, lane_slots
             else:
-                # Round filtering preserves the per-shard slot sort.
-                members = [
-                    seg[rounds[seg] == k] if len(seg) else seg
-                    for seg in segs
-                ]
+                with self._stage("engine.pack"):
+                    # Round filtering preserves the per-shard slot sort.
+                    members = [
+                        seg[rounds[seg] == k] if len(seg) else seg
+                        for seg in segs
+                    ]
+                    m_slots = [seg_slots[m] for m in members]
             if not any(len(m) for m in members) and k not in clear_by_round:
                 continue
             clears = clear_by_round.get(k)
             if clears is not None:
                 self._apply_shard_clears(clears)
-            m_slots = [seg_slots[m] for m in members]
             offset = 0
             while True:
                 chunk_members = [
@@ -1284,8 +1337,6 @@ class ShardedDecisionEngine:
         once and reuse the sharded collapse.  Returns False for the
         rounds fallback (see core.engine._collapse_dataclass)."""
         from gubernator_tpu.ops.bucket_kernel import unpack_out_host
-        from gubernator_tpu.utils.tracing import span
-
         if any(k > 0 for k in clear_rounds):
             return False
         n_sh = self.n_shards
@@ -1376,14 +1427,15 @@ class ShardedDecisionEngine:
         """Per-shard duplicate-segment collapse; returns pieces or None
         for the rounds fallback (same preconditions as the single-device
         engine's _try_collapse)."""
-        per_shard: List[Optional[tuple]] = []
-        for sh in range(self.n_shards):
-            idx = shard_idx[sh]
-            if len(idx) == 0:
-                per_shard.append(None)
-                continue
-            order = np.argsort(shard_slots[sh], kind="stable")
-            per_shard.append((idx[order], shard_slots[sh][order]))
+        with self._stage("engine.pack"):
+            per_shard: List[Optional[tuple]] = []
+            for sh in range(self.n_shards):
+                idx = shard_idx[sh]
+                if len(idx) == 0:
+                    per_shard.append(None)
+                    continue
+                order = np.argsort(shard_slots[sh], kind="stable")
+                per_shard.append((idx[order], shard_slots[sh][order]))
         return self._collapse_presorted(
             per_shard, clear_by_round, algo, behavior, hits, limit,
             duration, burst, greg_dur, greg_exp, now_ms,
@@ -1397,7 +1449,10 @@ class ShardedDecisionEngine:
         """Collapse over per-shard (src, s_slots) pairs already sorted
         by (slot, arrival) — the native multi_schedule order, or the
         argsort in _try_collapse_sharded.  flat=True: one pseudo-shard
-        of globalized slots (see _dispatch_sorted_chunk)."""
+        of globalized slots (see _dispatch_sorted_chunk).  engine.pack
+        is observed slice by slice (the gate, then each chunk's pack),
+        never around a dispatch: chunk k+1 packs while the mesh runs
+        chunk k."""
         from gubernator_tpu.ops.bucket_kernel import (
             COLLAPSED_IN_ROWS,
             pack_collapsed_host,
@@ -1413,108 +1468,98 @@ class ShardedDecisionEngine:
         rst_bit = int(Behavior.RESET_REMAINING)
         leaky = int(Algorithm.LEAKY_BUCKET)
 
-        for p in per_shard:
-            if p is None:
-                continue
-            src, s_slots = p
-            uniq, seg_start, counts = np.unique(
-                s_slots, return_index=True, return_counts=True
-            )
-            seg_of = np.repeat(np.arange(len(uniq), dtype=np.int64), counts)
-            dup = counts[seg_of] > 1
-            for col in cols:
-                cs = col[src]
-                if not np.array_equal(
-                    cs[dup], cs[seg_start][seg_of][dup]
+        with self._stage("engine.pack"):
+            for p in per_shard:
+                if p is None:
+                    continue
+                src, s_slots = p
+                uniq, seg_start, counts = np.unique(
+                    s_slots, return_index=True, return_counts=True
+                )
+                seg_of = np.repeat(
+                    np.arange(len(uniq), dtype=np.int64), counts
+                )
+                dup = counts[seg_of] > 1
+                for col in cols:
+                    cs = col[src]
+                    if not np.array_equal(
+                        cs[dup], cs[seg_start][seg_of][dup]
+                    ):
+                        return None
+                beh_s = behavior[src]
+                if bool((((beh_s & rst_bit) != 0) & dup).any()):
+                    return None
+                if bool(
+                    (((algo[src] == leaky) & (hits[src] < 0)) & dup).any()
                 ):
                     return None
-            beh_s = behavior[src]
-            if bool((((beh_s & rst_bit) != 0) & dup).any()):
-                return None
-            if bool(
-                (((algo[src] == leaky) & (hits[src] < 0)) & dup).any()
-            ):
-                return None
+            max_lanes = max(
+                (len(p[0]) for p in per_shard if p is not None), default=0
+            )
 
         clears = clear_by_round.get(0)
         if clears is not None:
             self._apply_shard_clears(clears)
 
-        max_lanes = max(
-            (len(p[0]) for p in per_shard if p is not None), default=0
-        )
+        if flat:
+            programs = (
+                self._flat_collapsed_fused,
+                self._flat_collapsed_compute, self._flat_scatter,
+            )
+        else:
+            programs = (
+                self._collapsed_fused, self._collapsed_compute,
+                self._step_scatter,
+            )
         pieces: List[tuple] = []
         empty64 = np.empty(0, dtype=_I64)
         for lo in range(0, max_lanes, self.max_kernel_width):
-            chunk_m = [
-                min(max(len(p[0]) - lo, 0), self.max_kernel_width)
-                if p is not None
-                else 0
-                for p in per_shard
-            ]
-            width = _pad_size(max(chunk_m))
-            buf = np.zeros((n_sh, COLLAPSED_IN_ROWS, width), dtype=_I32)
-            dst_rows: List[np.ndarray] = []
-            for sh in range(n_sh):
-                m = chunk_m[sh]
-                if m == 0:
+            with self._stage("engine.pack"):
+                chunk_m = [
+                    min(max(len(p[0]) - lo, 0), self.max_kernel_width)
+                    if p is not None
+                    else 0
+                    for p in per_shard
+                ]
+                width = _pad_size(max(chunk_m))
+                buf = np.zeros((n_sh, COLLAPSED_IN_ROWS, width), dtype=_I32)
+                dst_rows: List[np.ndarray] = []
+                for sh in range(n_sh):
+                    m = chunk_m[sh]
+                    if m == 0:
+                        pack_collapsed_host(
+                            width, now_ms, cap, np.empty(0, dtype=_I32),
+                            empty64,
+                            (empty64,) * 8,
+                            np.empty(0, dtype=_I32),
+                            np.empty(0, dtype=_I32),
+                            out=buf[sh],
+                        )
+                        dst_rows.append(np.empty(0, dtype=np.int64))
+                        continue
+                    src, s_slots = per_shard[sh]
+                    c_src = src[lo : lo + m]
+                    c_slots = s_slots[lo : lo + m]
+                    c_uniq, c_start, c_counts = np.unique(
+                        c_slots, return_index=True, return_counts=True
+                    )
+                    c_seg_of = np.repeat(
+                        np.arange(len(c_uniq), dtype=np.int64), c_counts
+                    )
+                    c_pos = np.arange(m, dtype=np.int64) - c_start[c_seg_of]
                     pack_collapsed_host(
-                        width, now_ms, cap, np.empty(0, dtype=_I32),
-                        empty64,
-                        (empty64,) * 8,
-                        np.empty(0, dtype=_I32), np.empty(0, dtype=_I32),
+                        width, now_ms, cap,
+                        np.ascontiguousarray(c_uniq, dtype=_I32),
+                        c_counts.astype(np.int64),
+                        tuple(col[c_src][c_start] for col in cols),
+                        c_seg_of.astype(_I32),
+                        c_pos.astype(_I32),
                         out=buf[sh],
                     )
-                    dst_rows.append(np.empty(0, dtype=np.int64))
-                    continue
-                src, s_slots = per_shard[sh]
-                c_src = src[lo : lo + m]
-                c_slots = s_slots[lo : lo + m]
-                c_uniq, c_start, c_counts = np.unique(
-                    c_slots, return_index=True, return_counts=True
-                )
-                c_seg_of = np.repeat(
-                    np.arange(len(c_uniq), dtype=np.int64), c_counts
-                )
-                c_pos = np.arange(m, dtype=np.int64) - c_start[c_seg_of]
-                pack_collapsed_host(
-                    width, now_ms, cap,
-                    np.ascontiguousarray(c_uniq, dtype=_I32),
-                    c_counts.astype(np.int64),
-                    tuple(col[c_src][c_start] for col in cols),
-                    c_seg_of.astype(_I32),
-                    c_pos.astype(_I32),
-                    out=buf[sh],
-                )
-                dst_rows.append(c_src)
-
-            import time as _time
+                    dst_rows.append(c_src)
 
             t0 = _time.monotonic()
-            pin = self._put(buf)
-            if flat:
-                if self._fused:
-                    self._state, pout = self._flat_collapsed_fused(
-                        self._state, pin
-                    )
-                    self.dispatches_total += 1
-                else:
-                    slot_dev, vals2, pout = self._flat_collapsed_compute(
-                        self._state, pin
-                    )
-                    self._state = self._flat_scatter(
-                        self._state, slot_dev, vals2
-                    )
-                    self.dispatches_total += 2
-            elif self._fused:
-                self._state, pout = self._collapsed_fused(self._state, pin)
-                self.dispatches_total += 1
-            else:
-                slot_dev, vals2, pout = self._collapsed_compute(
-                    self._state, pin
-                )
-                self._state = self._step_scatter(self._state, slot_dev, vals2)
-                self.dispatches_total += 2
+            pout = self._step(*programs, self._put(buf))
             self.round_duration.observe(_time.monotonic() - t0)
             self.rounds_total += 1
             pieces.append(
@@ -1574,76 +1619,74 @@ class ShardedDecisionEngine:
 
         n_sh = 1 if flat else self.n_shards
         cap = self.capacity if flat else self.shard_capacity
-        width = _pad_size(max((len(m) for m in members), default=1))
+        merging = merge_n is not None and self._use_psum_merge and not flat
 
-        buf = np.zeros((n_sh, PACKED_IN_ROWS, width), dtype=_I32)
-        dst_rows = []
-        empty_cols = np.empty(0, dtype=_I64)
-        for sh in range(n_sh):
-            m = len(members[sh])
-            if m == 0:
-                dst_rows.append(np.empty(0, dtype=np.int64))
+        with self._stage("engine.pack"):
+            width = _pad_size(max((len(m) for m in members), default=1))
+            buf = np.zeros((n_sh, PACKED_IN_ROWS, width), dtype=_I32)
+            dst_rows = []
+            empty_cols = np.empty(0, dtype=_I64)
+            for sh in range(n_sh):
+                m = len(members[sh])
+                if m == 0:
+                    dst_rows.append(np.empty(0, dtype=np.int64))
+                    pack_batch_host(
+                        width, now_ms, cap, np.empty(0, dtype=_I32),
+                        empty_cols, empty_cols, empty_cols, empty_cols,
+                        empty_cols, empty_cols, empty_cols, empty_cols,
+                        out=buf[sh],
+                    )
+                    continue
+                if presorted:
+                    idx_sorted = members[sh]
+                    slots_sorted = m_slots[sh]
+                else:
+                    order = np.argsort(m_slots[sh], kind="stable")
+                    idx_sorted = members[sh][order]
+                    slots_sorted = m_slots[sh][order]
                 pack_batch_host(
-                    width, now_ms, cap, np.empty(0, dtype=_I32),
-                    empty_cols, empty_cols, empty_cols, empty_cols,
-                    empty_cols, empty_cols, empty_cols, empty_cols,
+                    width,
+                    now_ms,
+                    cap,
+                    np.ascontiguousarray(slots_sorted, dtype=_I32),
+                    algo[idx_sorted],
+                    behavior[idx_sorted],
+                    hits[idx_sorted],
+                    limit[idx_sorted],
+                    duration[idx_sorted],
+                    burst[idx_sorted],
+                    greg_dur[idx_sorted],
+                    greg_exp[idx_sorted],
                     out=buf[sh],
                 )
-                continue
-            if presorted:
-                idx_sorted = members[sh]
-                slots_sorted = m_slots[sh]
-            else:
-                order = np.argsort(m_slots[sh], kind="stable")
-                idx_sorted = members[sh][order]
-                slots_sorted = m_slots[sh][order]
-            pack_batch_host(
-                width,
-                now_ms,
-                cap,
-                np.ascontiguousarray(slots_sorted, dtype=_I32),
-                algo[idx_sorted],
-                behavior[idx_sorted],
-                hits[idx_sorted],
-                limit[idx_sorted],
-                duration[idx_sorted],
-                burst[idx_sorted],
-                greg_dur[idx_sorted],
-                greg_exp[idx_sorted],
-                out=buf[sh],
-            )
-            dst_rows.append(idx_sorted)
-
-        import time as _time
+                dst_rows.append(idx_sorted)
 
         t0 = _time.monotonic()
-        pin = self._put(buf)
         if flat:
-            if self._fused:
-                self._state, pout = self._flat_fused(self._state, pin)
-                self.dispatches_total += 1
-            else:
-                slot_dev, vals, pout = self._flat_compute(self._state, pin)
-                self._state = self._flat_scatter(self._state, slot_dev, vals)
-                self.dispatches_total += 2
-        elif self._fused:
-            self._state, pout = self._packed_fused(self._state, pin)
-            self.dispatches_total += 1
+            programs = (
+                self._flat_fused, self._flat_compute, self._flat_scatter
+            )
         else:
-            slot_dev, vals, pout = self._packed_compute(self._state, pin)
-            self._state = self._step_scatter(self._state, slot_dev, vals)
-            self.dispatches_total += 2
-        if merge_n is not None and self._use_psum_merge and not flat:
+            programs = (
+                self._packed_fused, self._packed_compute, self._step_scatter
+            )
+        pout = self._step(*programs, self._put(buf))
+        if merging:
             # psum GLOBAL merge: scatter every shard's lanes to their
             # request positions on device and sum across the mesh —
-            # one replicated, already-request-ordered readback.
-            n_pad = _pad_size(merge_n)
-            pos = np.full((n_sh, width), n_pad, dtype=_I32)
-            for sh in range(n_sh):
-                if len(dst_rows[sh]):
-                    pos[sh, : len(dst_rows[sh])] = dst_rows[sh]
-            merged = self._merge_prog(n_pad, width)(pout, self._put(pos))
-            self.dispatches_total += 1
+            # one replicated, already-request-ordered readback.  The
+            # positions pack while the mesh runs the step.
+            with self._stage("engine.pack"):
+                n_pad = _pad_size(merge_n)
+                pos = np.full((n_sh, width), n_pad, dtype=_I32)
+                for sh in range(n_sh):
+                    if len(dst_rows[sh]):
+                        pos[sh, : len(dst_rows[sh])] = dst_rows[sh]
+            pos_dev = self._put(pos)
+            merge = self._merge_prog(n_pad, width)
+            with self._stage("device.launch"):
+                merged = merge(pout, pos_dev)
+                self.dispatches_total += 1
             self.round_duration.observe(_time.monotonic() - t0)
             return (
                 self.readback.register(merged),

@@ -39,6 +39,7 @@ from gubernator_tpu.cluster.health import backoff_delay
 from gubernator_tpu.cluster.multiregion import MultiRegionManager
 from gubernator_tpu.cluster.peer_client import PeerClient, PeerError
 from gubernator_tpu.config import BehaviorConfig, Config
+from gubernator_tpu.utils.metrics import DurationStat, stage
 from gubernator_tpu.types import (
     MAX_BATCH_SIZE,
     Algorithm,
@@ -377,8 +378,6 @@ class V1Instance:
         # MembershipManager (cluster/membership.py), set by the daemon
         # after construction; None for bare library instances.
         self.membership = None
-        from gubernator_tpu.utils.metrics import DurationStat
-
         # Peer-flush duration summary, shared by every PeerClient this
         # instance creates (reference: guber_batch_send_duration).
         self.flush_duration = DurationStat()
@@ -413,6 +412,15 @@ class V1Instance:
         round_dur = getattr(engine, "round_duration", None)
         if round_dur is not None:
             self.stage_timers["device.step"] = round_dur
+        # The served wire route, stage by stage (utils/metrics.stage;
+        # OBSERVABILITY.md §3): the codec's two ends here, the engine's
+        # own stages (lock wait/hold, intern, pack, h2d, launch,
+        # set_expiry, unpack, sweep; mesh.route on the sharded engine)
+        # from the engine, the listener's queue wait from the daemon.
+        self.stage_timers["wire.decode"] = DurationStat()
+        self.stage_timers["service.hotkeys"] = DurationStat()
+        self.stage_timers["wire.encode"] = DurationStat()
+        self.stage_timers.update(getattr(engine, "stages", {}))
         transfer = getattr(
             getattr(engine, "readback", None), "transfer_duration", None
         )
@@ -1001,7 +1009,8 @@ class V1Instance:
         self.counters["replicated_local"] += dec.n
         self.counters["columnar"] += dec.n
         self._offer_hotkeys(dec)
-        return wire_codec.encode_resps(
+        return self._encode(
+            wire_codec.encode_resps,
             st.astype(np.int32), np.asarray(dec.limit, dtype=np.int64),
             rem, rst,
         )
@@ -1013,17 +1022,20 @@ class V1Instance:
         hk = self.hotkeys
         if hk is None:
             return
-        lim = np.asarray(dec.limit)
-        elig = (
-            (np.asarray(dec.algo) == _TOKEN_I)
-            & ((np.asarray(dec.behavior) & _LEASE_BREAKERS) == 0)
-            & (lim > 0)
-        )
-        hk.offer_columns(
-            dec.key_buf, dec.key_offsets, dec.hits, idx=idx,
-            hashes=dec.fnv1a, limit=np.where(elig, lim, 0),
-            duration=dec.duration,
-        )
+        # A per-item Python pass on the RPC's own thread, before the
+        # engine lock: milliseconds a 1,000-item RPC (PERF.md §5).
+        with stage("service.hotkeys", self.stage_timers["service.hotkeys"]):
+            lim = np.asarray(dec.limit)
+            elig = (
+                (np.asarray(dec.algo) == _TOKEN_I)
+                & ((np.asarray(dec.behavior) & _LEASE_BREAKERS) == 0)
+                & (lim > 0)
+            )
+            hk.offer_columns(
+                dec.key_buf, dec.key_offsets, dec.hits, idx=idx,
+                hashes=dec.fnv1a, limit=np.where(elig, lim, 0),
+                duration=dec.duration,
+            )
 
     def serve_decoded_local(self, dec):
         """Shared post-decode columnar serve for the native fronts —
@@ -1097,6 +1109,11 @@ class V1Instance:
             return out
         return plan.merge_outputs(st, rem, rst)
 
+    def _encode(self, encoder, *columns) -> bytes:
+        """The columnar routes' response encode (net/wire_codec)."""
+        with stage("wire.encode", self.stage_timers["wire.encode"]):
+            return encoder(*columns)
+
     def serve_wire_bytes(
         self, raw: bytes, *, check_ownership: bool = True
     ) -> Optional[bytes]:
@@ -1120,10 +1137,11 @@ class V1Instance:
         # Decode with GLOBAL/SKETCH allowed: all-GLOBAL and all-SKETCH
         # batches have their own columnar routes below; mixed batches
         # decline to the pb path.
-        dec = wire_codec.decode_reqs(
-            bytes(raw), MAX_BATCH_SIZE,
-            COLUMNAR_DISQUALIFIERS & ~_GLOBAL_I & ~_SKETCH_I,
-        )
+        with stage("wire.decode", self.stage_timers["wire.decode"]):
+            dec = wire_codec.decode_reqs(
+                bytes(raw), MAX_BATCH_SIZE,
+                COLUMNAR_DISQUALIFIERS & ~_GLOBAL_I & ~_SKETCH_I,
+            )
         if dec is None:
             return None
         s_mask = (dec.behavior & _SKETCH_I) != 0
@@ -1144,7 +1162,7 @@ class V1Instance:
                     dec.key_buf, dec.key_offsets, dec.hits,
                     hashes=dec.fnv1a,
                 )
-            return wire_codec.encode_resps(st, lim, rem, rst)
+            return self._encode(wire_codec.encode_resps, st, lim, rem, rst)
         g_mask = (dec.behavior & _GLOBAL_I) != 0
         if g_mask.any():
             if not g_mask.all():
@@ -1173,7 +1191,7 @@ class V1Instance:
             if out is None:
                 return None
             st, lim, rem, rst = out
-            return wire_codec.encode_resps(st, lim, rem, rst)
+            return self._encode(wire_codec.encode_resps, st, lim, rem, rst)
         packed = PackedKeys(dec.key_buf, dec.key_offsets, dec.n)
         t_serve = time.monotonic()
         if hasattr(engine, "tables"):  # sharded: codec hashes route shards
@@ -1189,7 +1207,7 @@ class V1Instance:
         self.stage_timers["engine_serve"].observe(
             time.monotonic() - t_serve
         )
-        return wire_codec.encode_resps(st, lim, rem, rst)
+        return self._encode(wire_codec.encode_resps, st, lim, rem, rst)
 
     def _serve_columnar_ledger(self, dec) -> Optional[bytes]:
         """The local columnar route through the decision ledger: rows
@@ -1204,7 +1222,7 @@ class V1Instance:
         plan = self.ledger.plan(dec, engine.clock.now_ms())
         if plan.full:
             st, lim, rem, rst = plan.dense_cols()
-            return wire_codec.encode_resps(st, lim, rem, rst)
+            return self._encode(wire_codec.encode_resps, st, lim, rem, rst)
         lane = plan.build_engine_lane()
         out = self._dispatch_lane(lane)
         if out is None:
@@ -1213,8 +1231,10 @@ class V1Instance:
         st, lim, rem, rst = out
         plan.learn(st, lim, rem, rst)
         if not plan.answered_rows and lane is dec:
-            return wire_codec.encode_resps(st, lim, rem, rst)
-        return wire_codec.encode_resps(*plan.merge_outputs(st, rem, rst))
+            return self._encode(wire_codec.encode_resps, st, lim, rem, rst)
+        return self._encode(
+            wire_codec.encode_resps, *plan.merge_outputs(st, rem, rst)
+        )
 
     def _dispatch_lane(self, lane):
         """Run one engine-lane column set through the group-commit
@@ -1477,10 +1497,13 @@ class V1Instance:
         self.counters["columnar"] += n
         self._offer_hotkeys(dec)
         if owner_strs:
-            return wire_codec.encode_resps_owner(
-                status, limit, remaining, reset, owner_meta_idx, owner_strs
+            return self._encode(
+                wire_codec.encode_resps_owner,
+                status, limit, remaining, reset, owner_meta_idx, owner_strs,
             )
-        return wire_codec.encode_resps(status, limit, remaining, reset)
+        return self._encode(
+            wire_codec.encode_resps, status, limit, remaining, reset
+        )
 
     def apply_columnar_local(
         self,

@@ -19,6 +19,8 @@ from __future__ import annotations
 
 import os
 import threading
+import time
+from math import frexp as _frexp
 from typing import TYPE_CHECKING, Iterable, Sequence
 
 from prometheus_client.core import (
@@ -29,6 +31,8 @@ from prometheus_client.core import (
 )
 from prometheus_client.registry import Collector, CollectorRegistry
 from prometheus_client.samples import Exemplar
+
+from gubernator_tpu.utils import tracing
 
 if TYPE_CHECKING:
     from gubernator_tpu.service import V1Instance
@@ -109,13 +113,11 @@ class DurationStat:
 
     @classmethod
     def bucket_of(cls, seconds: float) -> int:
-        import math
-
         if seconds <= cls._BASE:
             return 0
         # frexp is exact and ~3x cheaper than log2 here: for
         # m * 2^e with m in [0.5, 1), floor(log2(x)) == e - 1.
-        _m, e = math.frexp(seconds / cls._BASE)
+        _m, e = _frexp(seconds / cls._BASE)
         return min(cls.N_BUCKETS - 1, max(0, e - 1))
 
     @classmethod
@@ -130,8 +132,6 @@ class DurationStat:
         # off the per-decision path; a disabled tracer short-circuits
         # at one global check.
         if exemplars_enabled():
-            from gubernator_tpu.utils import tracing
-
             if tracing.active():
                 ctx = tracing.current_context()
                 if ctx is not None and ctx.sampled:
@@ -210,8 +210,6 @@ class DurationStat:
             out = dict(self.exemplars)
         if not out:
             return out
-        from gubernator_tpu.utils import tracing
-
         has = getattr(tracing.current_tracer(), "has_trace", None)
         if has is None:
             return out
@@ -270,6 +268,108 @@ class DurationStat:
             "p99_ms": round(self.p99() * 1e3, digits),
             "max_ms": round(max_s * 1e3, digits),
         }
+
+
+# The served path's stage vocabulary (OBSERVABILITY.md §3, PERF.md §3):
+# one name per site for the histogram, the request span and the
+# profiler annotation.  Both engines create exactly these stats
+# (`engine_stages`); the service registers them in `stage_timers`, so
+# /metrics, /debug/vars and obs/fleet.py carry them without further
+# code.  `device.step` / `device.readback` / `device.window_wait`
+# predate the primitive and live on the objects that own them.
+ENGINE_STAGES = (
+    "engine.lock_wait",
+    "engine.lock_hold",
+    "engine.intern",
+    "engine.pack",
+    "device.h2d",
+    "device.launch",
+    "engine.set_expiry",
+    "engine.unpack",
+    "engine.sweep",
+)
+
+
+def engine_stages(extra: Sequence[str] = ()) -> dict:
+    """A fresh {stage name: DurationStat} for one engine."""
+    return {name: DurationStat() for name in ENGINE_STAGES + tuple(extra)}
+
+
+_annotation_cls = None  # jax.profiler.TraceAnnotation, False when jax is absent
+
+
+def _trace_annotation(name: str):
+    """A TraceMe for the profiler's host plane, or None where jax is
+    absent (the jax-free smoke stubs).  Inert unless a profile is being
+    captured; then it lands in the same xplane as the device ops, on
+    the profiler's one timeline."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _annotation_cls = TraceAnnotation
+        except ImportError:
+            _annotation_cls = False
+    return _annotation_cls(name) if _annotation_cls else None
+
+
+class stage:
+    """One measured site of the served path, three sinks under one
+    name: it always observes `stat` (two clock reads and one observe);
+    while the tracer is active it is a child span of the RPC's tree
+    (utils/tracing.py, the flight recorder's feed); and when `work`
+    is true the body runs under a `jax.profiler.TraceAnnotation`.
+
+    Two rules keep the profiler's timeline attributable (a gap
+    between device ops is named by the host event that overlaps it
+    most): annotate LEAF stages only — never one `work` stage inside
+    another on a thread — and pass `work=False` for a WAIT (lock wait,
+    window wait, a blocking readback): a waiting thread would claim
+    every gap that another thread's work caused.
+
+    A context manager; `start()` / `stop()` serve the one shape a
+    `with` cannot: an interval that ends inside the block that follows
+    it (waiting for a lock that a `with` then holds)."""
+
+    __slots__ = ("_name", "_stat", "_work", "_cm", "span", "_ann", "_t0")
+
+    def __init__(self, name: str, stat: DurationStat, work: bool = True):
+        self._name = name
+        self._stat = stat
+        self._work = work
+        self._cm = None
+        self.span = None  # the open request span, for attributes
+        self._ann = None
+
+    def start(self) -> "stage":
+        # The clock first and last: what the instrumentation itself
+        # costs belongs to the stage it instruments, not to nobody
+        # (the leaf stages under the engine lock tile engine.lock_hold).
+        self._t0 = time.monotonic()
+        if tracing.active():
+            self._cm = tracing.span(self._name)
+            self.span = self._cm.__enter__()
+        if self._work:
+            self._ann = _trace_annotation(self._name)
+            if self._ann is not None:
+                self._ann.__enter__()
+        return self
+
+    def stop(self, exc_type=None, exc=None, tb=None) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        # Observed inside the span: the histogram's exemplar links the
+        # bucket to this RPC's trace.
+        self._stat.observe(time.monotonic() - self._t0)
+        if self._cm is not None:
+            self._cm.__exit__(exc_type, exc, tb)
+
+    __enter__ = start
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.stop(exc_type, exc, tb)
+        return False
 
 
 class InstanceCollector(Collector):
@@ -706,22 +806,17 @@ class InstanceCollector(Collector):
         )
         yield s
 
-        s = SummaryMetricFamily(
-            "gubernator_engine_round_duration",
-            "Seconds of host-side dispatch per device kernel round.",
-            count_value=eng.round_duration.count,
-            sum_value=eng.round_duration.total,
-        )
-        yield s
-
-        # The cluster-tier p50 budget, stage by stage (VERDICT r5
-        # next-round #3): client window wait, engine serve, hit-window
-        # wait, owner RPC, and broadcast enqueue→delivered age.  The
-        # serial sum of these stage means IS the GLOBAL path's median
-        # budget; PERF.md §10 publishes the measured table.
+        # The latency budget, stage by stage: the cluster tier's
+        # (client window wait, engine serve, hit-window wait, owner
+        # RPC, broadcast enqueue→delivered age) and the served wire
+        # route's (OBSERVABILITY.md §3: listener.queue_wait,
+        # wire.decode/encode, engine.*, device.h2d/launch/readback,
+        # mesh.route).  device.step is the host's ENQUEUE wall of one
+        # dispatch (= device.h2d + device.launch), not device time.
         s = SummaryMetricFamily(
             "gubernator_stage_duration",
-            "Seconds per GLOBAL-path pipeline stage.",
+            "Seconds per pipeline stage (host wall; device.step is "
+            "the enqueue of one dispatch, not device time).",
             labels=["stage"],
         )
         for stage, stat in inst.stage_timers.items():

@@ -15,6 +15,7 @@ import pytest
 
 from gubernator_tpu.cluster.batch_loop import AdaptiveWait, IntervalBatcher
 from gubernator_tpu.net.wire_window import WireWindow
+from gubernator_tpu.utils.metrics import ENGINE_STAGES
 
 
 def _combine(existing, item):
@@ -144,7 +145,14 @@ STAGES = (
     # Cross-region hop budget (ISSUE 14 / RESILIENCE.md §12).
     "multiregion.window_wait",
     "multiregion.region_rpc",
-)
+    # The served wire route, stage by stage (ISSUE 24 /
+    # OBSERVABILITY.md §3): the listener's and the service's here, the
+    # engine's own from utils/metrics.ENGINE_STAGES.
+    "listener.queue_wait",
+    "wire.decode",
+    "service.hotkeys",
+    "wire.encode",
+) + ENGINE_STAGES
 
 
 def test_global_pipeline_reports_all_stage_timers():

@@ -1662,6 +1662,56 @@ def test_drift_span_twin_suppression_respected(tmp_path):
     assert not any(f.rule.startswith("span-name") for f in findings)
 
 
+@pytest.mark.parametrize("suppressed", [False, True])
+def test_drift_stage_sites_are_phases_with_one_home_module(tmp_path, suppressed):
+    """stage("name") / self._stage("name") sites: snake-dot style like
+    spans; several sites in one module are one phase; a second module
+    timing the same stage is a twin and says so at its first site."""
+    from tools.guberlint import driftcheck
+
+    root = _drift_repo(tmp_path)
+    (root / "gubernator_tpu" / "a_engine.py").write_text(
+        textwrap.dedent(
+            """
+            from gubernator_tpu.utils.metrics import stage
+
+            def f(stat):
+                with stage("engine.pack", stat):
+                    pass
+                with stage("engine.pack", stat):
+                    pass
+                with stage("Bad.Name", stat):
+                    pass
+            """
+        )
+    )
+    mark = "# guberlint: ok drift — sharded twin of a_engine.py's engine.pack"
+    (root / "gubernator_tpu" / "b_sharded.py").write_text(
+        textwrap.dedent(
+            f"""
+            class E:
+                def f(self):
+                    {mark if suppressed else "pass"}
+                    with self._stage("engine.pack"):
+                        pass
+                    with self._stage("engine.pack"):
+                        pass
+            """
+        )
+    )
+    findings = driftcheck.check(root, [])
+    rules = [(f.rule, f.detail, f.file) for f in findings
+             if f.rule.startswith("span-name")]
+    assert ("span-name-style", "Bad.Name", "gubernator_tpu/a_engine.py") in rules
+    twins = [r for r in rules if r[0] == "span-name-duplicate"]
+    if suppressed:
+        assert twins == []
+    else:
+        assert twins == [
+            ("span-name-duplicate", "engine.pack", "gubernator_tpu/b_sharded.py")
+        ]
+
+
 def test_drift_span_variable_name_not_scanned(tmp_path):
     """Helper-routed spans (variable name argument) are outside the
     literal catalog — no style/duplicate findings for them."""

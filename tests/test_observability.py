@@ -41,13 +41,14 @@ def test_grpc_stats_and_metric_catalog():
             "gubernator_batch_send_duration",
             "gubernator_global_send_duration",
             "gubernator_broadcast_duration",
-            "gubernator_engine_round_duration",
+            "gubernator_stage_duration",
         ):
             assert name in body, name
-        # Round-duration summary must move under load (the request
-        # above ran at least one device round).
-        assert _sample(body, "gubernator_engine_round_duration_count") >= 1
-        assert _sample(body, "gubernator_engine_round_duration_sum") > 0
+        # The dispatch's enqueue wall (stage device.step) must move
+        # under load (the request above ran at least one device round).
+        step = 'gubernator_stage_duration_%s{stage="device.step"}'
+        assert _sample(body, step % "count") >= 1
+        assert _sample(body, step % "sum") > 0
     finally:
         h.stop()
 

@@ -25,7 +25,12 @@ The operator contract, enforced (STATIC_ANALYSIS.md):
   a name make "where did this span come from" unanswerable.
   Deliberate twins (the sharded engine mirrors engine.py's stages
   under the same names so the tests/oracles stay backend-agnostic)
-  carry reasoned suppressions at the twin site.
+  carry reasoned suppressions at the twin site.  A literal
+  ``stage("name", ...)`` / ``self._stage("name")`` site
+  (utils/metrics.stage: histogram, span and profiler annotation under
+  one name) is held to the same style; a stage is a PHASE, so one
+  module may time it at several sites, but a second module that
+  times the same stage is a twin and says so, once, at its first site.
 - ``drift-slo-metric-unregistered`` / ``drift-slo-no-metric`` — the
   slo sub-rule: every ``SLI(...)`` declaration in config.SLO_REGISTRY
   (obs/slo.py) must carry a literal ``metric=`` naming a series
@@ -36,8 +41,8 @@ The operator contract, enforced (STATIC_ANALYSIS.md):
 Knob reads are collected from the AST (string literals used as call
 arguments), so prose/docstrings never count as reads; metric
 registrations are the first-argument literals of ``*MetricFamily``
-constructors; span sites are calls to a function named ``span`` with
-a literal first argument.  Suppression uses the normal grammar at the
+constructors; span sites are calls to a function named ``span``
+(``stage`` / ``_stage`` for stage sites) with a literal first argument.  Suppression uses the normal grammar at the
 read / registration / span site.
 """
 
@@ -180,14 +185,17 @@ def _check_knobs(
 _SPAN_NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z][a-z0-9_]*)*$")
 
 
+_SPAN_CALLS = {"span": "span", "stage": "stage", "_stage": "stage"}
+
+
 def _span_sites(
     repo_root: Path,
-) -> List[Tuple[str, SourceFile, int]]:
-    """(name, source, line) for every literal span("name", ...) call
-    under KNOB_SCAN_ROOTS.  Helper-routed spans (a variable name
-    argument) are invisible here by design — the rule governs the
-    literal catalog OBSERVABILITY.md indexes."""
-    out: List[Tuple[str, SourceFile, int]] = []
+) -> List[Tuple[str, SourceFile, int, str]]:
+    """(name, source, line, kind) for every literal span("name", ...)
+    or stage("name", ...) call under KNOB_SCAN_ROOTS.  Helper-routed
+    spans (a variable name argument) are invisible here by design —
+    the rule governs the literal catalog OBSERVABILITY.md indexes."""
+    out: List[Tuple[str, SourceFile, int, str]] = []
     roots = [repo_root / r for r in KNOB_SCAN_ROOTS]
     for src in iter_py_files(roots, repo_root, exclude=EXCLUDE):
         if src.tree is None:
@@ -200,20 +208,20 @@ def _span_sites(
                 func.attr if isinstance(func, ast.Attribute)
                 else func.id if isinstance(func, ast.Name) else ""
             )
-            if name != "span":
-                continue
-            if not node.args:
+            kind = _SPAN_CALLS.get(name)
+            if kind is None or not node.args:
                 continue
             arg = node.args[0]
             if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
-                out.append((arg.value, src, node.lineno))
+                out.append((arg.value, src, node.lineno, kind))
+    out.sort(key=lambda site: (site[1].rel, site[2]))
     return out
 
 
 def _check_spans(repo_root: Path, findings: List[Finding]) -> None:
     sites = _span_sites(repo_root)
     by_name: Dict[str, List[Tuple[SourceFile, int]]] = {}
-    for name, src, line in sites:
+    for name, src, line, kind in sites:
         if not _SPAN_NAME_RE.match(name):
             if not src.suppressed(line, PASS):
                 findings.append(
@@ -225,7 +233,12 @@ def _check_spans(repo_root: Path, findings: List[Finding]) -> None:
                         "+ OTel query surface (OBSERVABILITY.md)",
                     )
                 )
-        by_name.setdefault(name, []).append((src, line))
+        where = by_name.setdefault(name, [])
+        # A stage is a phase of its module's path: its later sites in
+        # a module that already has one are the same phase, not twins.
+        if kind == "stage" and any(s.rel == src.rel for s, _l in where):
+            continue
+        where.append((src, line))
     for name, where in sorted(by_name.items()):
         if len(where) < 2:
             continue
