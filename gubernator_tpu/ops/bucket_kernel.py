@@ -349,6 +349,34 @@ def split_remf(v: jax.Array) -> tuple[jax.Array, jax.Array]:
     return wc.astype(_I32), jnp.floor((v - w) * (2.0**32)).astype(_U32)
 
 
+# How a scatter into a state column is declared to XLA.  With
+# `indices_are_sorted` + `unique_indices` XLA:TPU merges the updates
+# into ONE STREAMING PASS over the whole column, ~12 ps a row of the
+# table whatever the width: 29-69 µs at 1 M rows, where round 2 measured
+# it "~200x faster" than the loop (PERF_HISTORY §4), and 1.18-1.24 ms a
+# column, 14.3 ms a twelve-column step, at 100 M rows (ledger, PR 24).
+# Without them it is, on a large table, an in-place loop over the lanes:
+# 15 / 109 / 748 µs at 64 / 1,024 / 8,192 lanes and 100 M rows; on a
+# small one XLA finds the pass's price by itself (1 M rows: equal at
+# every width).  The hints are a promise about the indices, never a
+# requirement, so each scatter makes it only where the pass is the
+# cheaper form — a rule on the two shapes the program is compiled for,
+# no setting — which was the cheaper form, or within 3 µs of it, at all twelve
+# points of 1 M-100 M rows × 64-8,192 lanes (chip run, PR 25,
+# scripts/probe_state_access.py; PERF.md §6).  Gathers are no pass in
+# either form (15-31 µs) and keep the hints.
+_SCATTER_PASS_ROWS_PER_LANE = 8192
+
+
+def _scatter_hints(rows: int, lanes: int) -> dict:
+    """Keyword hints for `column.at[slots].set(...)`, `slots` sorted
+    and unique: `column` has `rows` rows, `slots` has `lanes` lanes."""
+    pass_is_cheaper = rows < _SCATTER_PASS_ROWS_PER_LANE * lanes
+    return dict(
+        indices_are_sorted=pass_is_cheaper, unique_indices=pass_is_cheaper
+    )
+
+
 # guberlint: shapes meta [capacity] fixed at engine build; slots [C], C in the pow2 clear ladder (warmup)
 def _clear_occupied_impl(meta: jax.Array, slots: jax.Array) -> jax.Array:
     """Mark evicted slots unoccupied (host eviction executed on device).
@@ -358,14 +386,16 @@ def _clear_occupied_impl(meta: jax.Array, slots: jax.Array) -> jax.Array:
     eviction bursts then never trigger apply-kernel recompiles.
     Padding lanes use distinct ascending out-of-range slots.  With the
     packed layout this is a sparse read-modify-write of the meta word
-    (clear bit 0); the gather+scatter touch O(clears) cells only."""
+    (clear bit 0).  The gather touches O(clears) cells; the scatter
+    does too only where `_scatter_hints` withholds the hints — with
+    them it is a pass over the meta column."""
     s = jnp.sort(slots)
     cur = meta.at[s].get(
         mode="fill", fill_value=0, indices_are_sorted=True,
         unique_indices=True,
     )
     return meta.at[s].set(
-        cur & ~1, mode="drop", indices_are_sorted=True, unique_indices=True
+        cur & ~1, mode="drop", **_scatter_hints(meta.shape[0], s.shape[0])
     )
 
 
@@ -386,15 +416,17 @@ def _apply_batch_impl(
     cap = state.meta.shape[0]
     now = now_ms.astype(_I64)
 
-    # TPU gather/scatter with arbitrary indices lowers to a serial
-    # per-element loop (~1µs each — measured 8ms for an 8k batch).  With
-    # `indices_are_sorted` + `unique_indices` the same ops are ~200x
-    # faster.  Rounds guarantee uniqueness (engine invariant); sortedness
-    # comes from co-sorting the whole batch by slot with one multi-
-    # operand lax.sort (a sorting network — no random access), and
+    # A TPU scatter with arbitrary indices lowers to a serial loop over
+    # the lanes (~1µs a lane for the twelve columns — measured 8ms for
+    # an 8k batch); with `indices_are_sorted` + `unique_indices` it was
+    # ~200x faster AT THE <= 2 M ROWS OF ROUND 2, and is a pass over the
+    # table that costs 14 ms a step at 100 M rows (`_scatter_hints`
+    # chooses).  Rounds guarantee uniqueness (engine invariant);
+    # sortedness comes from co-sorting the whole batch by slot with one
+    # multi-operand lax.sort (a sorting network — no random access), and
     # responses are restored to request order by a second sort keyed on
     # the lane index.  Padding uses distinct ascending out-of-range
-    # slots (cap + lane) so both flags stay truthful.
+    # slots (cap + lane) so both flags stay truthful wherever given.
     lane = jnp.arange(batch.slot.shape[0], dtype=_I32)
     (
         slot,
@@ -862,16 +894,19 @@ def _scatter_values(
     buffer forces XLA's copy-insertion to clone every state array —
     measured 18 full-capacity copies (~41ms at 2M slots, O(capacity)
     per batch) before the kernel was split into compute + scatter.
-    `slot` is sorted with distinct out-of-range padding → flags hold;
-    out-of-range (padding) lanes are dropped.
+    `slot` is sorted with distinct out-of-range padding → the hints
+    hold wherever `_scatter_hints` gives them (a small table: one
+    streaming pass a column; at 100 M rows that pass was 1.19 ms a
+    column and the whole of the step's 14 ms, so there the scatter
+    goes without them, ~0.1 µs a lane); out-of-range (padding) lanes
+    are dropped.
     """
 
     def sc(arr, v):
         return arr.at[slot].set(
             v.astype(arr.dtype),
             mode="drop",
-            indices_are_sorted=True,
-            unique_indices=True,
+            **_scatter_hints(arr.shape[0], slot.shape[0]),
         )
 
     words = encode_slot_values(vals)
@@ -1573,11 +1608,15 @@ def _load_slots_impl(state: BucketState, rec: SlotRecord) -> BucketState:
     """Hydrate persisted bucket values into their slots.
 
     The scatter contract matches the apply kernel: `rec.slot` sorted,
-    unique, padding out-of-range (dropped)."""
+    unique, padding out-of-range (dropped).  `_scatter_hints` keeps
+    the pass a column for a bulk load (more than a lane per 8,192
+    rows) and spares it a single key's hydration."""
 
     def put(arr, vals):
         return arr.at[rec.slot].set(
-            vals, mode="drop", indices_are_sorted=True, unique_indices=True
+            vals,
+            mode="drop",
+            **_scatter_hints(arr.shape[0], rec.slot.shape[0]),
         )
 
     def put64(hi, lo, v):

@@ -75,6 +75,12 @@ def test_register_discover_and_watch(mini_etcd):
     pool_b, daemon_b, client_b = _pool(mini_etcd, "127.0.0.1:9102")
     try:
         pool_a.start()
+        # MiniEtcdServer delivers live events only (no start_revision
+        # replay): B must register after A's watch reached the server,
+        # or the event is lost to the double, not to the pool.
+        deadline = time.monotonic() + 5
+        while not mini_etcd._watchers and time.monotonic() < deadline:
+            time.sleep(0.01)
         pool_b.start()
         # B registered after A started: A's watch must deliver B.
         deadline = time.monotonic() + 5

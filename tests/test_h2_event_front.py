@@ -268,7 +268,14 @@ def test_idle_connection_reaped(daemon):
         ]
         sock.close()
         assert 7 in types, f"no GOAWAY before close (saw {types})"
-        cs = front.conn_stats()
+        # The reactor books the reap after it has written the GOAWAY:
+        # a client that read it first has to look again.
+        deadline = time.monotonic() + 5.0
+        while True:
+            cs = front.conn_stats()
+            if cs["conns_idle_reaped"] >= 1 or time.monotonic() > deadline:
+                break
+            time.sleep(0.02)
         assert cs["conns_idle_reaped"] >= 1
         assert cs["conns_open"] == 0
     finally:
