@@ -15,9 +15,11 @@ for a fixed number of RPCs, checks that /debug/vars names the platform,
 engine, rows and chips of the configuration, measures for `--seconds`,
 stops the daemon, holds the answers the clients kept to the reference
 (lib/judge.py), reduces, and prints one JSON line.  `setup_s` is spawn →
-end of warm-up.  Anywhere but on a TPU it exits non-zero and prints no
-result; `--rehearse-cpu` is the only CPU path, at a tiny size, and the
-result then says `"platform": "cpu"`.
+end of warm-up.  The run's log cuts the window into 10 s slices
+(each slice's rate, p50, p95 and the machine pauses in it), so that a
+run says how it wandered inside one start.  Anywhere but on a TPU it
+exits non-zero and prints no result; `--rehearse-cpu` is the only CPU
+path, at a tiny size, and the result then says `"platform": "cpu"`.
 
 What is started in the daemon's place is the configuration's to say
 (`launcher`, `holds_chip` in its file): the `control_*` configurations
@@ -194,6 +196,9 @@ def ladder(latencies: np.ndarray) -> dict:
     }
 
 
+SLICE_S = 10.0  # the per-slice line's grain
+
+
 class Ticker(threading.Thread):
     """Sleeps 5 ms at a time through the window and keeps the gaps it
     overslept by more than 50 ms: a pause that this idle parent sees
@@ -211,6 +216,29 @@ class Ticker(threading.Thread):
             if now - last > 0.05:
                 self.gaps.append((last, now - last))
             last = now
+
+
+def slices_of(sent_at, took, t_start, seconds, items_per_rpc, gaps) -> list:
+    """The window cut into SLICE_S slices by completion time: each
+    slice's completed decisions/s, p50 and p95, and the pauses over
+    50 ms (`gaps`: start, length) that the idle parent's ticker saw
+    begin in it.  One long run gives a dozen readings of how a single
+    start wanders."""
+    done = sent_at + took - t_start
+    out = []
+    for k in range(int(np.ceil(seconds / SLICE_S))):
+        lo, hi = k * SLICE_S, min(seconds, (k + 1) * SLICE_S)
+        lat = np.sort(took[(done >= lo) & (done < hi)])
+        row = {"from_s": lo, "rpcs": int(lat.size),
+               "decisions_per_s": lat.size * items_per_rpc / (hi - lo)}
+        if lat.size:
+            row["rpc_p50_ms"] = 1e3 * quantile(lat, 0.50)
+            row["rpc_p95_ms"] = 1e3 * quantile(lat, 0.95)
+        row["pauses_ms"] = [
+            round(1e3 * g) for t, g in gaps if lo <= t - t_start < hi
+        ]
+        out.append(row)
+    return out
 
 
 def run(args) -> int:
@@ -375,14 +403,9 @@ def run(args) -> int:
         f"opened {t_start - t_listen:.2f}s after the port; slowest RPCs [sent s "
         f"after the port, ms]: "
         f"{[[round(t - t_listen, 2), round(1e3 * x, 1)] for t, x in slowest]}")
-    thirds = np.minimum(2, ((timed[1] - t_start) * 3 / seconds).astype(int))
-    say("latency ms by third of the window (by send time): " + json.dumps([
-        dict(ladder(timed[0][thirds == k]), rpcs=int((thirds == k).sum()))
-        for k in range(3) if (thirds == k).any()
-    ]))
-    say(f"pauses over 50 ms seen by the idle parent's 5 ms ticker [s into "
-        f"the window, ms]: "
-        f"{[[round(t - t_start, 2), round(1e3 * g)] for t, g in ticker.gaps]}")
+    say(f"by {SLICE_S:g} s slice of the window (by completion time): "
+        + json.dumps(slices_of(timed[1], timed[0], t_start, seconds,
+                               int(mix["items_per_rpc"]), ticker.gaps)))
     say(f"judged: {judged['keys']} keys ({judged['shared_keys']} with answers "
         f"to more than one caller, {judged['reordered_keys']} placed in another "
         f"order than their clocks'), {judged['checked']} answers; rows occupied "

@@ -18,6 +18,11 @@ def test_one_chip_cell_traced():
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert list(result)[:5] == CONTRACT_KEYS and list(result)[-1] == "compared"
     assert set(result) <= set(CONTRACT_KEYS) | {"breakdown", "state", "compared"}
+    # the log's per-slice line: one row a 10 s slice of the window
+    (line,) = [x for x in proc.stdout.splitlines() if "s slice of the window" in x]
+    (row,) = json.loads(line.split(": ", 1)[1])
+    assert row["from_s"] == 0.0 and row["rpcs"] > 0
+    assert {"decisions_per_s", "rpc_p50_ms", "rpc_p95_ms", "pauses_ms"} <= set(row)
     assert 0 < result["state"]["rows_occupied_start"] <= result["state"]["rows_occupied_end"]
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 0
