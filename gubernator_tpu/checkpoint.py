@@ -5,18 +5,19 @@ the reference (SURVEY.md §5.4): `engine.save(loader)` streams a
 full-fidelity device→host snapshot out, `engine.load(loader)` streams
 it back in before serving.  `NpzFileLoader` persists the stream as one
 compressed npz of columnar arrays — the struct-of-arrays layout on
-disk mirrors the layout in HBM, so save/restore is a single
-device↔host transfer plus one numpy write/read, not a per-key walk.
+disk mirrors the layout in HBM — and hands it back as columns
+(`load_columns`), so a restore is one numpy read, one bulk insert and
+one device scatter a chunk, not a per-key walk.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Iterable, Iterator, List
+from typing import Iterable, Iterator
 
 import numpy as np
 
-from gubernator_tpu.store import CacheItem, LeakyBucketItem, TokenBucketItem
+from gubernator_tpu.store import CacheItem, ItemColumns, pack_keys
 from gubernator_tpu.types import Algorithm
 
 
@@ -27,122 +28,60 @@ class NpzFileLoader:
         self.path = path
 
     def save(self, items: Iterator[CacheItem]) -> None:
-        keys: List[str] = []
-        algo: List[int] = []
-        status: List[int] = []
-        limit: List[int] = []
-        remaining_i: List[int] = []
-        remaining_f: List[float] = []
-        remf_hi: List[int] = []
-        remf_lo: List[int] = []
-        duration: List[int] = []
-        t0: List[int] = []
-        expire: List[int] = []
-        burst: List[int] = []
-        invalid: List[int] = []
-        for it in items:
-            v = it.value
-            if v is None:
-                continue
-            keys.append(it.key)
-            algo.append(int(it.algorithm))
-            expire.append(it.expire_at)
-            invalid.append(it.invalid_at)
-            if isinstance(v, TokenBucketItem):
-                status.append(v.status)
-                limit.append(v.limit)
-                remaining_i.append(v.remaining)
-                remaining_f.append(0.0)
-                remf_hi.append(0)
-                remf_lo.append(0)
-                duration.append(v.duration)
-                t0.append(v.created_at)
-                burst.append(0)
-            else:
-                status.append(0)
-                limit.append(v.limit)
-                remaining_i.append(0)
-                remaining_f.append(v.remaining)
-                # Exact 32.32 words when present — the float64 mirror
-                # rounds once whole parts exceed 2^21.  Items built from
-                # the float field only derive their words from it.
-                from gubernator_tpu.store import words_from_float
-
-                w = (
-                    v.remaining_words
-                    if v.remaining_words is not None
-                    else words_from_float(v.remaining)
-                )
-                remf_hi.append(w[0])
-                remf_lo.append(w[1])
-                duration.append(v.duration)
-                t0.append(v.updated_at)
-                burst.append(v.burst)
+        cols = ItemColumns.from_items(items)
+        leaky = cols.algo != int(Algorithm.TOKEN_BUCKET)
         # .npz-suffixed temp name (savez would append the suffix
         # otherwise), swapped in atomically so a crash mid-save never
         # clobbers the previous checkpoint.
         tmp = self.path + ".tmp.npz"
         np.savez_compressed(
             tmp,
-            keys=np.asarray(keys, dtype=object),
-            algo=np.asarray(algo, dtype=np.int32),
-            status=np.asarray(status, dtype=np.int32),
-            limit=np.asarray(limit, dtype=np.int64),
-            remaining_i=np.asarray(remaining_i, dtype=np.int64),
-            remaining_f=np.asarray(remaining_f, dtype=np.float64),
-            remf_hi=np.asarray(remf_hi, dtype=np.int32),
-            remf_lo=np.asarray(remf_lo, dtype=np.uint32),
-            duration=np.asarray(duration, dtype=np.int64),
-            t0=np.asarray(t0, dtype=np.int64),
-            expire=np.asarray(expire, dtype=np.int64),
-            burst=np.asarray(burst, dtype=np.int64),
-            invalid=np.asarray(invalid, dtype=np.int64),
+            keys=np.asarray(cols.keys(), dtype=object),
+            algo=cols.algo,
+            status=cols.status,
+            limit=cols.limit,
+            remaining_i=cols.remaining,
+            # The float64 mirror of the exact 32.32 words below (it
+            # rounds once whole parts exceed 2^21).
+            remaining_f=np.where(
+                leaky, cols.remf_hi + cols.remf_lo * 2.0**-32, 0.0
+            ),
+            remf_hi=cols.remf_hi,
+            remf_lo=cols.remf_lo,
+            duration=cols.duration,
+            t0=cols.t0,
+            expire=cols.expire_at,
+            burst=cols.burst,
+            invalid=cols.invalid_at,
         )
         os.replace(tmp, self.path)
 
-    def load(self) -> Iterable[CacheItem]:
+    def load_columns(self) -> Iterable[ItemColumns]:
+        """The file's arrays as one chunk; no CacheItem is built."""
         if not os.path.exists(self.path):
             return
         with np.load(self.path, allow_pickle=True) as z:
-            keys = z["keys"]
-            algo = z["algo"]
-            status = z["status"]
-            limit = z["limit"]
-            remaining_i = z["remaining_i"]
-            remaining_f = z["remaining_f"]
-            duration = z["duration"]
-            t0 = z["t0"]
-            expire = z["expire"]
-            burst = z["burst"]
-            invalid = z["invalid"]
-            remf_hi = z["remf_hi"] if "remf_hi" in z else None
-            remf_lo = z["remf_lo"] if "remf_lo" in z else None
-            for i in range(len(keys)):
-                if algo[i] == int(Algorithm.TOKEN_BUCKET):
-                    value = TokenBucketItem(
-                        status=int(status[i]),
-                        limit=int(limit[i]),
-                        duration=int(duration[i]),
-                        remaining=int(remaining_i[i]),
-                        created_at=int(t0[i]),
-                    )
-                else:
-                    value = LeakyBucketItem(
-                        limit=int(limit[i]),
-                        duration=int(duration[i]),
-                        remaining=float(remaining_f[i]),
-                        updated_at=int(t0[i]),
-                        burst=int(burst[i]),
-                        remaining_words=(
-                            (int(remf_hi[i]), int(remf_lo[i]))
-                            if remf_hi is not None
-                            else None
-                        ),
-                    )
-                yield CacheItem(
-                    key=str(keys[i]),
-                    value=value,
-                    expire_at=int(expire[i]),
-                    algorithm=int(algo[i]),
-                    invalid_at=int(invalid[i]),
-                )
+            key_buf, key_offsets = pack_keys(
+                [k.encode() for k in z["keys"].tolist()])
+            if "remf_hi" in z:
+                remf_hi, remf_lo = z["remf_hi"], z["remf_lo"]
+            else:
+                # A file from before the exact words were kept: the
+                # float mirror's words, as store.words_from_float's.
+                whole = np.floor(z["remaining_f"])
+                remf_hi = whole.astype(np.int32)
+                remf_lo = np.minimum(
+                    (z["remaining_f"] - whole) * 2.0**32, 2.0**32 - 1
+                ).astype(np.uint32)
+            yield ItemColumns(
+                key_buf=key_buf, key_offsets=key_offsets,
+                algo=z["algo"], status=z["status"], limit=z["limit"],
+                remaining=z["remaining_i"], remf_hi=remf_hi,
+                remf_lo=remf_lo, duration=z["duration"], t0=z["t0"],
+                expire_at=z["expire"], burst=z["burst"],
+                invalid_at=z["invalid"],
+            )
+
+    def load(self) -> Iterable[CacheItem]:
+        for cols in self.load_columns():
+            yield from cols.items()

@@ -53,6 +53,7 @@ def describe(engine) -> dict:
         "cpu_unrequested": d0.platform == "cpu" and not cpu_was_requested(),
         "engine": type(engine).__name__,
         "rows": engine.capacity,
+        "rows_occupied": engine.cache_size(),
         "fused_mode": engine.fused_mode,
         "pump": pump is not None,
         "pump_scan": bool(pump is not None and pump._scan_ok),
@@ -73,6 +74,11 @@ def describe(engine) -> dict:
             "rounds_total": engine.rounds_total,
             "dispatches_total": engine.dispatches_total,
             "over_limit_total": engine.over_limit_total,
+            "evictions_total": sum(t.evictions for t in tables),
+            "unexpired_evictions_total": sum(
+                t.unexpired_evictions for t in tables
+            ),
+            "rows_loaded_total": engine.rows_loaded_total,
             "pump_fused_rounds": pump.fused_rounds if pump else 0,
             "pump_flushes": pump.flushes if pump else 0,
         },

@@ -516,11 +516,20 @@ class DecisionEngine:
         # stacks) — the numerator of the dispatches-per-batch gauge the
         # fused plane pins to 1 in steady state (PERF.md §24).
         self.dispatches_total = 0  # guberlint: guarded-by _lock
+        # Rows restored through load(), either path.
+        self.rows_loaded_total = 0  # guberlint: guarded-by _lock
+        # Rows of one columnar restore scatter (_load_columns): as wide
+        # as the table up to 2^20, so that one program is compiled and
+        # `_scatter_hints` keeps its scatter the pass over each column
+        # (1.2 ms at 100 M rows, 2^20 rows a pass).
+        self.load_width = min(1 << 20, _pad_size(capacity, floor=1024))
         # device.step: the host's ENQUEUE wall of one dispatch (h2d +
         # launch, both staged below) — not device time.
         self.round_duration = DurationStat()
         # The served path's stages (utils/metrics.ENGINE_STAGES).
-        self.stages = engine_stages()
+        # engine.load is this engine's alone, as mesh.route is the
+        # sharded one's (whose load is one pass through the host).
+        self.stages = engine_stages(extra=("engine.load",))
         # Engine-wide d2h transfer batching (core/readback.py): every
         # dispatched output registers a ticket; readers share one
         # stacked transfer instead of paying a device→host read each.
@@ -806,15 +815,17 @@ class DecisionEngine:
             if len(cleared) == 0:
                 return
         self._flush_pump()
-        csize = _pad_size(len(cleared), floor=16)
-        c = np.arange(
-            self.capacity, self.capacity + csize, dtype=np.int64
-        ).astype(_I32)
-        c[: len(cleared)] = cleared
-        c_dev = self._h2d(c)
-        with self._stage("device.launch"):
+        # One leaf stage for what a round's evictions cost the host
+        # inside the lock: pad, upload and enqueue of the clear (the
+        # pump's flush above ran through its own stages).
+        with self._stage("engine.evict_clear"):
+            csize = _pad_size(len(cleared), floor=16)
+            c = np.arange(
+                self.capacity, self.capacity + csize, dtype=np.int64
+            ).astype(_I32)
+            c[: len(cleared)] = cleared
             self._state = self._state._replace(
-                meta=clear_occupied(self._state.meta, c_dev)
+                meta=clear_occupied(self._state.meta, jnp.asarray(c))
             )
             self.dispatches_total += 1
 
@@ -1479,10 +1490,19 @@ class DecisionEngine:
     # drivers are gubernator_pool.go:341-531 Load/Store)
 
     def load(self, loader) -> int:
-        """Stream CacheItems in before serving; returns count restored.
+        """Stream the cache in before serving; returns rows restored.
+        A loader that hands over columns (`load_columns`, store.py) is
+        restored a chunk at a time; one that has only `load()` — and a
+        paged engine, whose restores split by page residency — item by
+        item.  Either way the stream's order is the LRU order (first
+        row oldest), a key seen twice keeps its last row, and a stream
+        longer than the table evicts as served misses do.
 
         reference: gubernator.go:146-152 → gubernator_pool.go:341-427.
         """
+        columns = getattr(loader, "load_columns", None)
+        if columns is not None and self.paging is None:
+            return self._load_columns(columns())
         count = 0
         batch: List[tuple] = []
         pending_slots: set = set()
@@ -1491,11 +1511,8 @@ class DecisionEngine:
         def flush():
             nonlocal batch
             if batch:
-                self._apply_restores(batch)
-                self.table.set_expiry(
-                    np.asarray([s for s, _ in batch], dtype=_I32),
-                    np.asarray([it.expire_at for _, it in batch], dtype=_I64),
-                )
+                with self._stage("engine.load"):
+                    self._apply_restores(batch)
                 batch = []
                 pending_slots.clear()
 
@@ -1506,6 +1523,13 @@ class DecisionEngine:
                     continue
                 evicted: List[int] = []
                 slot = self.table.intern(item.key, now_ms, evicted)
+                # The TTL mirror at once, as the columnar path and
+                # upstream's Add have it: an eviction later in the
+                # stream then counts this row as unexpired.
+                self.table.set_expiry(
+                    np.asarray([slot], dtype=_I32),
+                    np.asarray([item.expire_at], dtype=_I64),
+                )
                 # A re-used slot (eviction, or a loader emitting the
                 # same key twice) must not appear twice in one restore
                 # scatter, and its clear must not run after a pending
@@ -1522,7 +1546,81 @@ class DecisionEngine:
                 if len(batch) >= 4096:
                     flush()
             flush()
+            self.rows_loaded_total += count
         return count
+
+    def _load_columns(self, chunks) -> int:
+        """The columnar restore: per piece of `load_width` rows one
+        bulk insert into the intern table (which writes the TTL mirror
+        row by row), and one `load_slots` scatter of that fixed width.
+        No clear runs: an evicted slot goes at once to the row that
+        evicted it, and a restore writes every column of its row."""
+        count = 0
+        now_ms = self.clock.now_ms()
+        with self._lock:
+            self._flush_pump()
+            for chunk in chunks:
+                for lo in range(0, len(chunk), self.load_width):
+                    with self._stage("engine.load"):
+                        count += self._load_piece(chunk, lo, now_ms)
+        return count
+
+    def _load_piece(self, chunk, lo: int, now_ms: int) -> int:  # guberlint: holds _lock
+        hi = min(lo + self.load_width, len(chunk))
+        offsets = chunk.key_offsets[lo : hi + 1]
+        # The piece's rows of the chunk: a slice while they are all of
+        # them in order, an index array from then on.
+        rows = slice(lo, hi)
+        keyed = offsets[1:] > offsets[:-1]
+        if not keyed.all():
+            # A row without a key restores nothing; its two equal
+            # boundaries fall to one, the buffer stays as it is.
+            rows = np.flatnonzero(keyed) + lo
+            offsets = np.concatenate((offsets[:1], offsets[1:][keyed]))
+        slots = self.table.load_rows(
+            chunk.key_buf, offsets, chunk.expire_at[rows], now_ms
+        )
+        n = len(slots)
+        if n > 1 and not (slots[1:] > slots[:-1]).all():
+            # The scatter wants its slots ascending and each once: of
+            # a slot's rows (a key seen twice, a slot evicted and given
+            # away inside the piece) the last one is its state.
+            order = np.argsort(slots, kind="stable")
+            last = np.ones(n, dtype=bool)
+            last[:-1] = slots[order][1:] != slots[order][:-1]
+            keep = order[last]
+            if isinstance(rows, slice):
+                rows = np.arange(lo, hi)
+            rows, slots = rows[keep], slots[keep]
+        self._restore_rows(slots, lambda name: getattr(chunk, name)[rows])
+        self.rows_loaded_total += n
+        return n
+
+    def _restore_rows(self, slots: np.ndarray, column) -> None:  # guberlint: holds _lock
+        """One `load_slots` scatter of `load_width` lanes: `slots`
+        ascending and each once, `column(name)` their values of that
+        SlotRecord column; the lanes beyond them are padding."""
+        from gubernator_tpu.store import COLUMN_DTYPES
+
+        width, n = self.load_width, len(slots)
+        if n == width:  # a full piece goes up as it is
+            rec = {"slot": slots}
+            for name, dtype in COLUMN_DTYPES.items():
+                rec[name] = np.asarray(column(name), dtype=dtype)
+        else:
+            rec = {"slot": np.arange(
+                self.capacity, self.capacity + width, dtype=np.int64
+            ).astype(_I32)}
+            rec["slot"][:n] = slots
+            for name, dtype in COLUMN_DTYPES.items():
+                rec[name] = np.zeros(width, dtype=dtype)
+                if n:
+                    rec[name][:n] = column(name)
+        self._state = load_slots(
+            self._state,
+            SlotRecord(**{k: jnp.asarray(a) for k, a in rec.items()}),
+        )
+        self.dispatches_total += 1
 
     def export_items(self):
         """Full-fidelity device→host snapshot as CacheItems.
@@ -1600,6 +1698,8 @@ class DecisionEngine:
                 self.dispatches_total,
                 self.table.hits,
                 self.table.misses,
+                self.table.evictions,
+                self.table.unexpired_evictions,
             )
             # Warmup traffic must not reach a write-through Store (it would
             # persist junk __warmup__ keys and pay external round-trips).
@@ -1661,6 +1761,11 @@ class DecisionEngine:
                         meta=clear_occupied(self._state.meta, dummy)
                     )
                     csize *= 2
+                # The columnar restore's one program (_load_columns),
+                # every lane padding: a daemon with a Loader restores
+                # right after this warm-up.
+                if self.paging is None:
+                    self._restore_rows(np.zeros(0, dtype=_I32), None)
                 # Readback-combiner stack ladder: concurrent/pipelined
                 # callers share one stacked d2h transfer; precompile the
                 # stack programs per output width (core/readback.py).
@@ -1684,19 +1789,22 @@ class DecisionEngine:
                     self.batches_total,
                     self.rounds_total,
                     self.dispatches_total,
-                    saved_hits,
-                    saved_misses,
+                    *saved_table,
                 ) = saved
-                if hasattr(self.table, "discount_stats"):
+                t = self.table
+                if hasattr(t, "discount_stats"):
                     # The native table mirrors cumulative C++ counters on
                     # every schedule(); plain attribute restore would be
                     # overwritten by the next mirror, so register discounts
                     # instead.
-                    self.table.discount_stats(
-                        self.table.hits - saved_hits, self.table.misses - saved_misses
-                    )
+                    t.discount_stats(*(
+                        now - then for now, then in zip(
+                            (t.hits, t.misses, t.evictions,
+                             t.unexpired_evictions), saved_table)
+                    ))
                 else:
-                    self.table.hits, self.table.misses = saved_hits, saved_misses
+                    (t.hits, t.misses, t.evictions,
+                     t.unexpired_evictions) = saved_table
             finally:
                 # Exception-safety: a failed warmup (backend or
                 # compile error) must not leave persistence disabled.

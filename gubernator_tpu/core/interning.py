@@ -61,6 +61,11 @@ class InternTable:
             self._map.move_to_end(key)
             return slot
         self.misses += 1
+        return self._place(key, now_ms, cleared)
+
+    def _place(self, key: str, now_ms: int, cleared: list[int]) -> int:
+        """Give an unknown key a slot: a free one, else the least
+        recently used key's."""
         if self._free:
             slot = self._free.pop()
         else:
@@ -75,6 +80,34 @@ class InternTable:
         self._slot_key[slot] = key
         self._expire[slot] = 0
         return slot
+
+    def load_rows(
+        self, buf_arr: np.ndarray, offsets: np.ndarray,
+        expires: np.ndarray, now_ms: int,
+    ) -> np.ndarray:
+        """Bulk restore (reference: store.go:69-78 Loader.Load →
+        lrucache.go Add per item): intern the rows' keys — packed as
+        `buf_arr[offsets[i]:offsets[i+1]]` — in arrival order, so the
+        first row ends up least recently used; returns their slots.  A
+        key seen twice keeps its slot (the caller restores its last
+        row); a full table evicts its oldest exactly as `intern` does,
+        and since each row's TTL mirror is written as it lands, an
+        eviction later in the stream counts an unexpired row as one.
+        Hits and misses stay as they were (Add does not call
+        accessMetric)."""
+        raw = np.ascontiguousarray(buf_arr, dtype=np.uint8).tobytes()
+        slots = np.empty(len(offsets) - 1, dtype=np.int32)
+        evicted: list[int] = []  # each is re-used at once by its evictor
+        for i in range(len(slots)):
+            key = raw[offsets[i]:offsets[i + 1]].decode()
+            slot = self._map.get(key)
+            if slot is None:
+                slot = self._place(key, now_ms, evicted)
+            else:
+                self._map.move_to_end(key)
+            self._expire[slot] = expires[i]
+            slots[i] = slot
+        return slots
 
     def set_expiry(self, slots: np.ndarray, expires: np.ndarray) -> None:
         """Update the host TTL mirror after a kernel step."""

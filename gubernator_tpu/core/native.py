@@ -84,6 +84,17 @@ def load_library():
         ctypes.c_void_p,  # stats_out
         ctypes.c_int64,  # n_threads
     ]
+    lib.git_load.restype = None
+    lib.git_load.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_void_p,  # buf
+        ctypes.c_void_p,  # offsets
+        ctypes.c_int64,  # n
+        ctypes.c_int64,  # now_ms
+        ctypes.c_void_p,  # expires
+        ctypes.c_void_p,  # out_slots
+        ctypes.c_void_p,  # stats_out
+    ]
     lib.git_set_expiry.argtypes = [
         ctypes.c_void_p,
         ctypes.c_void_p,
@@ -196,6 +207,33 @@ class NativeInternTable:
             int(stats[3]) - off[3],
         )
         return slots, rounds, evicted[:n_ev], evict_rounds[:n_ev]
+
+    def load_rows(
+        self,
+        buf_arr: np.ndarray,  # uint8 concatenated key bytes
+        offsets: np.ndarray,  # int64 [n+1]
+        expires: np.ndarray,  # int64 [n] TTL mirror of each row
+        now_ms: int,
+    ) -> np.ndarray:
+        """Bulk restore: intern the rows' keys in arrival order (first
+        row least recently used) in one FFI call; returns their slots.
+        Semantics in `InternTable.load_rows`, its Python twin."""
+        n = len(offsets) - 1
+        buf_arr = np.ascontiguousarray(buf_arr, dtype=np.uint8)
+        offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+        expires = np.ascontiguousarray(expires, dtype=np.int64)
+        if len(expires) != n:
+            raise ValueError(f"{len(expires)} expiries for {n} keys")
+        slots = np.empty(n, dtype=np.int32)
+        stats = np.zeros(4, dtype=np.int64)
+        self._lib.git_load(
+            self._t, _ptr(buf_arr), _ptr(offsets), n, now_ms,
+            _ptr(expires), _ptr(slots), _ptr(stats),
+        )
+        off = self._stat_off
+        self.evictions = int(stats[2]) - off[2]
+        self.unexpired_evictions = int(stats[3]) - off[3]
+        return slots
 
     def discount_stats(self, hits: int, misses: int, evictions: int = 0,
                        unexpired: int = 0) -> None:

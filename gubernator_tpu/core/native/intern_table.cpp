@@ -328,6 +328,51 @@ int64_t git_schedule(void* tp, const uint8_t* buf, const int64_t* offsets,
                           stats_out);
 }
 
+// Bulk restore (engine.load's columnar path; reference: store.go:69-78
+// Loader.Load → lrucache.go Add per item): intern n keys in arrival
+// order, so the first row ends up least recently used.  A key seen
+// twice keeps its slot (the caller restores its last row); a full
+// table evicts its oldest exactly as a served miss does, and because
+// every row's TTL mirror is written as it lands, an eviction later in
+// the stream counts an unexpired row as one.  Not a served batch: no
+// round counters, and hits/misses stay as they were (Add does not
+// call accessMetric).  out_slots[n]; stats_out[4] as git_schedule.
+// guberlint: gil-free
+void git_load(void* tp, const uint8_t* buf, const int64_t* offsets, int64_t n,
+              int64_t now_ms, const int64_t* expires, int32_t* out_slots,
+              int64_t* stats_out) {
+  Table& t = *static_cast<Table*>(tp);
+  const int64_t hits0 = t.hits, misses0 = t.misses;
+  // The same hash-ahead prefetch window as git_schedule_idx: at 100 M
+  // rows every probe is a cache miss.
+  constexpr int64_t kAhead = 16;
+  uint64_t hwin[kAhead];
+  auto hash_of = [&](int64_t j) {
+    return fnv1a(buf + offsets[j], offsets[j + 1] - offsets[j]);
+  };
+  auto prefetch = [&](uint64_t h) {
+    __builtin_prefetch(&t.buckets[h & t.mask]);
+    __builtin_prefetch(&t.bucket_hash[h & t.mask]);
+  };
+  for (int64_t j = 0; j < n && j < kAhead; ++j) prefetch(hwin[j] = hash_of(j));
+  for (int64_t j = 0; j < n; ++j) {
+    const uint64_t h = hwin[j % kAhead];
+    if (j + kAhead < n) prefetch(hwin[j % kAhead] = hash_of(j + kAhead));
+    int32_t ev_slot, ev_round;
+    const int32_t slot = schedule_one(t, buf + offsets[j],
+                                      offsets[j + 1] - offsets[j], h, now_ms,
+                                      &ev_slot, &ev_round);
+    t.expire[slot] = expires[j];
+    out_slots[j] = slot;
+  }
+  t.hits = hits0;
+  t.misses = misses0;
+  stats_out[0] = t.hits;
+  stats_out[1] = t.misses;
+  stats_out[2] = t.evictions;
+  stats_out[3] = t.unexpired_evictions;
+}
+
 // Schedule one batch across n_sh shard tables in ONE call (the
 // sharded engine's whole host tier for a decoded wire batch): shard
 // routing (hash % n_sh), per-table interning + LRU + eviction, round

@@ -137,6 +137,7 @@ class ShardedDecisionEngine:
         self.rounds_total = 0
         # Decision-plane device dispatch counter (see DecisionEngine).
         self.dispatches_total = 0
+        self.rows_loaded_total = 0  # rows restored through load()
         # GLOBAL column merge as a psum over the mesh (ROADMAP item 1 /
         # PERF.md §24): a whole-batch round's per-shard packed outputs
         # are scattered to their request positions ON DEVICE and
@@ -462,17 +463,19 @@ class ShardedDecisionEngine:
         if not n_clear:
             return
         cap = self.shard_capacity
-        csize = _pad_size(n_clear, floor=16)
-        c = np.tile(
-            np.arange(cap, cap + csize, dtype=_I64).astype(_I32),
-            (self.n_shards, 1),
-        )
-        for sh in range(self.n_shards):
-            c[sh, : len(clears[sh])] = clears[sh]
-        c_dev = self._put(c)
-        with self._stage("device.launch"):
+        # guberlint: ok drift — sharded twin of engine.py's engine.evict_clear site
+        with self._stage("engine.evict_clear"):
+            csize = _pad_size(n_clear, floor=16)
+            c = np.tile(
+                np.arange(cap, cap + csize, dtype=_I64).astype(_I32),
+                (self.n_shards, 1),
+            )
+            for sh in range(self.n_shards):
+                c[sh, : len(clears[sh])] = clears[sh]
             self._state = self._state._replace(
-                meta=self._clear_step(self._state.meta, c_dev)
+                meta=self._clear_step(
+                    self._state.meta, jax.device_put(c, self._placement)
+                )
             )
             self.dispatches_total += 1
 
@@ -803,7 +806,8 @@ class ShardedDecisionEngine:
             self.batches_total,
             self.rounds_total,
             self.dispatches_total,
-            [(t.hits, t.misses) for t in self.tables],
+            [(t.hits, t.misses, t.evictions, t.unexpired_evictions)
+             for t in self.tables],
         )
         # Warmup traffic must not reach a write-through Store (it would
         # persist junk __warmup__ keys and pay external round-trips).
@@ -951,14 +955,18 @@ class ShardedDecisionEngine:
                 self.dispatches_total,
                 table_stats,
             ) = saved
-            for t, (h, m) in zip(self.tables, table_stats):
+            for t, (h, m, ev, un) in zip(self.tables, table_stats):
                 if hasattr(t, "discount_stats"):
                     # Native tables re-mirror cumulative C++ counters on
                     # every schedule(); register discounts instead of
                     # restoring attributes (see DecisionEngine.warmup).
-                    t.discount_stats(t.hits - h, t.misses - m)
+                    t.discount_stats(
+                        t.hits - h, t.misses - m, t.evictions - ev,
+                        t.unexpired_evictions - un,
+                    )
                 else:
                     t.hits, t.misses = h, m
+                    t.evictions, t.unexpired_evictions = ev, un
         finally:
             # Exception-safety: a failed warmup must not leave
             # persistence disabled (see DecisionEngine.warmup).
@@ -1766,6 +1774,7 @@ class ShardedDecisionEngine:
             self._state = BucketState(
                 **{f: self._put(a) for f, a in packed.items()}
             )
+            self.rows_loaded_total += count
         return count
 
     def export_items(self):

@@ -285,6 +285,7 @@ ENGINE_STAGES = (
     "device.h2d",
     "device.launch",
     "engine.set_expiry",
+    "engine.evict_clear",
     "engine.unpack",
     "engine.sweep",
 )
@@ -611,6 +612,31 @@ class InstanceCollector(Collector):
         )
         g.add_metric([], eng.cache_size())
         yield g
+
+        # reference: lrucache.go:148-159 — a full cache evicts its
+        # oldest item; evictions of items that had not expired are
+        # the operator's sign of an undersized cache.
+        tables = getattr(eng, "tables", None) or [eng.table]
+        c = CounterMetricFamily(
+            "gubernator_evictions_count",
+            "Keys evicted from a full cache, least recently used first.",
+        )
+        c.add_metric([], sum(t.evictions for t in tables))
+        yield c
+        c = CounterMetricFamily(
+            "gubernator_unexpired_evictions_count",
+            "Count the number of cache items which were evicted while "
+            "unexpired.",
+        )
+        c.add_metric([], sum(t.unexpired_evictions for t in tables))
+        yield c
+        c = CounterMetricFamily(
+            "gubernator_loaded_rows_count",
+            "Rows restored through the Loader (engine.load; stage "
+            "engine.load has the time).",
+        )
+        c.add_metric([], eng.rows_loaded_total)
+        yield c
 
         c = CounterMetricFamily(
             "gubernator_global_async_sends",

@@ -152,6 +152,9 @@ STAGES = (
     "wire.decode",
     "service.hotkeys",
     "wire.encode",
+    # The single-device engine's restore at start (PR 28); the cluster
+    # harness runs one device a node.
+    "engine.load",
 ) + ENGINE_STAGES
 
 
