@@ -82,6 +82,8 @@ _TIER_LOST = {
     "h2_server": "the native h2 front, decision plane and columnar "
     "feeder (GUBER_H2_FAST_ADDRESS cannot be served)",
     "h2_client": "the native h2 load client (bench/herd modes only)",
+    "hotkeys": "the native hot-key table; the Python one serves, a "
+    "per-key loop under the interpreter lock on every RPC's thread",
 }
 
 

@@ -1022,8 +1022,9 @@ class V1Instance:
         hk = self.hotkeys
         if hk is None:
             return
-        # A per-item Python pass on the RPC's own thread, before the
-        # engine lock: milliseconds a 1,000-item RPC (PERF.md §5).
+        # On the RPC's own thread, before the engine lock: a numpy
+        # grouping, then one native call over the unique keys with the
+        # interpreter lock released (utils/hotkeys.py; PERF.md §5).
         with stage("service.hotkeys", self.stage_timers["service.hotkeys"]):
             lim = np.asarray(dec.limit)
             elig = (

@@ -1009,6 +1009,17 @@ class InstanceCollector(Collector):
                     [key.decode(errors="replace")], float(count)
                 )
             yield g
+            # Which table serves the sketch: the native one (one call
+            # an RPC, interpreter lock released) or the Python fallback
+            # — a per-key loop on every RPC's thread.
+            g = GaugeMetricFamily(
+                "gubernator_hotkeys_native",
+                "1 when the hot-key sketch's table is the native one "
+                "(core/native/hotkeys.cpp), 0 when the Python fallback "
+                "serves.",
+            )
+            g.add_metric([], float(hk.tier == "native"))
+            yield g
 
         # Decision-ledger counters (core/ledger.py): decisions answered
         # on the host without a device dispatch, rows that fell through
