@@ -79,18 +79,15 @@ GREGORIAN_YEARS = 5
 # compiled on the chip, by /debug/vars `device.compiles.programs`).
 SHAPE_SITES = {
     "ops/bucket_kernel.py": (
-        "_clear_occupied_impl", "_apply_batch_impl", "_scatter_values",
-        "_apply_batch_sorted_impl", "_compute_update_sorted_impl",
+        "_clear_occupied_impl",
         "_fused_step_core", "_multi_fused_core", "_uniform_step_core",
-        "_multi_uniform_core", "_packed_compute_core", "_collapsed_values",
-        "_collapsed_step_core", "_load_slots_impl", "gather_page_words",
-        "_load_page_words_impl",
+        "_multi_uniform_core", "_collapsed_step_core",
+        "_load_slots_impl", "gather_page_words", "_load_page_words_impl",
     ),
     "ops/expiry.py": (
         "sweep_window_scan", "sweep_window_commit", "sweep_expired",
     ),
     "ops/sketch.py": ("_rotate", "sketch_step"),
-    "ops/pallas_step.py": ("step",),
     "core/pump.py": ("stack_rounds",),
     "core/readback.py": ("stack_outputs",),
     "parallel/sharded_engine.py": (
@@ -647,8 +644,7 @@ def check_device(dev: dict, want_platform: str, rows: int) -> None:
     )
     if want_platform == "tpu":
         for name, v in dev["probes"].items():
-            if name != "pallas_step":  # the kernel's refusal is recorded
-                check(v["ok"], f"{name} probe said no: {v['reason']}")
+            check(v["ok"], f"{name} probe said no: {v['reason']}")
         if n == 1:
             check(dev["pump"] and dev["pump_scan"], "pump/scan off on TPU")
         # The state is resident where it should be: every device holds
@@ -835,7 +831,7 @@ def parity_child(args) -> int:
         "swept": freed,
         "device": {
             k: info[k] for k in (
-                "platform", "device_kind", "device_count", "fused_mode",
+                "platform", "device_kind", "device_count",
                 "pump", "pump_scan",
             )
         },
@@ -943,7 +939,7 @@ def main(argv=None) -> int:
         say(
             f"first start: {cold_secs:.1f}s to first answer on "
             f"{dev0['platform']} {dev0['device_kind']} x{dev0['device_count']}"
-            f", engine {dev0['engine']}, fused_mode {dev0['fused_mode']}, "
+            f", engine {dev0['engine']}, "
             f"pump {dev0['pump']}, scan {dev0['pump_scan']}"
         )
         for name, v in dev0["probes"].items():
@@ -1060,7 +1056,6 @@ def main(argv=None) -> int:
         "bytes_in_use_per_device": [
             m["bytes_in_use"] for m in dev1["memory"]
         ],
-        "fused_mode": dev0["fused_mode"],
         "pump": dev0["pump"],
         "pump_scan": dev0["pump_scan"],
         "probes": dev0["probes"],

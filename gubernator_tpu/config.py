@@ -190,8 +190,6 @@ KNOWN_ENV_KNOBS = (
     "GUBER_PLATFORM",         # daemon.py: `cpu` forces the host backend
     "GUBER_PUMP",             # core/engine.py: step-pump mode override
     "GUBER_PUMP_SCAN",        # core/pump.py: fused-scan round loop toggle
-    "GUBER_FUSED",            # core/engine.py: fused-step impl select
-                              # (auto|pallas|interpret|xla|split)
     "GUBER_WINDOW_DEPTH",     # core/pump.py + core/readback.py:
                               # double-buffered h2d/d2h window depth
     "GUBER_PSUM_MERGE",       # parallel/sharded_engine.py: psum column

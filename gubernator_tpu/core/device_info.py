@@ -54,7 +54,6 @@ def describe(engine) -> dict:
         "engine": type(engine).__name__,
         "rows": engine.capacity,
         "rows_occupied": engine.cache_size(),
-        "fused_mode": engine.fused_mode,
         "pump": pump is not None,
         "pump_scan": bool(pump is not None and pump._scan_ok),
         "probes": {

@@ -4,7 +4,7 @@ Replaces the reference's worker pool + per-key algorithm calls
 (reference: gubernator_pool.go:250-336 → algorithms.go) with:
 
   host: key interning (key string → device slot) + batch assembly
-  device: one `apply_batch` kernel call per round (ops/bucket_kernel.py)
+  device: one donated step program per round (ops/bucket_kernel.py)
 
 Per-key serialization — which the reference gets from its worker hash
 ring (reference: gubernator_pool.go:19-37,183-187) — is preserved by
@@ -41,16 +41,15 @@ from gubernator_tpu.ops.bucket_kernel import (
     BucketState,
     SlotRecord,
     clear_occupied,
-    collapsed_compute,
     collapsed_step,
     fused_step,
     fused_step_ok,
     load_slots,
     make_state,
+    multi_step_ok,
     pack_batch_host,
     pack_collapsed_host,
-    packed_compute,
-    scatter_store,
+    uniform_step,
 )
 from gubernator_tpu.ops.expiry import windowed_sweep
 from gubernator_tpu.core.interning import InternTable
@@ -328,17 +327,34 @@ def build_restore_record(
     return rec
 
 
-def record_probe(probes: dict, name: str, verdict, otherwise: str) -> bool:
-    """Keep a compile probe's verdict (core/device_info.py serves
-    `probes`); a "no" that changes what serves is logged with the
-    compiler's reason.  Shared by both engines."""
-    probes[name] = verdict
-    if not verdict.ok:
-        log.warning(
-            "%s probe said no (%s): serving %s",
-            name, verdict.reason, otherwise,
+def require_in_place(verdict):
+    """`fused_step_ok`'s verdict for `probes`, or on an accelerator the
+    refusal to start on its no: a donated step that XLA compiled with
+    a state-sized temp would copy the table every dispatch (4.8 GB at
+    100 M rows), and there is no second step program to serve instead.
+    Both engines construct through here.
+
+    XLA:CPU does say no — it clones six of the twelve columns whatever
+    the size, which passes the probe's 1 MiB floor only under 43,691
+    rows — and its step then costs O(rows) (0.15 / 2.6 / 20 ms at
+    4,096 / 400,000 / 4 M rows in this sandbox).  The CPU backend
+    carries tests, rehearsals and `GUBER_PLATFORM=cpu`, not a
+    deployment, so there the no is logged and the same program
+    serves."""
+    if verdict.ok:
+        return verdict
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"fused_step probe said no ({verdict.reason}): the donated "
+            "bucket step does not compile in place on this backend, "
+            "so the engine does not start"
         )
-    return verdict.ok
+    log.warning(
+        "fused_step probe said no (%s): XLA:CPU copies part of the "
+        "state every dispatch; serving the same step program",
+        verdict.reason,
+    )
+    return verdict
 
 
 class DecisionEngine:
@@ -407,103 +423,47 @@ class DecisionEngine:
         self.store = store
         with jax.default_device(device) if device else nullcontext():
             self._state: BucketState = make_state(capacity)  # guberlint: guarded-by _lock
-            # Reusable no-op clear argument for apply_batch (all lanes
-            # out of range — real clears run via clear_occupied).
-            self._noop_clear = jnp.asarray(
-                np.arange(capacity, capacity + 16, dtype=np.int64).astype(_I32)
-            )
         # RLock: PumpTicket.fetch may flush from a thread already
         # inside the engine (dataclass-path dispatch fetches inline).
         self._lock = threading.RLock()
         # Next window start for incremental sweep.
         self._sweep_cursor = 0  # guberlint: guarded-by _lock
-        # Fused-step implementation select (PERF.md §24).  GUBER_FUSED:
-        #   auto (default) — the Pallas kernel when the backend lowers
-        #     it (pallas_step_ok, tried once on accelerators), else the
-        #     fused XLA program when the donated RMW stays in place
-        #     (fused_step_ok), else split;
-        #   pallas — the COMPILED Pallas kernel; raises with the
-        #     compiler's message where the backend refuses it;
-        #   interpret — Pallas interpret mode (CI parity: the kernel
-        #     body runs as traced ops on any backend);
-        #   xla — the fused XLA program, no Pallas attempt;
-        #   split — the UNFUSED compute+scatter pair, multiple device
-        #     dispatches per round (the devfused bench A/B control).
-        # Each probe's verdict and reason is kept in `self.probes`
-        # (core/device_info.py serves them); a "no" is logged.
+        # The bucket step is one family of donated XLA programs
+        # (ops/bucket_kernel.py); which of them a batch runs follows
+        # from the batch's shape.  The in-place probe is a fault
+        # detector, not a selector: a step compiled with a state-sized
+        # temp would copy the table every dispatch, so on an
+        # accelerator a no stops the start (`require_in_place`).  Each
+        # probe's verdict and reason is kept in `self.probes`
+        # (core/device_info.py serves them).
         import os as _os
 
-        fused_env = (
-            _os.environ.get("GUBER_FUSED", "auto").strip().lower()
-            or "auto"
-        )
-        if fused_env not in ("auto", "pallas", "interpret", "xla", "split"):
-            raise ValueError(
-                f"GUBER_FUSED={fused_env!r}: expected "
-                "auto|pallas|interpret|xla|split"
-            )
-        self.probes: dict = {}
-        # _pallas_interpret: None = Pallas off; False = compiled
-        # kernel; True = interpret mode.
-        self._pallas_interpret: Optional[bool] = None
-        if fused_env == "split":
-            self._fused = False
-        else:
-            self._fused = self._probe(
-                "fused_step", fused_step_ok(capacity),
-                "the split compute+scatter pair",
-            )
-        self.fused_mode = "xla" if self._fused else "split"
-        if fused_env == "interpret":
-            self._pallas_interpret = True
-            self.fused_mode = "pallas-interpret"
-        elif fused_env == "pallas":
-            from gubernator_tpu.ops.pallas_step import compile_pallas_step
-
-            compile_pallas_step(capacity)
-            self._pallas_interpret = False
-            self.fused_mode = "pallas"
-        elif fused_env == "auto" and jax.default_backend() != "cpu":
-            from gubernator_tpu.ops.pallas_step import pallas_step_ok
-
-            if self._probe(
-                "pallas_step", pallas_step_ok(capacity),
-                f"the {self.fused_mode} XLA program",
-            ):
-                self._pallas_interpret = False
-                self.fused_mode = "pallas"
+        self.probes: dict = {
+            "fused_step": require_in_place(fused_step_ok(capacity))
+        }
         # Cross-call dispatch batching (core/pump.py): queue packed
         # rounds, run ≤16 of them per dispatch via lax.scan.  Only
-        # when the scanned program keeps the donated state in place,
-        # and only on accelerator backends — the pump amortizes
+        # when the step and the scanned program keep the donated state
+        # in place, and only on accelerator backends — the pump amortizes
         # per-dispatch transfer/launch overhead that the in-process
         # CPU backend does not have (GUBER_PUMP=1/0 overrides).
-        from gubernator_tpu.ops.bucket_kernel import multi_step_ok
-
         pump_env = _os.environ.get("GUBER_PUMP", "")
         want_pump = (
             pump_env == "1"
             or (pump_env != "0" and jax.default_backend() != "cpu")
         )
-        # The pump's grouped dispatch is the XLA scan family
-        # (multi_fused_step) — grouped rounds would silently bypass a
-        # selected Pallas kernel and misattribute fused_mode, so
-        # Pallas modes run per-round dispatch until a scanned Pallas
-        # family exists (PERF.md §24a).
-        if want_pump and self._pallas_interpret is not None:
-            log.warning(
-                "step pump off: fused_mode=%s dispatches per round",
-                self.fused_mode,
-            )
-            want_pump = False
         self._pump: Optional["StepPump"] = None
-        if want_pump and self._fused and self._probe(
-            "multi_step", multi_step_ok(capacity),
-            "per-round dispatch (no step pump)",
-        ):
-            from gubernator_tpu.core.pump import StepPump
+        if want_pump and self.probes["fused_step"].ok:
+            verdict = self.probes["multi_step"] = multi_step_ok(capacity)
+            if verdict.ok:
+                from gubernator_tpu.core.pump import StepPump
 
-            self._pump = StepPump(self)
+                self._pump = StepPump(self)
+            else:
+                log.warning(
+                    "multi_step probe said no (%s): serving per-round "
+                    "dispatch (no step pump)", verdict.reason,
+                )
         # Metrics (reference: gubernator.go:59-113 catalog; wired to
         # prometheus in gubernator_tpu.utils.metrics).
         self.requests_total = 0  # guberlint: guarded-by _lock
@@ -537,9 +497,6 @@ class DecisionEngine:
 
         self.readback = ReadbackCombiner()
 
-    def _probe(self, name: str, verdict, otherwise: str) -> bool:
-        return record_probe(self.probes, name, verdict, otherwise)
-
     def _stage(self, name: str, work: bool = True) -> stage:
         return stage(name, self.stages[name], work)
 
@@ -551,19 +508,13 @@ class DecisionEngine:
         with self._stage("device.h2d"):
             return jnp.asarray(buf)
 
-    def _step(self, fused_fn, compute_fn, pin):  # guberlint: holds _lock
-        """One round's program(s) — the fused donated step, or the
-        split compute + scatter pair — as ONE device.launch: the
+    def _step(self, program, pin):  # guberlint: holds _lock
+        """One round's donated step program as ONE device.launch: the
         jitted call returning (the enqueue, not the device's run) and
         the donated state's old buffers let go."""
         with self._stage("device.launch"):
-            if self._fused:
-                self._state, pout = fused_fn(self._state, pin)
-                self.dispatches_total += 1
-            else:
-                slot_dev, vals, pout = compute_fn(self._state, pin)
-                self._state = scatter_store(self._state, slot_dev, vals)
-                self.dispatches_total += 2
+            self._state, pout = program(self._state, pin)
+            self.dispatches_total += 1
         return pout
 
     # ------------------------------------------------------------------
@@ -684,9 +635,9 @@ class DecisionEngine:
                         restore_rounds.setdefault(k, []).append((slot, item))
 
         # Paged translation: fault the batch's pages resident, then
-        # hand the dispatch machinery DEVICE rows — the kernels (XLA,
-        # interpret, and Pallas alike) see the same dense indexing
-        # they always did.  The intern table keeps LOGICAL slots.
+        # hand the dispatch machinery DEVICE rows — the step programs
+        # see the same dense indexing they always did.  The intern
+        # table keeps LOGICAL slots.
         lslots = slots
         if self.paging is not None:
             slots = self.paging.translate(self, slots)
@@ -740,12 +691,12 @@ class DecisionEngine:
                 requests, valid_idx, greg_dur, now_ms, responses, host_expire
             )
 
-    def _dispatch(self, buf: np.ndarray, fused_fn, compute_fn):  # guberlint: holds _lock
+    def _dispatch(self, buf: np.ndarray, program):  # guberlint: holds _lock
         """One device round: single h2d of the packed buffer, then the
-        fused donated kernel (or the split compute + scatter pair);
-        returns the packed output (caller starts the async readback)."""
+        donated step program; returns the packed output (caller starts
+        the async readback)."""
         t0 = _time.monotonic()
-        pout = self._step(fused_fn, compute_fn, self._h2d(buf))
+        pout = self._step(program, self._h2d(buf))
         self.round_duration.observe(_time.monotonic() - t0)
         return pout
 
@@ -753,41 +704,14 @@ class DecisionEngine:
         # The collapsed program reads state directly: queued pump
         # rounds must land first (ordering contract, core/pump.py).
         self._flush_pump()
-        return self._dispatch(buf, collapsed_step, collapsed_compute)
+        return self._dispatch(buf, collapsed_step)
 
-    def _dispatch_uniform(self, buf: np.ndarray):  # guberlint: holds _lock
-        """Narrow uniform-batch step (pump-only: requires the fused
-        in-place program family)."""
-        from gubernator_tpu.ops.bucket_kernel import uniform_step
-
-        t0 = _time.monotonic()
-        pin = self._h2d(buf)
-        with self._stage("device.launch"):
-            self._state, pout = uniform_step(self._state, pin)
-            self.dispatches_total += 1
-        self.round_duration.observe(_time.monotonic() - t0)
-        return pout
+    def _dispatch_uniform(self, buf: np.ndarray):
+        """Narrow uniform-batch step."""
+        return self._dispatch(buf, uniform_step)
 
     def _dispatch_packed(self, buf: np.ndarray):
-        if self._pallas_interpret is not None:
-            return self._dispatch_pallas(buf)
-        return self._dispatch(buf, fused_step, packed_compute)
-
-    def _dispatch_pallas(self, buf: np.ndarray):  # guberlint: holds _lock
-        """The Pallas single-kernel step (ops/pallas_step.py): the
-        whole gather→update→scatter→pack round as ONE device program
-        over the in-place-aliased state columns."""
-        from gubernator_tpu.ops.pallas_step import pallas_fused_step
-
-        t0 = _time.monotonic()
-        pin = self._h2d(buf)
-        with self._stage("device.launch"):
-            self._state, pout = pallas_fused_step(
-                self._state, pin, interpret=self._pallas_interpret
-            )
-            self.dispatches_total += 1
-        self.round_duration.observe(_time.monotonic() - t0)
-        return pout
+        return self._dispatch(buf, fused_step)
 
     def _flush_pump(self) -> None:
         """Apply queued pump rounds before any OTHER state access (see
@@ -1259,7 +1183,7 @@ class DecisionEngine:
             # device kernel would otherwise pay a sorting network for),
             # packs the whole round into ONE int32 buffer (one h2d op
             # on a dispatch-bound backend — see bucket_kernel
-            # PACKED_IN_ROWS), runs the fused (or split) kernel, and
+            # PACKED_IN_ROWS), runs the donated step program, and
             # starts an async copy of the packed outputs.
             # Materialization happens in PendingColumnar.get(), so the
             # caller can overlap this batch's readback with the next
@@ -1720,10 +1644,10 @@ class DecisionEngine:
                     ]
                     self.get_rate_limits(reqs, now_ms=now)
                     width *= 2
-                # Columnar-kernel ladder: the wire/bench fast path runs the
-                # packed columnar step, a DIFFERENT jitted program than
-                # apply_batch — without this ladder the first served
-                # columnar batch pays an XLA compile that can exceed the
+                # Columnar-kernel ladder: the wire path packs with other
+                # pad widths than the dataclass path above (uniform and
+                # collapsed programs too) — without this ladder the first
+                # served columnar batch pays an XLA compile that can exceed the
                 # peer batch timeout ("timeout waiting for batched
                 # response").
                 width = 64

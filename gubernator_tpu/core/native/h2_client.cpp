@@ -418,7 +418,7 @@ int64_t h2_bench_unary(const char* host, int32_t port, const char* path,
 }  // extern "C"
 
 // ---------------------------------------------------------------------
-// Connection-scale epoll client (BENCH_MODE=connscale, PERF.md §26).
+// Connection-scale epoll client (scripts/connscale_client.py, PERF.md §26).
 //
 // Holds n_conns connections open against one address from a HANDFUL
 // of epoll threads — the client-side mirror of the server's reactor

@@ -9,10 +9,9 @@ GUBER_PAGED_RESIDENT of them resident in the device state array (the
 page store.  The layout follows the Ragged Paged Attention discipline
 (PAPERS.md): the kernels never learn about pages — the host translates
 logical slot → (page, row) → frame*page_size + row BEFORE packing a
-batch, so the XLA fused program, the Pallas kernel, and interpret mode
-all gather/scatter through the same indirection by construction, and
-every compiled program keeps its dense shape at the (much smaller)
-device-resident capacity.
+batch, so every step program gathers and scatters through the same
+indirection by construction, and keeps its dense shape at the (much
+smaller) device-resident capacity.
 
 Residency is a two-hand-clock over frames: every batch sets the
 reference bit of the pages it touches; the eviction hand clears bits

@@ -415,9 +415,9 @@ class Daemon:
         info = device_info.describe(engine)
         log.info(
             "serving on platform=%s device_kind=%s devices=%d engine=%s "
-            "rows=%d fused_mode=%s pump=%s pump_scan=%s probes=%s",
+            "rows=%d pump=%s pump_scan=%s probes=%s",
             info["platform"], info["device_kind"], info["device_count"],
-            info["engine"], info["rows"], info["fused_mode"],
+            info["engine"], info["rows"],
             info["pump"], info["pump_scan"], info["probes"],
         )
         if info["cpu_unrequested"]:

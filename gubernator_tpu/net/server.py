@@ -38,7 +38,7 @@ def _decode_columns(items) -> Optional[Tuple]:
 
     This skips dataclass materialization entirely for the common case —
     the decoded columns feed DecisionEngine.apply_columnar, the same
-    program bench.py measures (reference hot path: gubernator.go:197-317).
+    program the wire route serves (reference hot path: gubernator.go:197-317).
     """
     n = len(items)
     if n == 0 or n > MAX_BATCH_SIZE:

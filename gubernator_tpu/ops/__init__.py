@@ -8,9 +8,7 @@ computation over the whole batch (SURVEY.md §7.1).
 from gubernator_tpu.ops.bucket_kernel import (
     BucketState,
     BatchInput,
-    BatchOutput,
-    apply_batch,
     make_state,
 )
 
-__all__ = ["BucketState", "BatchInput", "BatchOutput", "apply_batch", "make_state"]
+__all__ = ["BucketState", "BatchInput", "make_state"]

@@ -47,9 +47,8 @@ _I64 = jnp.int64
 _I32 = jnp.int32
 _F64 = jnp.float64
 
-# numpy scalars (not jnp): they inline as jaxpr literals, which keeps
-# the shared lane math embeddable in a Pallas kernel body — a kernel
-# may not close over materialized device constants (ops/pallas_step.py).
+# numpy scalars (not jnp): they inline as jaxpr literals instead of
+# materializing device constants at import.
 _OVER = np.int32(int(Status.OVER_LIMIT))
 _UNDER = np.int32(int(Status.UNDER_LIMIT))
 
@@ -128,22 +127,13 @@ class BatchInput(NamedTuple):
     greg_expire: jax.Array  # int64
 
 
-class BatchOutput(NamedTuple):
-    """Per-request responses (reference: proto/gubernator.proto:169-182)."""
-
-    status: jax.Array  # int32
-    limit: jax.Array  # int64
-    remaining: jax.Array  # int64
-    reset_time: jax.Array  # int64
-
-
 _U32 = jnp.uint32
 
 
 def make_state(capacity: int) -> BucketState:
     """Allocate an empty state of `capacity` slots.
 
-    Every field gets its own buffer — `apply_batch` donates the whole
+    Every field gets its own buffer — every step donates the whole
     state, and aliased buffers cannot be donated twice."""
 
     def z(dt):
@@ -406,92 +396,14 @@ def _clear_occupied_impl(meta: jax.Array, slots: jax.Array) -> jax.Array:
 clear_occupied = jax.jit(_clear_occupied_impl, donate_argnums=(0,))
 
 
-# guberlint: shapes state fixed at capacity; batch lanes padded to the pow2 width ladder (warmup 64..1024)
-def _apply_batch_impl(
-    state: BucketState,
-    batch: BatchInput,
-    clear_slots: jax.Array,  # int32 [C]; padding = out-of-range ascending
-    now_ms: jax.Array,  # int64 scalar
-) -> tuple[BucketState, BatchOutput]:
-    cap = state.meta.shape[0]
-    now = now_ms.astype(_I64)
-
-    # A TPU scatter with arbitrary indices lowers to a serial loop over
-    # the lanes (~1µs a lane for the twelve columns — measured 8ms for
-    # an 8k batch); with `indices_are_sorted` + `unique_indices` it was
-    # ~200x faster AT THE <= 2 M ROWS OF ROUND 2, and is a pass over the
-    # table that costs 14 ms a step at 100 M rows (`_scatter_hints`
-    # chooses).  Rounds guarantee uniqueness (engine invariant);
-    # sortedness comes from co-sorting the whole batch by slot with one
-    # multi-operand lax.sort (a sorting network — no random access), and
-    # responses are restored to request order by a second sort keyed on
-    # the lane index.  Padding uses distinct ascending out-of-range
-    # slots (cap + lane) so both flags stay truthful wherever given.
-    lane = jnp.arange(batch.slot.shape[0], dtype=_I32)
-    (
-        slot,
-        lane_s,
-        r_algo,
-        r_beh,
-        r_hits,
-        r_limit,
-        r_dur,
-        r_burst,
-        r_gdur,
-        r_gexp,
-    ) = jax.lax.sort(
-        (
-            batch.slot,
-            lane,
-            batch.algo,
-            batch.behavior,
-            batch.hits,
-            batch.limit,
-            batch.duration,
-            batch.burst,
-            batch.greg_duration,
-            batch.greg_expire,
-        ),
-        num_keys=1,
-    )
-    # Host-side eviction: mark reclaimed slots unoccupied before applying
-    # the batch (the reference evicts inline in the LRU; here eviction is
-    # a host decision executed on device, SURVEY.md §7.3 item 6).
-    occupied = _clear_occupied_impl(state.meta, clear_slots)
-
-    new_state, resp_status, resp_rem, resp_reset = _apply_core(
-        state, occupied, slot, r_algo, r_beh, r_hits, r_limit, r_dur,
-        r_burst, r_gdur, r_gexp, now,
-    )
-
-    # Un-sort: restore responses to request order via a sort on lane idx.
-    _, o_status, o_limit, o_rem, o_reset = jax.lax.sort(
-        (lane_s, resp_status.astype(_I32), r_limit, resp_rem, resp_reset),
-        num_keys=1,
-    )
-    out = BatchOutput(
-        status=o_status,
-        limit=o_limit,
-        remaining=o_rem,
-        reset_time=o_reset,
-    )
-    return new_state, out
-
-
-def _apply_core(
-    state: BucketState,
-    occupied: jax.Array,
-    slot: jax.Array,
-    *args,
-):
-    """gather → update → scatter in ONE program (single-call variants).
-
-    Hot paths use the split pair (`_compute_update` + `scatter_store`)
-    instead — see `_scatter_values` for why."""
+def _apply_core(state: BucketState, slot: jax.Array, *args):
+    """gather → update → scatter in ONE program: the body of the
+    packed and the uniform step.  With the state donated it must
+    compile in place — `fused_step_ok` checks that it does."""
     vals, resp_status, resp_rem, resp_reset = _compute_update(
-        state, occupied, slot, *args
+        state, slot, *args
     )
-    new_state = _scatter_values(state._replace(meta=occupied), slot, vals)
+    new_state = _scatter_values(state, slot, vals)
     return new_state, resp_status, resp_rem, resp_reset
 
 
@@ -500,13 +412,11 @@ class GatheredSlots(NamedTuple):
     values for each request lane's slot, still encoded (meta/hi2 bit
     packings, hi/lo word pairs).  Shape [B] per field.
 
-    This is the seam between the two halves of the decision step: the
-    XLA path produces it with `gather_slots` (one sorted/unique gather
-    per column) and the Pallas kernel produces it with its in-kernel
-    gather loop (ops/pallas_step.py) — both feed the SAME
-    `update_lanes` math, so the two backends cannot drift."""
+    This is the seam between the two halves of the decision step:
+    `gather_slots` produces it (one sorted/unique gather per column)
+    and `update_lanes` is the math over it."""
 
-    meta: jax.Array  # int32 (possibly clear-updated meta array)
+    meta: jax.Array  # int32
     hi2: jax.Array  # int32
     t0_lo: jax.Array  # uint32
     expire_lo: jax.Array  # uint32
@@ -520,13 +430,10 @@ class GatheredSlots(NamedTuple):
     burst_lo: jax.Array  # uint32
 
 
-def gather_slots(
-    state: BucketState, occupied: jax.Array, slot: jax.Array
-) -> GatheredSlots:
+def gather_slots(state: BucketState, slot: jax.Array) -> GatheredSlots:
     """Gather the raw state words for slot-sorted lanes (fill 0 for
-    out-of-range padding lanes).  `occupied` is the meta array to read
-    occupancy from (it may carry this round's eviction clears).
-    Field order tracks BucketState (the gather zips the two)."""
+    out-of-range padding lanes).  Field order tracks BucketState (the
+    gather zips the two)."""
 
     def g(arr):
         return arr.at[slot].get(
@@ -536,14 +443,11 @@ def gather_slots(
             unique_indices=True,
         )
 
-    return GatheredSlots(
-        *(g(arr) for arr in state._replace(meta=occupied))
-    )
+    return GatheredSlots(*(g(arr) for arr in state))
 
 
 def _compute_update(
     state: BucketState,
-    occupied: jax.Array,
     slot: jax.Array,  # int32 [B] SORTED ascending, unique; padding = cap+i
     r_algo: jax.Array,
     r_beh: jax.Array,
@@ -560,7 +464,7 @@ def _compute_update(
     remaining, reset_time) with everything in the SORTED lane order."""
     cap = state.meta.shape[0]
     mask = slot < cap
-    g = gather_slots(state, occupied, slot)
+    g = gather_slots(state, slot)
     return update_lanes(
         g, mask, r_algo, r_beh, r_hits, r_limit, r_dur, r_burst,
         r_gdur, r_gexp, now,
@@ -598,8 +502,8 @@ def update_lanes(
     now: jax.Array,
 ):
     """The branch-free bucket update over already-gathered lanes: the
-    pure vector math between gather and scatter, shared verbatim by the
-    XLA programs and the Pallas kernel (see GatheredSlots)."""
+    pure vector math between gather and scatter, shared by every step
+    program (see GatheredSlots)."""
     s_meta = g.meta
     s_occ = meta_occupied(s_meta) & mask
     s_algo = meta_algo(s_meta)
@@ -813,9 +717,9 @@ def update_lanes(
 
 
 class SlotValues(NamedTuple):
-    """Per-lane values to store after an update — the write half of the
-    split kernel, shape [B] per field (combined int64; split into hi/lo
-    words inside the scatter program)."""
+    """Per-lane values to store after an update, shape [B] per field
+    (combined int64; `encode_slot_values` splits them into hi/lo
+    words for the scatter)."""
 
     occ: jax.Array  # bool
     algo: jax.Array  # int32
@@ -831,9 +735,9 @@ class SlotValues(NamedTuple):
 
 class StoredWords(NamedTuple):
     """Per-lane encoded column words to store — field-for-field aligned
-    with BucketState so a scatter (XLA) or an in-kernel store loop
-    (Pallas) can zip the two.  Shape [B] per field; dtypes are the
-    logical pre-cast ones (the store casts to each column's dtype)."""
+    with BucketState so the scatter can zip the two.  Shape [B] per
+    field; dtypes are the logical pre-cast ones (the store casts to
+    each column's dtype)."""
 
     meta: jax.Array
     hi2: jax.Array
@@ -851,8 +755,7 @@ class StoredWords(NamedTuple):
 
 def encode_slot_values(vals: SlotValues) -> StoredWords:
     """Encode computed slot values into the packed column words — the
-    pure half of the write path, shared by `_scatter_values` and the
-    Pallas kernel's store loop (update always clears invalid_at)."""
+    pure half of `_scatter_values` (update always clears invalid_at)."""
     algo_norm = (vals.algo != 0).astype(_I32)
     t0c = clamp_ts(vals.t0)
     invc = jnp.zeros_like(t0c)  # updates always clear invalid_at
@@ -882,18 +785,18 @@ def encode_slot_values(vals: SlotValues) -> StoredWords:
     )
 
 
-# guberlint: shapes state fixed at capacity; slot/vals [W] on the same pow2 width ladder as the compute step
 def _scatter_values(
     state: BucketState, slot: jax.Array, vals: SlotValues
 ) -> BucketState:
-    """WRITE-ONLY scatter of computed slot values into the state.
+    """Scatter of computed slot values into the state — the write half
+    of every step program.
 
-    Kept free of any other read of the state arrays on purpose: when
-    jitted with donated state this compiles to a true in-place update.
-    A program that gathers from and scatters into the same donated
-    buffer forces XLA's copy-insertion to clone every state array —
-    measured 18 full-capacity copies (~41ms at 2M slots, O(capacity)
-    per batch) before the kernel was split into compute + scatter.
+    The steps gather from and scatter into the same donated buffers.
+    Where XLA's copy-insertion answers that by cloning every state
+    array (measured once, on an early backend: 18 full-capacity copies,
+    ~41 ms at 2 M slots, O(capacity) a batch) a step is unusable, which
+    is why the engines compile `fused_step_ok`'s probe at start and, on
+    an accelerator, refuse to serve on a no.
     `slot` is sorted with distinct out-of-range padding → the hints
     hold wherever `_scatter_hints` gives them (a small table: one
     streaming pass a column; at 100 M rows that pass was 1.19 ms a
@@ -915,83 +818,6 @@ def _scatter_values(
     )
 
 
-# Donated write-only scatter: compiles to a true in-place update (no
-# full-capacity copies) because the program never reads what it writes.
-scatter_store = jax.jit(_scatter_values, donate_argnums=(0,))
-
-apply_batch = jax.jit(_apply_batch_impl, donate_argnums=(0,))
-
-
-# guberlint: shapes state fixed at capacity; batch lanes padded to the pow2 width ladder (warmup 64..1024)
-def _apply_batch_sorted_impl(
-    state: BucketState,
-    batch: BatchInput,  # lanes PRE-SORTED by slot ascending (host sorts)
-    now_ms: jax.Array,
-):
-    """Sort-free variant: the host (which assigned the slots) delivers
-    lanes already slot-sorted, so the device runs only gather → update
-    → scatter — no O(B log²B) sorting network to compile or execute.
-    Outputs are packed into ONE flat int64 buffer
-    [status… remaining… reset_time…] so the host pays a single
-    device→host transfer per step.  Responses stay in the sorted lane
-    order; the host unpermutes with the inverse of its own argsort.
-    """
-    new_state, resp_status, resp_rem, resp_reset = _apply_core(
-        state,
-        state.meta,
-        batch.slot,
-        batch.algo,
-        batch.behavior,
-        batch.hits,
-        batch.limit,
-        batch.duration,
-        batch.burst,
-        batch.greg_duration,
-        batch.greg_expire,
-        now_ms.astype(_I64),
-    )
-    packed = jnp.concatenate(
-        [resp_status.astype(_I64), resp_rem, resp_reset]
-    )
-    return new_state, packed
-
-
-apply_batch_sorted = jax.jit(_apply_batch_sorted_impl, donate_argnums=(0,))
-
-
-# guberlint: shapes state fixed at capacity; batch lanes padded to the pow2 width ladder (warmup 64..1024)
-def _compute_update_sorted_impl(
-    state: BucketState,
-    batch: BatchInput,  # lanes PRE-SORTED by slot ascending (host sorts)
-    now_ms: jax.Array,
-):
-    """Compute half of the sorted columnar step: gathers + bucket math,
-    NO state writes.  Pair with `scatter_store` (donated) — the split
-    keeps the in-place scatter free of full-capacity copy-insertion
-    (see `_scatter_values`)."""
-    vals, resp_status, resp_rem, resp_reset = _compute_update(
-        state,
-        state.meta,
-        batch.slot,
-        batch.algo,
-        batch.behavior,
-        batch.hits,
-        batch.limit,
-        batch.duration,
-        batch.burst,
-        batch.greg_duration,
-        batch.greg_expire,
-        now_ms.astype(_I64),
-    )
-    packed = jnp.concatenate(
-        [resp_status.astype(_I64), resp_rem, resp_reset]
-    )
-    return vals, packed
-
-
-compute_update_sorted = jax.jit(_compute_update_sorted_impl)
-
-
 # ---------------------------------------------------------------------------
 # Packed single-transfer step — the serving fast path.
 #
@@ -999,9 +825,9 @@ compute_update_sorted = jax.jit(_compute_update_sorted_impl)
 # dispatch cost next to which the HBM/compute time of an 8k-lane step
 # is small, so the design reason is fewer transfers and dispatches per
 # decision.  The columnar path packs the WHOLE request round into ONE
-# int32 [PACKED_IN_ROWS, B] host buffer (one h2d op), runs ONE (or
-# two, see below) kernels, and reads back ONE int32
-# [PACKED_OUT_ROWS, B] buffer.  Layout:
+# int32 [PACKED_IN_ROWS, B] host buffer (one h2d op), runs ONE
+# program, and reads back ONE int32 [PACKED_OUT_ROWS, B] buffer.
+# Layout:
 #
 #   row 0      header: [now_hi, now_lo, 0, ...]   (now_ms int64 words)
 #   row 1      slot    (int32; sorted ascending; padding = cap + lane)
@@ -1119,7 +945,6 @@ def _fused_step_core(state: BucketState, pin: jax.Array):
     batch, now = _unpack_in(pin)
     new_state, resp_status, resp_rem, resp_reset = _apply_core(
         state,
-        state.meta,
         batch.slot,
         batch.algo,
         batch.behavior,
@@ -1134,10 +959,11 @@ def _fused_step_core(state: BucketState, pin: jax.Array):
     return new_state, _pack_out(resp_status, resp_rem, resp_reset)
 
 
-# Fused gather→update→scatter with donated state: ONE device op per
-# round.  Whether XLA compiles the in-place RMW without cloning the
-# state is platform-dependent — callers MUST check `fused_step_ok()`
-# (memory_analysis probe) and fall back to the split pair below.
+# Gather→update→scatter with donated state: ONE device op per round.
+# Whether XLA compiles the in-place RMW without cloning the state is
+# platform-dependent — both engines check `fused_step_ok()`
+# (memory_analysis probe) at construction (core/engine.py
+# `require_in_place`).
 fused_step = jax.jit(_fused_step_core, donate_argnums=(0,))
 
 
@@ -1247,7 +1073,7 @@ def _uniform_step_core(state: BucketState, pin: jax.Array):
     burst = bc(hdr[8].astype(_I64))
     zeros = jnp.zeros((w,), dtype=_I64)
     new_state, status, rem, reset = _apply_core(
-        state, state.meta, slot, algo, behavior, hits, limit,
+        state, slot, algo, behavior, hits, limit,
         duration, burst, zeros, zeros, now,
     )
     pout = jnp.stack(
@@ -1344,33 +1170,6 @@ def multi_step_ok(
     )
 
 
-# guberlint: shapes pin [PACKED_IN_ROWS, W] int32, W on the pow2 width ladder; state fixed at capacity
-def _packed_compute_core(state: BucketState, pin: jax.Array):
-    batch, now = _unpack_in(pin)
-    vals, resp_status, resp_rem, resp_reset = _compute_update(
-        state,
-        state.meta,
-        batch.slot,
-        batch.algo,
-        batch.behavior,
-        batch.hits,
-        batch.limit,
-        batch.duration,
-        batch.burst,
-        batch.greg_duration,
-        batch.greg_expire,
-        now,
-    )
-    # `slot` is returned as a device output so the follow-up
-    # scatter_store needs no second host transfer.
-    return batch.slot, vals, _pack_out(resp_status, resp_rem, resp_reset)
-
-
-# Split pair: read-only compute (no donation) + donated write-only
-# scatter_store — two device ops, guaranteed copy-free everywhere.
-packed_compute = jax.jit(_packed_compute_core)
-
-
 # ---------------------------------------------------------------------------
 # Collapsed duplicate-segment step.
 #
@@ -1412,7 +1211,6 @@ packed_compute = jax.jit(_packed_compute_core)
 COLLAPSED_IN_ROWS = 19
 
 
-# guberlint: shapes pin [COLLAPSED_IN_ROWS, W] int32, W on the pow2 width ladder; state fixed at capacity
 def _collapsed_values(state: BucketState, pin: jax.Array):
     now = (pin[0, 0].astype(_I64) << 32) | (pin[0, 1].astype(_I64) & 0xFFFFFFFF)
     slot = pin[1]
@@ -1434,7 +1232,7 @@ def _collapsed_values(state: BucketState, pin: jax.Array):
 
     # First application per segment: the full bucket update.
     vals, st1, rem1, rst1 = _compute_update(
-        state, state.meta, slot, s_algo, s_beh, s_hits, s_limit,
+        state, slot, s_algo, s_beh, s_hits, s_limit,
         s_dur, s_burst, s_gdur, s_gexp, now,
     )
 
@@ -1518,10 +1316,7 @@ def _collapsed_step_core(state: BucketState, pin: jax.Array):
     return _scatter_values(state, slot, vals2), packed
 
 
-# Fused (donated RMW) and split variants, mirroring fused_step /
-# packed_compute — the engine picks by the same fused_step_ok probe.
 collapsed_step = jax.jit(_collapsed_step_core, donate_argnums=(0,))
-collapsed_compute = jax.jit(_collapsed_values)
 
 
 def pack_collapsed_host(
@@ -1577,8 +1372,10 @@ def pack_collapsed_host(
 @functools.lru_cache(maxsize=None)
 def fused_step_ok(capacity: int, width: int = 64) -> ProbeVerdict:
     """Probe whether `fused_step` compiles to a true in-place update
-    at this capacity (tiny width).  On a no, callers must use the
-    split pair instead."""
+    at this capacity (tiny width).  A no is a fault, not a choice: a
+    step that clones the state would copy the whole table every
+    dispatch, so on an accelerator both engines refuse to start on it
+    (core/engine.py `require_in_place`)."""
     return _in_place_probe(fused_step, capacity, (PACKED_IN_ROWS, width))
 
 
@@ -1713,26 +1510,3 @@ def _load_page_words_impl(
 
 load_page_words = jax.jit(_load_page_words_impl, donate_argnums=(0,))
 
-
-def batch_input_from_numpy(
-    slot: np.ndarray,
-    algo: np.ndarray,
-    behavior: np.ndarray,
-    hits: np.ndarray,
-    limit: np.ndarray,
-    duration: np.ndarray,
-    burst: np.ndarray,
-    greg_duration: np.ndarray,
-    greg_expire: np.ndarray,
-) -> BatchInput:
-    return BatchInput(
-        slot=jnp.asarray(slot, dtype=_I32),
-        algo=jnp.asarray(algo, dtype=_I32),
-        behavior=jnp.asarray(behavior, dtype=_I32),
-        hits=jnp.asarray(hits, dtype=_I64),
-        limit=jnp.asarray(limit, dtype=_I64),
-        duration=jnp.asarray(duration, dtype=_I64),
-        burst=jnp.asarray(burst, dtype=_I64),
-        greg_duration=jnp.asarray(greg_duration, dtype=_I64),
-        greg_expire=jnp.asarray(greg_expire, dtype=_I64),
-    )

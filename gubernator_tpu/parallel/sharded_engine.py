@@ -39,6 +39,7 @@ from gubernator_tpu.ops.bucket_kernel import (
     fused_step_ok,
     make_state,
 )
+from gubernator_tpu.core.engine import require_in_place
 from gubernator_tpu.core.native import make_intern_table
 from gubernator_tpu.parallel.mesh import (
     KEYS_AXIS,
@@ -184,14 +185,19 @@ class ShardedDecisionEngine:
             ),
             jax.eval_shape(lambda: make_state(shard_capacity)),
         )
+        # The per-shard program is the same computation as the
+        # single-device step, so its copy-insertion probes identically
+        # at shard capacity; on an accelerator a no refuses the start
+        # (core/engine.py `require_in_place`).
+        self.probes: dict = {
+            "fused_step": require_in_place(fused_step_ok(shard_capacity))
+        }
         self._build_step()
 
     # ------------------------------------------------------------------
 
     def _build_step(self):
         mesh = self.mesh
-        cap = self.shard_capacity
-
         pspec = P(KEYS_AXIS)
 
         if self._single_program:
@@ -215,10 +221,8 @@ class ShardedDecisionEngine:
         )
 
         from gubernator_tpu.ops.bucket_kernel import (
-            SlotValues,
             _collapsed_values,
             _fused_step_core,
-            _packed_compute_core,
             _scatter_values,
         )
 
@@ -231,10 +235,6 @@ class ShardedDecisionEngine:
             new_state, pout = _fused_step_core(_squeeze(state), pin[0])
             return _expand(new_state), pout[None]
 
-        def local_packed_compute(state, pin):
-            slot, vals, pout = _packed_compute_core(_squeeze(state), pin[0])
-            return slot[None], _expand(vals), pout[None]
-
         # Collapsed duplicate-segment step per shard (hot keys — see
         # bucket_kernel COLLAPSED_IN_ROWS; the single-device engine's
         # closed form, run under shard_map).
@@ -243,42 +243,13 @@ class ShardedDecisionEngine:
             slot, vals2, pout = _collapsed_values(state1, pin[0])
             return _expand(_scatter_values(state1, slot, vals2)), pout[None]
 
-        def local_collapsed_compute(state, pin):
-            slot, vals2, pout = _collapsed_values(_squeeze(state), pin[0])
-            return slot[None], _expand(vals2), pout[None]
-
-        def local_scatter(state, slot, vals):
-            return _expand(
-                _scatter_values(_squeeze(state), slot[0], _squeeze(vals))
-            )
-
         state_specs2 = jax.tree.map(lambda _: pspec, make_state(0))
-        vals_specs = jax.tree.map(
-            lambda _: pspec, SlotValues(*(0,) * len(SlotValues._fields))
-        )
         self._packed_fused = jax.jit(
             _shard_map(
                 local_packed_fused,
                 mesh=mesh,
                 in_specs=(state_specs2, pspec),
                 out_specs=(state_specs2, pspec),
-            ),
-            donate_argnums=(0,),
-        )
-        self._packed_compute = jax.jit(
-            _shard_map(
-                local_packed_compute,
-                mesh=mesh,
-                in_specs=(state_specs2, pspec),
-                out_specs=(pspec, vals_specs, pspec),
-            )
-        )
-        self._step_scatter = jax.jit(
-            _shard_map(
-                local_scatter,
-                mesh=mesh,
-                in_specs=(state_specs2, pspec, vals_specs),
-                out_specs=state_specs2,
             ),
             donate_argnums=(0,),
         )
@@ -290,14 +261,6 @@ class ShardedDecisionEngine:
                 out_specs=(state_specs2, pspec),
             ),
             donate_argnums=(0,),
-        )
-        self._collapsed_compute = jax.jit(
-            _shard_map(
-                local_collapsed_compute,
-                mesh=mesh,
-                in_specs=(state_specs2, pspec),
-                out_specs=(pspec, vals_specs, pspec),
-            )
         )
         # Store read-through hydration: sharded counterpart of
         # core.engine load_slots (one batched scatter per round).
@@ -318,22 +281,7 @@ class ShardedDecisionEngine:
             ),
             donate_argnums=(0,),
         )
-        self._select_step()
         self._flat_ok = False  # flat dispatch is single-program-only
-
-    def _select_step(self) -> None:
-        """Fused or split, by the compile probe.  The per-shard
-        program is the same computation as the single-device fused
-        step, so its copy-insertion behavior probes identically at
-        shard capacity."""
-        from gubernator_tpu.core.engine import record_probe
-
-        self.probes: dict = {}
-        self._fused = record_probe(
-            self.probes, "fused_step", fused_step_ok(self.shard_capacity),
-            "the split compute+scatter pair",
-        )
-        self.fused_mode = "xla" if self._fused else "split"
 
     def _build_step_single_program(self):
         """One vmapped XLA program over the [n_shards, ...] leading
@@ -346,17 +294,12 @@ class ShardedDecisionEngine:
             _collapsed_values,
             _fused_step_core,
             _load_slots_impl,
-            _packed_compute_core,
             _scatter_values,
         )
 
         self._clear_step = jax.jit(jax.vmap(_clear_occupied_impl))
         self._packed_fused = jax.jit(
             jax.vmap(_fused_step_core), donate_argnums=(0,)
-        )
-        self._packed_compute = jax.jit(jax.vmap(_packed_compute_core))
-        self._step_scatter = jax.jit(
-            jax.vmap(_scatter_values), donate_argnums=(0,)
         )
 
         # The one-device program itself, vmapped: it keeps the module
@@ -365,11 +308,9 @@ class ShardedDecisionEngine:
         self._collapsed_fused = jax.jit(
             jax.vmap(_collapsed_step_core), donate_argnums=(0,)
         )
-        self._collapsed_compute = jax.jit(jax.vmap(_collapsed_values))
         self._load_step = jax.jit(
             jax.vmap(_load_slots_impl), donate_argnums=(0,)
         )
-        self._select_step()
 
         # Flat executors: the hot columnar path globalizes slots
         # (shard*cap + slot) and runs the WHOLE batch as one
@@ -394,36 +335,17 @@ class ShardedDecisionEngine:
             st, pout = _fused_step_core(_flatten(state), pin[0])
             return _unflatten(st), pout[None]
 
-        def flat_packed_compute(state, pin):
-            slot, vals, pout = _packed_compute_core(_flatten(state), pin[0])
-            return slot[None], _expand(vals), pout[None]
-
-        def flat_scatter(state, slot, vals):
-            return _unflatten(
-                _scatter_values(_flatten(state), slot[0], _squeeze(vals))
-            )
-
         def flat_collapsed_fused(state, pin):
             st = _flatten(state)
             slot, vals2, pout = _collapsed_values(st, pin[0])
             return _unflatten(_scatter_values(st, slot, vals2)), pout[None]
 
-        def flat_collapsed_compute(state, pin):
-            slot, vals2, pout = _collapsed_values(_flatten(state), pin[0])
-            return slot[None], _expand(vals2), pout[None]
-
         # guberlint: shapes pin [1, PACKED_IN_ROWS, W] per shard, W on the width ladder; state [n_sh, cap] fixed
         self._flat_fused = jax.jit(flat_packed_fused, donate_argnums=(0,))
-        # guberlint: shapes same pin/state contract as _flat_fused (split compute half)
-        self._flat_compute = jax.jit(flat_packed_compute)
-        # guberlint: shapes slot/vals [1, W] on the width ladder; state [n_sh, cap] fixed
-        self._flat_scatter = jax.jit(flat_scatter, donate_argnums=(0,))
         # guberlint: shapes pin [1, COLLAPSED_IN_ROWS, W] on the width ladder; state [n_sh, cap] fixed
         self._flat_collapsed_fused = jax.jit(
             flat_collapsed_fused, donate_argnums=(0,)
         )
-        # guberlint: shapes same pin/state contract as _flat_collapsed_fused (split compute half)
-        self._flat_collapsed_compute = jax.jit(flat_collapsed_compute)
 
     # ------------------------------------------------------------------
 
@@ -440,20 +362,14 @@ class ShardedDecisionEngine:
         with self._stage("device.h2d"):
             return jax.device_put(host, self._placement)
 
-    def _step(self, fused, compute, scatter, pin):
-        """One round's mesh program(s) — the fused donated step, or the
-        split compute + scatter pair — as ONE device.launch: the
-        jitted call returning (the enqueue) and the donated state's
-        old buffers let go.  Returns the packed output."""
+    def _step(self, program, pin):
+        """One round's donated mesh step program as ONE device.launch:
+        the jitted call returning (the enqueue) and the donated
+        state's old buffers let go.  Returns the packed output."""
         # guberlint: ok drift — sharded twin of engine.py's device.launch site
         with self._stage("device.launch"):
-            if self._fused:
-                self._state, pout = fused(self._state, pin)
-                self.dispatches_total += 1
-            else:
-                slot_dev, vals, pout = compute(self._state, pin)
-                self._state = scatter(self._state, slot_dev, vals)
-                self.dispatches_total += 2
+            self._state, pout = program(self._state, pin)
+            self.dispatches_total += 1
         return pout
 
     def _apply_shard_clears(self, clears: List[List[int]]) -> None:
@@ -725,10 +641,7 @@ class ShardedDecisionEngine:
             limits_of.append(c_limit)
 
         t0 = _time.monotonic()
-        pout = self._step(
-            self._packed_fused, self._packed_compute, self._step_scatter,
-            self._put(buf),
-        )
+        pout = self._step(self._packed_fused, self._put(buf))
         self.round_duration.observe(_time.monotonic() - t0)
 
         arr = self.readback.register(pout).fetch()
@@ -1509,16 +1422,9 @@ class ShardedDecisionEngine:
         if clears is not None:
             self._apply_shard_clears(clears)
 
-        if flat:
-            programs = (
-                self._flat_collapsed_fused,
-                self._flat_collapsed_compute, self._flat_scatter,
-            )
-        else:
-            programs = (
-                self._collapsed_fused, self._collapsed_compute,
-                self._step_scatter,
-            )
+        program = (
+            self._flat_collapsed_fused if flat else self._collapsed_fused
+        )
         pieces: List[tuple] = []
         empty64 = np.empty(0, dtype=_I64)
         for lo in range(0, max_lanes, self.max_kernel_width):
@@ -1567,7 +1473,7 @@ class ShardedDecisionEngine:
                     dst_rows.append(c_src)
 
             t0 = _time.monotonic()
-            pout = self._step(*programs, self._put(buf))
+            pout = self._step(program, self._put(buf))
             self.round_duration.observe(_time.monotonic() - t0)
             self.rounds_total += 1
             pieces.append(
@@ -1670,15 +1576,10 @@ class ShardedDecisionEngine:
                 dst_rows.append(idx_sorted)
 
         t0 = _time.monotonic()
-        if flat:
-            programs = (
-                self._flat_fused, self._flat_compute, self._flat_scatter
-            )
-        else:
-            programs = (
-                self._packed_fused, self._packed_compute, self._step_scatter
-            )
-        pout = self._step(*programs, self._put(buf))
+        pout = self._step(
+            self._flat_fused if flat else self._packed_fused,
+            self._put(buf),
+        )
         if merging:
             # psum GLOBAL merge: scatter every shard's lanes to their
             # request positions on device and sum across the mesh —

@@ -4,9 +4,8 @@ Tier-1 tests, the multi-chip dry run and the host-side scripts run on
 the CPU backend — the tests on an 8-device virtual mesh — whatever
 accelerator the machine has.  `force_cpu_platform` is the one place
 that does it (`tests/conftest.py`, `__graft_entry__.dryrun_multichip`,
-`bench.py` under `BENCH_FORCE_CPU=1`, the daemon under
-`GUBER_PLATFORM=cpu`, `scripts/`); keep the logic here so it cannot
-drift.
+the daemon under `GUBER_PLATFORM=cpu`, `scripts/`); keep the logic
+here so it cannot drift.
 
 Must be called before any jax backend initializes (first array op /
 `jax.devices()`): `XLA_FLAGS` is read at backend-init time, and the

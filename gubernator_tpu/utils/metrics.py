@@ -10,7 +10,7 @@ Collector at scrape time, which also serves as the test oracle
 
 This file is the metric REGISTRY guberlint's drift pass anchors on:
 every ``*MetricFamily`` name constructed here must appear in the
-README catalog (or PERF/RESILIENCE/STATIC_ANALYSIS/bench_trend), and
+README catalog (or PERF/RESILIENCE/STATIC_ANALYSIS/OBSERVABILITY), and
 every documented ``gubernator_*`` series must still be constructed
 here — registering a metric without documenting it fails CI.
 """
@@ -875,8 +875,8 @@ class InstanceCollector(Collector):
         yield g
 
         # The RAW per-stage histograms behind the quantile gauge: a
-        # cross-node scraper (obs/fleet.py, bench.py's multi-node
-        # stage budgets) needs the bucket counts to MERGE histograms
+        # cross-node scraper (obs/fleet.py) needs the bucket counts
+        # to MERGE histograms
         # into real cluster quantiles — averaging per-node p99s is
         # the means-of-means lie the rollup exists to retire.  Tail
         # buckets carry OpenMetrics exemplars (last sampled trace_id)

@@ -1,10 +1,10 @@
 #!/usr/bin/env python
-"""Connection-scale load generator subprocess (BENCH_MODE=connscale).
+"""Connection-scale load generator subprocess.
 
 Runs the epoll connscale client (core/h2_client.h2_connscale_run)
 against ADDRESS and prints ONE JSON line with the results.  A
 subprocess because fds are the scarce resource: at the 10k rung the
-server (the bench process) and the client each hold one fd per
+server and the client each hold one fd per
 connection, and RLIMIT_NOFILE is per-process — colocating both halves
 would cap the ramp at half the limit.
 

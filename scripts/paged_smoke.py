@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Paged-state smoke: one fast pass over the GUBER_PAGED plane's
 load-bearing contract (ci_fast stage; 30 s wall budget enforced by
-the caller, jax on CPU — interpret-mode engine, no TPU).
+the caller, jax on CPU, no TPU).
 
 Asserts, in order:
   1. a paged engine boots with device capacity = frames x page_size
@@ -30,7 +30,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["GUBER_PAGED"] = "1"
 os.environ["GUBER_PAGE_SIZE"] = "16"
 os.environ["GUBER_PAGED_RESIDENT"] = "4"
-os.environ["GUBER_FUSED"] = "interpret"
 os.environ["GUBER_PUMP"] = "0"
 
 import numpy as np

@@ -1,15 +1,18 @@
-"""Fused-kernel parity: the Pallas decision step (interpret mode on
-CPU) must be BIT-EQUAL to the scalar spec (models/spec.py), to the XLA
-fused program, and to the ledger-fronted serve partition — token and
+"""Step parity: the served step programs — `fused_step` and the mesh's
+`local_packed_fused` — must be BIT-EQUAL to the scalar spec
+(models/spec.py), driven directly in the packed layout, and so must
+the engine and the ledger-fronted serve partition over it — token and
 leaky buckets, duration-change renewal, and expiry boundaries included
 (the test_ledger.py harness shape).
 
 Also pins the ISSUE 10 acceptance invariant directly: a steady-state
-fused decision batch runs as a SINGLE device dispatch.
+decision batch runs as a SINGLE device dispatch.
 """
 
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -17,21 +20,27 @@ from gubernator_tpu.clock import Clock
 from gubernator_tpu.core.engine import DecisionEngine, PackedKeys
 from gubernator_tpu.models.spec import SlotState, SpecInput, apply_spec
 from gubernator_tpu.ops import bucket_kernel as bk
-from gubernator_tpu.ops.pallas_step import pallas_fused_step
-from gubernator_tpu.types import Algorithm, Behavior, Status
+from gubernator_tpu.parallel.mesh import make_mesh
+from gubernator_tpu.parallel.sharded_engine import ShardedDecisionEngine
+from gubernator_tpu.types import Behavior
 
-SECOND = 1000
+CAPACITY = 512
 
 
-class PallasShadow:
-    """Drives the Pallas kernel (interpret mode) directly: key → slot
-    interning on the host, packed rounds through pallas_fused_step —
-    the exact serving layout, minus the engine plumbing."""
+class StepShadow:
+    """Drives a served step program directly: key → slot interning on
+    the host, packed rounds through `step(state, pin)` — the exact
+    serving layout, minus the engine plumbing.  `lead` is the mesh
+    programs' leading shard axis (state [1, cap], pin[None])."""
 
-    def __init__(self, capacity: int = 512, width: int = 64):
-        self.capacity = capacity
+    def __init__(self, step, lead: bool, width: int = 64):
+        self.step = step
+        self.lead = lead
+        self.capacity = CAPACITY
         self.width = width
-        self.state = bk.make_state(capacity)
+        self.state = bk.make_state(CAPACITY)
+        if lead:
+            self.state = jax.tree.map(lambda x: x[None], self.state)
         self.slots: dict[bytes, int] = {}
 
     def _slot(self, key: bytes) -> int:
@@ -46,8 +55,6 @@ class PallasShadow:
         """rows: [(key, algo, behavior, hits, limit, duration, burst)]
         with unique keys (callers split duplicate keys into rounds).
         Returns [(status, limit, remaining, reset)] in row order."""
-        import jax.numpy as jnp
-
         m = len(rows)
         slot = np.asarray([self._slot(r[0]) for r in rows], np.int32)
         order = np.argsort(slot, kind="stable")
@@ -61,10 +68,10 @@ class PallasShadow:
             np.zeros(m, np.int64),
             np.zeros(m, np.int64),
         )
-        self.state, pout = pallas_fused_step(
-            self.state, jnp.asarray(buf), interpret=True
-        )
-        st, rem, rst = bk.unpack_out_host(np.asarray(pout), m)
+        pin = jnp.asarray(buf[None] if self.lead else buf)
+        self.state, pout = self.step(self.state, pin)
+        pout = np.asarray(pout[0] if self.lead else pout)
+        st, rem, rst = bk.unpack_out_host(pout, m)
         inv = np.empty(m, np.int64)
         inv[order] = np.arange(m)
         limits = cols[3]
@@ -126,12 +133,28 @@ def _rand_rows(rng, keys, n):
     return uniq
 
 
-def test_pallas_interpret_bit_equal_to_spec_fuzz():
+@pytest.fixture(scope="module")
+def mesh_packed_fused():
+    """The mesh tier's packed step (`local_packed_fused` under
+    shard_map) as a one-device mesh builds it."""
+    engine = ShardedDecisionEngine(
+        shard_capacity=CAPACITY, mesh=make_mesh(jax.devices()[:1])
+    )
+    return engine._packed_fused
+
+
+@pytest.fixture(params=["fused_step", "mesh_packed_fused"])
+def shadow(request):
+    if request.param == "fused_step":
+        return StepShadow(bk.fused_step, lead=False)
+    return StepShadow(request.getfixturevalue(request.param), lead=True)
+
+
+def test_step_bit_equal_to_spec_fuzz(shadow):
     """Token + leaky fuzz across advancing time: every response field
-    of the Pallas kernel equals the scalar spec, including expiry
+    of the step program equals the scalar spec, including expiry
     boundaries crossed by the clock advances."""
     rng = np.random.default_rng(11)
-    shadow = PallasShadow()
     oracle = SpecShadow()
     keys = [b"fz_%d" % i for i in range(24)]
     now = 1_000_000
@@ -143,12 +166,11 @@ def test_pallas_interpret_bit_equal_to_spec_fuzz():
         assert got == want, f"step {step} now={now}: {rows}"
 
 
-def test_pallas_duration_change_renewal_boundary():
+def test_step_duration_change_renewal_boundary(shadow):
     """The duration-change renewal quirk (stored remaining becomes
     limit, response reports the pre-renewal snapshot — spec docstring)
-    must hold bit-for-bit through the Pallas kernel, on both sides of
+    must hold bit-for-bit through the step program, on both sides of
     the `new_expire <= now` boundary."""
-    shadow = PallasShadow()
     oracle = SpecShadow()
     now = 50_000
     key = b"renew"
@@ -166,10 +188,9 @@ def test_pallas_duration_change_renewal_boundary():
         )
 
 
-def test_pallas_expiry_boundary_exact():
+def test_step_expiry_boundary_exact(shadow):
     """`expire_at < now` is a strict miss; equality still serves the
     item (lrucache.go semantics) — pinned at the exact millisecond."""
-    shadow = PallasShadow()
     oracle = SpecShadow()
     key = b"edge"
     base = 10_000
@@ -181,11 +202,10 @@ def test_pallas_expiry_boundary_exact():
         assert shadow.apply(rows, now) == oracle.apply(rows, now), now
 
 
-def test_pallas_leaky_fractional_leak_parity():
+def test_step_leaky_fractional_leak_parity(shadow):
     """Leaky buckets accrue fractional leak by leaving t0 untouched
     (the TestLeakyBucketDivBug quirk) — the 32.32 fixed-point path
-    through the kernel must track the spec's quantization exactly."""
-    shadow = PallasShadow()
+    through the step must track the spec's quantization exactly."""
     oracle = SpecShadow()
     key = b"leak"
     now = 77_000
@@ -251,18 +271,15 @@ def _ledger_harness(clock):
 
 
 @pytest.mark.parametrize("seed", [3, 19])
-def test_pallas_vs_spec_vs_ledger_three_way(seed, monkeypatch):
-    """The three-tier pin the ISSUE asks for: the Pallas kernel
-    (interpret, forced via GUBER_FUSED for the ENGINE the ledger
-    fronts), the host ledger's answers through that engine, and the
-    scalar spec all agree row for row — token AND leaky, across
-    duration changes and expiries."""
-    monkeypatch.setenv("GUBER_FUSED", "interpret")
+def test_engine_vs_spec_vs_ledger_three_way(seed, monkeypatch):
+    """The three-tier pin: the engine's step, the host ledger's
+    answers through that engine, and the scalar spec all agree row
+    for row — token AND leaky, across duration changes and
+    expiries."""
     monkeypatch.setenv("GUBER_PUMP", "0")
     rng = np.random.default_rng(seed)
     clock = Clock().freeze()
     engine, ledger, serve = _ledger_harness(clock)
-    assert engine.fused_mode == "pallas-interpret"
     oracle = SpecShadow()
     keys = [b"led_%d" % i for i in range(10)]
     try:
@@ -288,7 +305,7 @@ def test_pallas_vs_spec_vs_ledger_three_way(seed, monkeypatch):
                 got = (int(st[i]), int(rem[i]), int(rst[i]))
                 assert got == (es, er, et), (
                     f"seed {seed} step {step} row {i} {rows[i]}: "
-                    f"ledger+pallas={got} spec={(es, er, et)}"
+                    f"ledger+engine={got} spec={(es, er, et)}"
                 )
     finally:
         ledger.close()
@@ -298,11 +315,10 @@ def test_pallas_vs_spec_vs_ledger_three_way(seed, monkeypatch):
 def test_paged_vs_spec_vs_ledger_three_way(seed, monkeypatch):
     """The three-way harness with the PAGED plane underneath
     (GUBER_PAGED, core/paging.py): ledger-fronted answers through a
-    paged Pallas-interpret engine squeezed to 64 resident rows under a
+    paged engine squeezed to 64 resident rows under a
     2048-slot key space still match the scalar spec row for row —
     eviction→spill→refill roundtrips land mid-fuzz (asserted via the
     fault counters), so residency is exercised, not incidental."""
-    monkeypatch.setenv("GUBER_FUSED", "interpret")
     monkeypatch.setenv("GUBER_PUMP", "0")
     monkeypatch.setenv("GUBER_PAGED", "1")
     monkeypatch.setenv("GUBER_PAGE_SIZE", "16")
@@ -347,12 +363,10 @@ def test_paged_vs_spec_vs_ledger_three_way(seed, monkeypatch):
 
 def test_fused_steady_state_is_single_dispatch(monkeypatch):
     """ISSUE 10 acceptance: in steady state one batch = ONE device
-    dispatch (unique keys, no evictions, fused step), and the split
-    control dispatches more — the A/B the devfused bench measures."""
+    dispatch (unique keys, no evictions)."""
     monkeypatch.setenv("GUBER_PUMP", "0")
     clock = Clock().freeze()
     engine = DecisionEngine(capacity=4096, clock=clock)
-    assert engine.fused_mode in ("xla", "pallas", "pallas-interpret")
 
     def batch(engine, start, n=100):
         return engine.apply_columnar(
@@ -370,36 +384,24 @@ def test_fused_steady_state_is_single_dispatch(monkeypatch):
     batch(engine, 200)  # new keys, capacity ample: still one dispatch
     assert engine.dispatches_total - before == 1
 
-    monkeypatch.setenv("GUBER_FUSED", "split")
-    unfused = DecisionEngine(capacity=4096, clock=clock)
-    assert unfused.fused_mode == "split"
-    batch(unfused, 0)
-    before = unfused.dispatches_total
-    batch(unfused, 0)
-    assert unfused.dispatches_total - before >= 2
 
+def test_engine_serves_wire_shapes_equal_to_spec(monkeypatch):
+    """The ordinary columnar and dataclass paths at 150 lanes (two pad
+    widths past the 64 floor, token and leaky mixed: the packed
+    program, not the uniform one) answer as the scalar spec does
+    (integration: packers, rounds, readback all route through the
+    step)."""
+    from gubernator_tpu.types import RateLimitReq
 
-def test_guber_fused_knob_rejects_unknown(monkeypatch):
-    monkeypatch.setenv("GUBER_FUSED", "warp")
-    with pytest.raises(ValueError, match="GUBER_FUSED"):
-        DecisionEngine(capacity=256, clock=Clock().freeze())
-
-
-def test_pallas_interpret_engine_serves_wire_shapes(monkeypatch):
-    """An engine forced onto the Pallas step serves the ordinary
-    columnar + dataclass paths with responses equal to a default
-    engine (integration: packers, rounds, readback all route through
-    the kernel)."""
     monkeypatch.setenv("GUBER_PUMP", "0")
     clock = Clock().freeze()
-    monkeypatch.setenv("GUBER_FUSED", "interpret")
-    a = DecisionEngine(capacity=1024, clock=clock)
-    monkeypatch.setenv("GUBER_FUSED", "xla")
-    b = DecisionEngine(capacity=1024, clock=clock)
-    assert a.fused_mode == "pallas-interpret"
-    n = 150  # spans two pad widths vs the 64 floor
+    columnar = DecisionEngine(capacity=1024, clock=clock)
+    dataclass = DecisionEngine(capacity=1024, clock=clock)
+    oracle = SpecShadow()
+    n = 150
+    algo = [i % 2 for i in range(n)]
     cols = dict(
-        algo=np.asarray([i % 2 for i in range(n)], np.int32),
+        algo=np.asarray(algo, np.int32),
         behavior=np.zeros(n, np.int32),
         hits=np.ones(n, np.int64),
         limit=np.full(n, 7, np.int64),
@@ -408,9 +410,29 @@ def test_pallas_interpret_engine_serves_wire_shapes(monkeypatch):
     )
     for step in range(4):
         clock.advance(ms=700)
-        keys = [b"w_%d" % (i % 90) for i in range(n)]
-        keys = [k + b"!%d" % i for i, k in enumerate(keys)]
-        ra = a.apply_columnar(keys, **cols)
-        rb = b.apply_columnar(keys, **cols)
-        for x, y in zip(ra, rb):
-            np.testing.assert_array_equal(x, y)
+        now = clock.now_ms()
+        keys = [b"w_%d!%d" % (i % 90, i) for i in range(n)]
+        want = oracle.apply(
+            [(k, algo[i], 0, 1, 7, 2_000, 0) for i, k in enumerate(keys)],
+            now,
+        )
+        st, lim, rem, rst = columnar.apply_columnar(keys, now_ms=now, **cols)
+        got = list(zip(st.tolist(), lim.tolist(), rem.tolist(), rst.tolist()))
+        assert got == want, f"columnar, step {step}"
+        resps = dataclass.get_rate_limits(
+            [
+                RateLimitReq(
+                    name="w", unique_key=k.decode(), hits=1, limit=7,
+                    duration=2_000, algorithm=algo[i],
+                )
+                for i, k in enumerate(keys)
+            ],
+            now_ms=now,
+        )
+        # The dataclass path keys by name + "_" + unique_key: other
+        # buckets than the columnar engine's, the same sequence.
+        got = [
+            (int(r.status), r.limit, r.remaining, r.reset_time)
+            for r in resps
+        ]
+        assert got == want, f"dataclass, step {step}"

@@ -29,16 +29,14 @@ from gubernator_tpu.models.spec import SlotState, SpecInput, apply_spec
 from gubernator_tpu.types import RateLimitReq, Status
 
 
-def _paged_env(monkeypatch, page_size=16, resident=4, fused="interpret"):
-    monkeypatch.setenv("GUBER_FUSED", fused)
+def _paged_env(monkeypatch, page_size=16, resident=4):
     monkeypatch.setenv("GUBER_PUMP", "0")
     monkeypatch.setenv("GUBER_PAGED", "1")
     monkeypatch.setenv("GUBER_PAGE_SIZE", str(page_size))
     monkeypatch.setenv("GUBER_PAGED_RESIDENT", str(resident))
 
 
-def _dense_env(monkeypatch, fused="interpret"):
-    monkeypatch.setenv("GUBER_FUSED", fused)
+def _dense_env(monkeypatch):
     monkeypatch.setenv("GUBER_PUMP", "0")
     monkeypatch.delenv("GUBER_PAGED", raising=False)
 

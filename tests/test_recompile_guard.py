@@ -122,15 +122,14 @@ def _algo_columns(n, algo, start=0, name="fz"):
 def test_fused_step_soak_zero_recompiles_both_algorithms(
     frozen_clock, jit_recompile_guard
 ):
-    """ISSUE 10 satellite: the FUSED decision step (default mode,
-    single dispatch per batch) stays recompile-flat across every wire
+    """ISSUE 10 satellite: the decision step (single dispatch per
+    batch) stays recompile-flat across every wire
     width and BOTH algorithms — token and leaky exercise different
     jnp.where arms of the same compiled program, so a flat count here
     pins that the algorithm mix cannot fork the compile cache."""
     engine = DecisionEngine(
         capacity=8192, clock=frozen_clock, max_kernel_width=1024
     )
-    assert engine.fused_mode in ("xla", "pallas", "pallas-interpret")
     engine.warmup(max_width=1024)
 
     jit_recompile_guard.snapshot()
@@ -143,29 +142,6 @@ def test_fused_step_soak_zero_recompiles_both_algorithms(
                     )
                 )
     jit_recompile_guard.assert_flat("fused-step width x algorithm soak")
-
-
-def test_pallas_interpret_soak_zero_recompiles(
-    frozen_clock, jit_recompile_guard, monkeypatch
-):
-    """The Pallas step family (interpret mode — what CPU CI runs) is
-    warmed by the same pad ladder as every other program: steady-state
-    traffic through it must not compile."""
-    monkeypatch.setenv("GUBER_FUSED", "interpret")
-    monkeypatch.setenv("GUBER_PUMP", "0")
-    engine = DecisionEngine(
-        capacity=4096, clock=frozen_clock, max_kernel_width=512
-    )
-    assert engine.fused_mode == "pallas-interpret"
-    engine.warmup(max_width=512)
-
-    jit_recompile_guard.snapshot()
-    for width in (1, 63, 64, 200, 512):
-        for algo in (0, 1):
-            engine.apply_columnar(
-                **_algo_columns(width, algo, start=width * 11, name="pz")
-            )
-    jit_recompile_guard.assert_flat("pallas interpret-mode soak")
 
 
 def test_sharded_psum_merge_soak_zero_recompiles(

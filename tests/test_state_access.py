@@ -101,7 +101,7 @@ def test_gather_slots_reads_the_words_numpy_reads(name):
     cap, live, width = SEAM_CASES[name]
     state = random_state(cap)
     slot = padded(live, width, cap)
-    got = jax.jit(bk.gather_slots)(state, state.meta, jnp.asarray(slot))
+    got = jax.jit(bk.gather_slots)(state, jnp.asarray(slot))
     inside = slot < cap
     for col, words in zip(state, got):
         want = np.where(inside, np.asarray(col)[np.where(inside, slot, 0)], 0)
@@ -212,15 +212,15 @@ def collapsed_pin(rng, width, cap, n, now=NOW):
     return bk.pack_collapsed_host(width, now, cap, s, counts, fields, seg, pos)
 
 
-def split_pair(core):
-    """The split form: a read-only compute program, then the scatter
-    program."""
+def flat(core):
+    """The mesh tier's flat form (parallel/sharded_engine.py
+    `flat_*_fused`): the [n_shards, cap] state flattened inside the
+    program, the slots global."""
 
     def run(state, pin):
-        slot, vals, out = jax.jit(fresh(core))(state, pin)
-        return jax.jit(fresh(bk._scatter_values))(state, slot, vals), out
+        st, out = core(jax.tree.map(lambda x: x.reshape(-1), state), pin)
+        return jax.tree.map(lambda x: x.reshape(2, -1), st), out
 
-    run.programs = 2
     return run
 
 
@@ -235,9 +235,12 @@ STEP_FORMS = {
     "collapsed_step": (bk._collapsed_step_core, collapsed_pin, ()),
     "multi_fused_step": (scanned(bk._fused_step_core), packed_pin, (3,)),
     "multi_uniform_step": (scanned(bk._uniform_step_core), uniform_pin, (3,)),
-    "split_packed": (split_pair(bk._packed_compute_core), packed_pin, ()),
-    "split_collapsed": (split_pair(bk._collapsed_values), collapsed_pin, ()),
+    "flat_fused_step": (flat(bk._fused_step_core), packed_pin, ()),
+    "flat_collapsed_step": (flat(bk._collapsed_step_core), collapsed_pin, ()),
     "vmapped_fused_step": (jax.vmap(bk._fused_step_core), packed_pin, (2,)),
+    "vmapped_collapsed_step": (
+        jax.vmap(bk._collapsed_step_core), collapsed_pin, (2,)
+    ),
 }
 
 
@@ -254,12 +257,12 @@ def test_step_form_with_the_loop_equals_the_same_with_the_pass(
     state = random_state(cap, seed=7)
     if name.startswith("vmapped"):
         state = jax.tree.map(lambda x: jnp.stack([x, x[::-1]]), state)
+    if name.startswith("flat"):
+        state = jax.tree.map(lambda x: x.reshape(2, -1), state)
 
     def run(form):
         monkeypatch.setattr(bk, "_SCATTER_PASS_ROWS_PER_LANE", FORMS[form])
         assert scatter_forms(jax.jit(fresh(step)), state, pin) == {form}
-        if getattr(step, "programs", 1) == 2:
-            return step(state, pin)
         return jax.jit(fresh(step))(state, pin)
 
     loop_state, loop_out = run("loop")

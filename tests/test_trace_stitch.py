@@ -453,14 +453,14 @@ def test_debug_endpoints_serve_live_data(tracer, monkeypatch):
         assert "device.step" in vars_["stage_budget"]
         assert vars_["stage_budget"]["device.step"]["count"] >= 1
         assert "device.readback" in vars_["stage_budget"]
-        # What the node serves on (ISSUE 21): platform, engine, step
-        # form, pump/scan state and every compile probe's verdict with
+        # What the node serves on (ISSUE 21): platform, engine,
+        # pump/scan state and every compile probe's verdict with
         # its reason.  conftest asked for the CPU by name.
         dev = vars_["device"]
         assert dev["platform"] == "cpu" and dev["device_count"] == 1
         assert dev["cpu_unrequested"] is False
         assert dev["engine"] == "DecisionEngine" and dev["rows"] == 1024
-        assert dev["fused_mode"] == "xla" and dev["pump"] is True
+        assert dev["pump"] is True
         assert dev["pump_scan"] is False  # singles on the CPU backend
         assert set(dev["probes"]) == {"fused_step", "multi_step"}
         assert all(v["ok"] and v["reason"] for v in dev["probes"].values())

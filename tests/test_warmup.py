@@ -2,7 +2,7 @@
 
 VERDICT r1 weak item 4 / next-round item 7: a daemon that warms up but
 then pays an XLA compile on a served batch blows the peer-batch timeout
-(an uncompiled apply_batch_sorted cost 1.1s on the wire path).  These
+(an uncompiled step cost 1.1s on the wire path).  These
 tests pin "zero compile-cache misses while serving" for both engines by
 snapshotting the jit caches of every kernel after warmup and asserting
 they do not grow while serving widths up to the warmed max.
@@ -16,16 +16,13 @@ from gubernator_tpu.core.engine import DecisionEngine
 from gubernator_tpu.ops import bucket_kernel as bk
 from gubernator_tpu.types import Algorithm, RateLimitReq
 
-# The serving programs: dataclass path (apply_batch), packed columnar
-# path (fused_step when in-place donation compiles, else
-# packed_compute + scatter_store), eviction clears.
+# The serving programs: the packed step (dataclass and columnar path),
+# the uniform and the collapsed step, eviction clears.  (The scanned
+# forms are the pump's: tests/test_recompile_guard.py.)
 _KERNELS = (
-    bk.apply_batch,
     bk.fused_step,
-    bk.packed_compute,
+    bk.uniform_step,
     bk.collapsed_step,
-    bk.collapsed_compute,
-    bk.scatter_store,
     bk.clear_occupied,
 )
 
@@ -89,10 +86,7 @@ def test_sharded_warmup_covers_serving_widths(frozen_clock):
         f._cache_size()
         for f in (
             engine._packed_fused,
-            engine._packed_compute,
             engine._collapsed_fused,
-            engine._collapsed_compute,
-            engine._step_scatter,
             engine._clear_step,
         )
     )
@@ -115,10 +109,7 @@ def test_sharded_warmup_covers_serving_widths(frozen_clock):
         f._cache_size()
         for f in (
             engine._packed_fused,
-            engine._packed_compute,
             engine._collapsed_fused,
-            engine._collapsed_compute,
-            engine._step_scatter,
             engine._clear_step,
         )
     )

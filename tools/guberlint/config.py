@@ -146,7 +146,7 @@ KNOB_DOC_FILE = "README.md"
 METRIC_REGISTRY = "gubernator_tpu/utils/metrics.py"
 METRIC_DOC_FILES = (
     "README.md", "PERF.md", "RESILIENCE.md", "STATIC_ANALYSIS.md",
-    "OBSERVABILITY.md", "scripts/bench_trend.py",
+    "OBSERVABILITY.md",
 )
 
 # The SLI declaration file (obs/slo.py): the drift `slo` sub-rule
