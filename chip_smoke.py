@@ -92,7 +92,8 @@ SHAPE_SITES = {
     "core/readback.py": ("stack_outputs",),
     "parallel/sharded_engine.py": (
         "local_packed_fused", "local_collapsed_fused", "local_clear",
-        "local_merge", "flat_packed_fused", "flat_collapsed_fused",
+        "local_merge", "sharded_sweep_scan", "flat_packed_fused",
+        "flat_collapsed_fused",
     ),
 }
 

@@ -327,9 +327,10 @@ def build_restore_record(
     return rec
 
 
-def require_in_place(verdict):
-    """`fused_step_ok`'s verdict for `probes`, or on an accelerator the
-    refusal to start on its no: a donated step that XLA compiled with
+def require_in_place(verdict, name: str = "fused_step"):
+    """`fused_step_ok`'s verdict (on the mesh `mesh_step`'s, named by
+    `name`) for `probes`, or on an accelerator the refusal to start on
+    its no: a donated step that XLA compiled with
     a state-sized temp would copy the table every dispatch (4.8 GB at
     100 M rows), and there is no second step program to serve instead.
     Both engines construct through here.
@@ -345,14 +346,14 @@ def require_in_place(verdict):
         return verdict
     if jax.default_backend() != "cpu":
         raise RuntimeError(
-            f"fused_step probe said no ({verdict.reason}): the donated "
+            f"{name} probe said no ({verdict.reason}): the donated "
             "bucket step does not compile in place on this backend, "
             "so the engine does not start"
         )
     log.warning(
-        "fused_step probe said no (%s): XLA:CPU copies part of the "
+        "%s probe said no (%s): XLA:CPU copies part of the "
         "state every dispatch; serving the same step program",
-        verdict.reason,
+        name, verdict.reason,
     )
     return verdict
 

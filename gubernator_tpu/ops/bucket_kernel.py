@@ -1130,25 +1130,21 @@ def first_line(e: BaseException) -> str:
     return f"{type(e).__name__}: {lines[0] if lines else ''}"[:400]
 
 
-def _in_place_probe(step, capacity: int, in_shape: tuple) -> ProbeVerdict:
-    """Compile donated `step` at this capacity and read XLA's memory
-    analysis: temp allocations a fraction of the state size mean the
-    donation aliased the buffers and no O(capacity) copy was inserted
-    (copy-insertion cloning the state shows as temp ≈ state size)."""
-    state_sds = jax.eval_shape(lambda: make_state(capacity))
-    in_sds = jax.ShapeDtypeStruct(in_shape, jnp.int32)
-    try:
-        compiled = step.lower(state_sds, in_sds).compile()
-    except Exception as e:  # noqa: BLE001 — the refusal is the verdict
-        return ProbeVerdict(False, first_line(e))
-    ma = compiled.memory_analysis()
+def compiled_temp_bytes(step, *shapes) -> int:
+    """Compile jitted `step` for these shapes (with their shardings,
+    where they carry any) and return XLA's temp allocation on a
+    device; raises the compiler's refusal."""
+    ma = step.lower(*shapes).compile().memory_analysis()
     if ma is None:
-        return ProbeVerdict(False, "backend reports no memory analysis")
-    state_bytes = sum(
-        int(np.prod(l.shape)) * l.dtype.itemsize
-        for l in jax.tree.leaves(state_sds)
-    )
-    temp = int(ma.temp_size_in_bytes)
+        raise RuntimeError("backend reports no memory analysis")
+    return int(ma.temp_size_in_bytes)
+
+
+def in_place_verdict(temp: int, state_bytes: int) -> ProbeVerdict:
+    """A donated step's temp against the state a device holds: temp
+    allocations a fraction of the state size mean the donation aliased
+    the buffers and no O(capacity) copy was inserted (copy-insertion
+    cloning the state shows as temp ≈ state size)."""
     bound = max(state_bytes // 4, 1 << 20)
     ok = temp < bound
     return ProbeVerdict(
@@ -1156,6 +1152,22 @@ def _in_place_probe(step, capacity: int, in_shape: tuple) -> ProbeVerdict:
         f"temp {temp} B {'<' if ok else '>='} bound {bound} B "
         f"(state {state_bytes} B)",
     )
+
+
+def _in_place_probe(step, capacity: int, in_shape: tuple) -> ProbeVerdict:
+    """Compile donated `step` at this capacity and read XLA's memory
+    analysis (`in_place_verdict`)."""
+    state_sds = jax.eval_shape(lambda: make_state(capacity))
+    in_sds = jax.ShapeDtypeStruct(in_shape, jnp.int32)
+    try:
+        temp = compiled_temp_bytes(step, state_sds, in_sds)
+    except Exception as e:  # noqa: BLE001 — the refusal is the verdict
+        return ProbeVerdict(False, first_line(e))
+    state_bytes = sum(
+        int(np.prod(l.shape)) * l.dtype.itemsize
+        for l in jax.tree.leaves(state_sds)
+    )
+    return in_place_verdict(temp, state_bytes)
 
 
 @functools.lru_cache(maxsize=None)

@@ -86,7 +86,10 @@ def sweep_window_commit(
     )
 
 
-def windowed_sweep(engine, cap: int, now_ms: int, max_windows, release) -> int:
+def windowed_sweep(
+    engine, cap: int, now_ms: int, max_windows, release,
+    scan=sweep_window_scan, commit=sweep_window_commit,
+) -> int:
     """Drive scan/commit windows over an engine's state.
 
     Shared by DecisionEngine.sweep and ShardedDecisionEngine.sweep (the
@@ -94,6 +97,9 @@ def windowed_sweep(engine, cap: int, now_ms: int, max_windows, release) -> int:
     `engine` supplies `_state`, `_sweep_cursor`, `SWEEP_WINDOW`; the
     caller holds the engine lock.  `release(order, count, start) -> n`
     frees the compacted slots in the host table(s) and returns how many.
+    `scan` / `commit` default to the two programs above; the mesh
+    engine passes its own, which run them per shard on its flat
+    columns.
     """
     window = min(cap, engine.SWEEP_WINDOW)
     n_windows = (cap + window - 1) // window
@@ -107,7 +113,7 @@ def windowed_sweep(engine, cap: int, now_ms: int, max_windows, release) -> int:
         # earlier in this pass are no longer occupied).
         start = min(engine._sweep_cursor, cap - window)
         start_dev = jnp.asarray(start, dtype=jnp.int32)
-        meta_w, order, count = sweep_window_scan(
+        meta_w, order, count = scan(
             engine._state.meta,
             engine._state.hi2,
             engine._state.expire_lo,
@@ -117,7 +123,7 @@ def windowed_sweep(engine, cap: int, now_ms: int, max_windows, release) -> int:
             window=window,
         )
         engine._state = engine._state._replace(
-            meta=sweep_window_commit(engine._state.meta, meta_w, start_dev)
+            meta=commit(engine._state.meta, meta_w, start_dev)
         )
         freed_total += release(order, count, start)
         engine._sweep_cursor += window

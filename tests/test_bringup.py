@@ -108,16 +108,41 @@ def test_probes_report_the_compilers_reason(monkeypatch):
 
 
 def _build_engine(which):
+    """The engine's module, its constructor, the name of its in-place
+    probe in `probes`, and how to make that probe say no."""
+    from gubernator_tpu.ops.bucket_kernel import ProbeVerdict
+
+    no = ProbeVerdict(False, "clones state")
     if which == "DecisionEngine":
         from gubernator_tpu.core import engine as mod
 
-        return mod, lambda: mod.DecisionEngine(capacity=64)
+        return (
+            lambda: mod.DecisionEngine(capacity=64),
+            "fused_step",
+            lambda mp: mp.setattr(mod, "fused_step_ok", lambda cap: no),
+        )
     from gubernator_tpu.parallel import sharded_engine as mod
 
-    return mod, lambda: mod.ShardedDecisionEngine(shard_capacity=64)
+    single = which.endswith("single_program")
+    return (
+        lambda: mod.ShardedDecisionEngine(
+            shard_capacity=64, single_program=single
+        ),
+        "mesh_step",
+        lambda mp: mp.setattr(
+            mod.ShardedDecisionEngine, "_mesh_step_ok", lambda self: no
+        ),
+    )
 
 
-@pytest.mark.parametrize("which", ["DecisionEngine", "ShardedDecisionEngine"])
+@pytest.mark.parametrize(
+    "which",
+    [
+        "DecisionEngine",
+        "ShardedDecisionEngine",
+        "ShardedDecisionEngine.single_program",
+    ],
+)
 def test_in_place_probes_no_refuses_the_start_with_its_reason(
     which, monkeypatch
 ):
@@ -126,17 +151,13 @@ def test_in_place_probes_no_refuses_the_start_with_its_reason(
     second program — it raises with the probe's reason."""
     import jax
 
-    from gubernator_tpu.ops.bucket_kernel import ProbeVerdict
-
-    mod, build = _build_engine(which)
-    yes = build().probes["fused_step"]
+    build, name, say_no = _build_engine(which)
+    yes = build().probes[name]
     assert yes.ok and "temp" in yes.reason
-    monkeypatch.setattr(
-        mod, "fused_step_ok", lambda cap: ProbeVerdict(False, "clones state")
-    )
+    say_no(monkeypatch)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(
-        RuntimeError, match=r"fused_step probe said no \(clones state\)"
+        RuntimeError, match=rf"{name} probe said no \(clones state\)"
     ):
         build()
 

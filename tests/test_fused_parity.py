@@ -31,7 +31,8 @@ class StepShadow:
     """Drives a served step program directly: key → slot interning on
     the host, packed rounds through `step(state, pin)` — the exact
     serving layout, minus the engine plumbing.  `lead` is the mesh
-    programs' leading shard axis (state [1, cap], pin[None])."""
+    programs' leading shard axis on what the host packs (pin[None],
+    pout[0]); the state is the flat column either way."""
 
     def __init__(self, step, lead: bool, width: int = 64):
         self.step = step
@@ -39,8 +40,6 @@ class StepShadow:
         self.capacity = CAPACITY
         self.width = width
         self.state = bk.make_state(CAPACITY)
-        if lead:
-            self.state = jax.tree.map(lambda x: x[None], self.state)
         self.slots: dict[bytes, int] = {}
 
     def _slot(self, key: bytes) -> int:

@@ -331,16 +331,21 @@ def test_lowered_step_scatters_into_the_table_in_the_form_the_rule_names(
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:  # noqa: BLE001 — no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -363,3 +368,181 @@ def test_on_a_v5e_the_step_updates_100m_rows_in_place_without_the_pass(
         assert "unique_indices=true" not in line
     # in place: a cloned column would be 400 MB of temp
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# -- the mesh engine's programs on the four chips of a described v5e-4 -------
+#
+# A [n_shards, cap] state gives a chip [1, cap], which the chip tiles
+# T(1,128) where [cap] is T(1024): squeezing it for the one-chip body
+# and expanding the result copied all twelve columns twice a step
+# (twelve `reduce`, twelve `while`, a temp the size of the state; 20.9
+# ms a dispatch at 25 M rows a shard — PERF.md §6, PR 31).  The state
+# is flat columns on the keys axis so that there is nothing to copy.
+
+SHARD_ROWS = 25_000_000
+REC_DTYPES = dict(
+    slot=jnp.int32, algo=jnp.int32, status=jnp.int32, limit=jnp.int64,
+    remaining=jnp.int64, remf_hi=jnp.int32, remf_lo=jnp.uint32,
+    duration=jnp.int64, t0=jnp.int64, expire_at=jnp.int64,
+    burst=jnp.int64, invalid_at=jnp.int64,
+)
+
+
+@pytest.fixture(scope="module")
+def mesh_engine(topo):
+    """`ShardedDecisionEngine` as its constructor leaves it, less the
+    state itself: nothing can be placed on a described device, so the
+    programs are built and lowered for shapes."""
+    from gubernator_tpu.parallel.mesh import keys_sharding, make_mesh
+    from gubernator_tpu.parallel.sharded_engine import ShardedDecisionEngine
+
+    engine = object.__new__(ShardedDecisionEngine)
+    engine.mesh = make_mesh(topo.devices)
+    engine.n_shards = len(topo.devices)
+    engine._single_program = False
+    engine.shard_capacity = SHARD_ROWS
+    engine.capacity = SHARD_ROWS * engine.n_shards
+    engine.max_kernel_width = 8192
+    engine._placement = keys_sharding(engine.mesh)
+    engine._build_step()
+    return engine
+
+
+def mesh_program_args(engine, name, width):
+    """Static arguments and shapes, placed as the engine places
+    them, for one of its programs."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    n_sh = engine.n_shards
+    keys = engine._placement
+    everywhere = NamedSharding(engine.mesh, PartitionSpec())
+
+    def sds(shape, dtype=jnp.int32, sharding=keys):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    state = engine._state_shapes()
+    window = min(engine.shard_capacity, engine.SWEEP_WINDOW)
+    start = sds((), jnp.int32, everywhere)
+    static = {"window": window} if name == "_sweep_scan" else {}
+    return static, {
+        "_collapsed_fused": (state, sds((n_sh, bk.COLLAPSED_IN_ROWS, width))),
+        "_packed_fused": (state, sds((n_sh, bk.PACKED_IN_ROWS, width))),
+        "_load_step": (
+            state,
+            bk.SlotRecord(
+                **{f: sds((n_sh, width), dt) for f, dt in REC_DTYPES.items()}
+            ),
+        ),
+        "_clear_step": (state.meta, sds((n_sh, width))),
+        "_sweep_scan": (
+            state.meta, state.hi2, state.expire_lo,
+            sds((), jnp.int32, everywhere), sds((), jnp.uint32, everywhere),
+            start,
+        ),
+        "_sweep_commit": (state.meta, sds((n_sh * window,)), start),
+    }[name]
+
+
+# program -> (lanes, scatters into a shard's 25 M-row column)
+MESH_PROGRAMS = {
+    "_collapsed_fused": (256, 12),
+    "_packed_fused": (256, 12),
+    "_load_step": (64, 12),
+    "_clear_step": (64, 1),
+    "_sweep_scan": (0, 0),
+    "_sweep_commit": (0, 0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MESH_PROGRAMS))
+def test_on_a_v5e_4_the_mesh_program_touches_a_shards_columns_in_place(
+    name, mesh_engine
+):
+    width, n_scatters = MESH_PROGRAMS[name]
+    static, shapes = mesh_program_args(mesh_engine, name, width)
+    compiled = getattr(mesh_engine, name).lower(*shapes, **static).compile()
+    # per device: a cloned column would be 100 MB, the state 1.2 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    lines = compiled.as_text().splitlines()
+    column = f"[{SHARD_ROWS}]"
+    # a chip's view of a column is the column: no [1, cap] block, so
+    # no `reduce` / `copy` out of one and no `while` copying back
+    assert not [l for l in lines if f"[1,{SHARD_ROWS}]" in l]
+    assert not [l for l in lines if " while(" in l and column in l]
+    assert not [l for l in lines if " reduce(" in l and column in l]
+    scatters = [l for l in lines if " scatter(" in l and column in l]
+    assert len(scatters) == n_scatters
+    for line in scatters:
+        assert "indices_are_sorted=true" not in line
+        assert "unique_indices=true" not in line
+
+
+def test_on_a_v5e_4_the_probe_reads_the_program_that_serves(mesh_engine):
+    """The start-up probe compiles the engine's own two step programs
+    at their shardings, against what one chip holds of the state.  The
+    one-chip program at shard capacity — what the probe used to ask —
+    says yes whatever the mesh program does: with the step the engine
+    served before (state [n_shards, cap], squeezed and expanded around
+    the body) in its place, the probe has to say no."""
+    from gubernator_tpu.parallel.mesh import KEYS_AXIS, shard_map
+
+    state = mesh_engine._state_shapes()
+    shard_bytes = 48 * SHARD_ROWS
+    pins = {
+        "_collapsed_fused": bk.COLLAPSED_IN_ROWS,
+        "_packed_fused": bk.PACKED_IN_ROWS,
+    }
+    temps = [
+        bk.compiled_temp_bytes(
+            getattr(mesh_engine, name),
+            state,
+            jax.ShapeDtypeStruct(
+                (mesh_engine.n_shards, rows, 64), jnp.int32,
+                sharding=mesh_engine._placement,
+            ),
+        )
+        for name, rows in pins.items()
+    ]
+    yes = mesh_engine._mesh_step_ok()
+    assert yes == bk.in_place_verdict(max(temps), shard_bytes)
+    assert yes.ok and f"bound {shard_bytes // 4} B" in yes.reason
+    one_chip_temp = bk.compiled_temp_bytes(
+        bk.fused_step, *step_shapes("fused_step", SHARD_ROWS, 64)
+    )
+    assert one_chip_temp not in temps
+
+    spec = jax.sharding.PartitionSpec(KEYS_AXIS)
+    specs = jax.tree.map(lambda _: spec, bk.make_state(0))
+
+    def before(core):
+        def squeezed_and_expanded(state, pin):
+            new, pout = core(jax.tree.map(lambda x: x[0], state), pin[0])
+            return jax.tree.map(lambda x: x[None], new), pout[None]
+
+        return jax.jit(
+            shard_map(
+                squeezed_and_expanded,
+                mesh=mesh_engine.mesh,
+                in_specs=(specs, spec),
+                out_specs=(specs, spec),
+            ),
+            donate_argnums=(0,),
+        )
+
+    served = dict(vars(mesh_engine))
+    try:
+        mesh_engine._packed_fused = before(bk._fused_step_core)
+        mesh_engine._collapsed_fused = before(bk._collapsed_step_core)
+        mesh_engine._state_shapes = lambda: jax.tree.map(
+            lambda c: jax.ShapeDtypeStruct(
+                (mesh_engine.n_shards, SHARD_ROWS), c.dtype,
+                sharding=c.sharding,
+            ),
+            state,
+        )
+        no = mesh_engine._mesh_step_ok()
+    finally:
+        vars(mesh_engine).clear()
+        vars(mesh_engine).update(served)
+    assert not no.ok and f">= bound {shard_bytes // 4} B" in no.reason
+    assert int(no.reason.split()[1]) > shard_bytes
