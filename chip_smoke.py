@@ -21,7 +21,12 @@ What it does, through the entry points a user would call:
    the daemon's `/debug/vars` `device` block must say platform `tpu`
    and show device dispatches growing with the traffic.
 3. Stops that daemon and starts a second one against the same compile
-   cache: the warm start must compile nothing.
+   cache: the warm start must compile nothing.  The second one also
+   opens the native front (`GUBER_H2_FAST_ADDRESS`): its start line
+   and `/debug/vars` `h2_front` must say what it serves with, and 200
+   single-item RPCs through it must answer as the spec — status, limit
+   and remaining equal, one `reset_time` a bucket, inside the interval
+   of the RPC that made the bucket — with none refused.
 4. With the chip released, runs a `DecisionEngine` on it in a child
    (`--parity-child`) under a frozen clock: a seeded mixed stream
    through `get_rate_limits` and `apply_columnar`, bit-equal to the
@@ -209,7 +214,7 @@ def load_natives() -> dict:
 
 
 class DaemonChild:
-    def __init__(self, tag: str, env: dict, rows: int):
+    def __init__(self, tag: str, env: dict, rows: int, front: bool = False):
         self.tag = tag
         self.grpc_addr = f"127.0.0.1:{free_port()}"
         self.http_addr = f"127.0.0.1:{free_port()}"
@@ -219,6 +224,10 @@ class DaemonChild:
             GUBER_GRPC_ADDRESS=self.grpc_addr,
             GUBER_HTTP_ADDRESS=self.http_addr,
         )
+        self.front_addr = ""
+        if front:  # the native front beside the two listeners
+            self.front_addr = f"127.0.0.1:{free_port()}"
+            self.env["GUBER_H2_FAST_ADDRESS"] = self.front_addr
         self.log_path = os.path.join(OUT_DIR, f"daemon_{tag}.log")
         self.proc = None
         self.t_spawn = 0.0
@@ -619,6 +628,58 @@ def drive_specials(daemon: DaemonChild) -> dict:
 
 
 # ----------------------------------------------------------------------
+# Phase: the native front (GUBER_H2_FAST_ADDRESS)
+
+
+def drive_front(daemon: DaemonChild, n_keys: int = 40, hits_a_key: int = 5) -> dict:
+    """Single-item RPCs through the native front, one caller: every
+    answer as models/spec.py gives it, a bucket's reset_time the same
+    in all its answers and inside the interval of the RPC that made it
+    (token buckets of limit 3, so the last two hits of a key answer
+    OVER_LIMIT).  The start line and /debug/vars must say what serves."""
+    import grpc
+
+    from gubernator_tpu.net.pb import gubernator_pb2 as pb
+
+    check("native h2 front serving: " in daemon.log_tail(1 << 20),
+          "the start log does not say what the front serves with")
+    before = daemon.debug_vars()["h2_front"]
+    settings = before["settings"]
+    say(f"front: {settings}")
+    check(settings["address"] == daemon.front_addr and settings["feeder"]
+          and settings["event_ring"], f"front settings: {settings}")
+    shadow, wrong, resets = SpecShadow(), [], {}
+    with grpc.insecure_channel(daemon.front_addr) as ch:
+        call = rpc_call(ch)
+        for n in range(hits_a_key):
+            for k in range(n_keys):
+                it = item("smoke_front", f"f{k}", hits=1, limit=3)
+                t_send = int(time.time() * 1000)
+                (m,) = call(pb.GetRateLimitsReq(
+                    requests=[pb.RateLimitReq(**it)]), timeout=30.0).responses
+                t_recv = int(time.time() * 1000)
+                want = shadow.apply(it, t_send)
+                lo, hi = resets.setdefault(
+                    k, (t_send + HOUR_MS - 1, t_recv + HOUR_MS + 2))
+                if m.error or (m.status, m.limit, m.remaining) != (
+                        int(want.status), want.limit, want.remaining) or not (
+                        lo <= m.reset_time <= hi):
+                    wrong.append((it["unique_key"], n, str(m).replace("\n", " ")))
+                resets[k] = (m.reset_time, m.reset_time)
+    rpcs = n_keys * hits_a_key
+    check(not wrong, f"front: {len(wrong)} of {rpcs} answers differ from "
+                     f"models/spec.py, first: {wrong[:3]}")
+    after = daemon.debug_vars()["h2_front"]
+    moved = {k: after[k] - before[k] for k in
+             ("rpcs", "errors", "declined_rpcs", "windows", "feeder_rpcs",
+              "plane_rpcs", "ring_dropped")}
+    check(moved["rpcs"] == rpcs and moved["errors"] == 0,
+          f"front counters for {rpcs} RPCs: {moved}")
+    return {"rpcs": rpcs, "over": 2 * n_keys, "settings": settings,
+            "counters": moved}
+
+
+# ----------------------------------------------------------------------
 # Checks on what the daemon says it serves on
 
 
@@ -1000,7 +1061,7 @@ def main(argv=None) -> int:
         cold.kill()
 
     # -- second daemon, same cache: nothing may recompile ----------------
-    warm = DaemonChild("second", env, rows)
+    warm = DaemonChild("second", env, rows, front=True)
     try:
         warm_secs = warm.start(timeout=600)
         devw = warm.debug_vars()["device"]
@@ -1022,6 +1083,9 @@ def main(argv=None) -> int:
                 cache_warm["misses"] == 0 and cache_warm["hits"] > 0,
                 f"warm start recompiled: {cache_warm}",
             )
+        front = drive_front(warm)
+        say(f"native front: {front['rpcs']} single-item RPCs as the spec, "
+            f"counters {front['counters']}")
         warm.stop()
     finally:
         warm.kill()
@@ -1084,6 +1148,7 @@ def main(argv=None) -> int:
                 "persistent_cache": cache_warm,
             },
         },
+        "native_front": front,
         "programs_compiled": shape_checklist(dev1["compiles"]["programs"]),
         "parity_rows": parity["rows_compared"],
         "parity_swept": parity["swept"],

@@ -1608,8 +1608,9 @@ class DecisionEngine:
         """Pre-compile the kernel for every padded batch width up to
         `max_width` (server batches cap at MAX_BATCH_SIZE=1000 → width
         1024) and every eviction-clear width, so no client request pays
-        an XLA compile.  Warmup keys expire after 1ms, a sweep reclaims
-        their slots, and metric counters are restored afterwards."""
+        an XLA compile, and hold `max_kernel_width` to it from then on.
+        Warmup keys expire after 1ms, a sweep reclaims their slots, and
+        metric counters are restored afterwards."""
         # Under the engine lock end-to-end: warmup mutates _state
         # (clear-scatter ladder, pump scans) and restores counters;
         # the RLock keeps the nested get_rate_limits/apply_columnar/
@@ -1730,6 +1731,12 @@ class DecisionEngine:
                 else:
                     (t.hits, t.misses, t.evictions,
                      t.unexpired_evictions) = saved_table
+                # Nothing served is wider than what was just compiled:
+                # a wider batch (the native front's window merges RPCs
+                # up to 8,192 rows) is chunked, chunk k+1 packing while
+                # the device runs chunk k — compiled on demand at 100 M
+                # rows it cost a client its deadline (chip, PR 32).
+                self.max_kernel_width = min(self.max_kernel_width, max_width)
             finally:
                 # Exception-safety: a failed warmup (backend or
                 # compile error) must not leave persistence disabled.

@@ -100,6 +100,8 @@ extern "C" int64_t evr_now_ns();
 constexpr int64_t kEvFeederPack = 4;      // conn thread: decode+pack
 constexpr int64_t kEvFeederRingWait = 5;  // pack → window callback
 constexpr int64_t kEvFeederServe = 6;     // columnar callback wall
+// 10 is the h2 front's per-RPC total (h2_server.cpp).
+constexpr int64_t kEvFeederScatter = 11;  // per window: encode + scatter
 
 namespace {
 
@@ -364,7 +366,12 @@ void serve_window(Feeder* f, int64_t idx) {
     rc = 14;  // sink mode (bench) / teardown: UNAVAILABLE
   }
   f->windows.fetch_add(1);
+  const int64_t t_sc = ring ? evr_now_ns() : 0;
   scatter_window(f, w, sealed, rc);
+  if (ring) {
+    const int64_t t1 = evr_now_ns();
+    evr_record(ring, kEvFeederScatter, t1, t1 - t_sc, n_rpcs);
+  }
   // Recycle: bump the generation, zero the claims, reopen.
   w.committed_rows.store(0);
   const uint64_t next_gen = (cur_gen(sealed) + 1) & kGenMask;

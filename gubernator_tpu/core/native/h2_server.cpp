@@ -106,6 +106,10 @@ constexpr int64_t kEvWindowServe = 3;  // window callback (Python) wall
 constexpr int64_t kEvReactorWake = 7;   // one epoll wake's processing wall
 constexpr int64_t kEvReactorRead = 8;   // one conn's read drain (items=bytes)
 constexpr int64_t kEvReactorWrite = 9;  // one writev flush (items=bytes)
+// Per RPC, whichever path answered it: body deframed (serve_rpc's
+// entry) → response handed to the connection's write path.
+constexpr int64_t kEvRpcTotal = 10;
+// 11 is the columnar feeder's scatter (columnar_feeder.cpp).
 
 namespace {
 
@@ -286,6 +290,10 @@ struct Server {
   std::atomic<void*> feeder{nullptr};
   // Stats.
   std::atomic<int64_t> rpcs{0}, windows{0}, errors{0};
+  // Of `errors`, the RPCs refused UNIMPLEMENTED(12): out of the
+  // columnar path's scope, never answered approximately.
+  std::atomic<int64_t> unimplemented{0};
+  std::atomic<int64_t> window_items{0};  // items of the byte windows
   std::atomic<int64_t> native_rpcs{0}, native_items{0};
   std::atomic<int64_t> feeder_rpcs{0}, feeder_items{0};
   std::atomic<int64_t> conns_open{0}, idle_reaped{0};
@@ -470,6 +478,17 @@ struct Conn : std::enable_shared_from_this<Conn> {
   // case) MOVE into the egress queue instead of deep-copying every
   // response's wire bytes per send.
   bool send_locked(std::string buf) {  // guberlint: holds write_mu
+    return queue_locked(std::move(buf)) &&
+           (epfd < 0 || flush_out_locked());
+  }
+
+  // Accept wire bytes WITHOUT a syscall of their own on the event
+  // plane: the caller flushes once it has queued everything it has (a
+  // response is HEADERS + DATA + trailers — three frames, one writev;
+  // on the chip's host a write costs ~40 us, and a herd window
+  // scatters a hundred responses from one thread, PERF.md §5).  The
+  // threaded plane writes through, as before.
+  bool queue_locked(std::string buf) {  // guberlint: holds write_mu
     if (epfd < 0) return send_blocking_locked(buf);
     if (outq_bytes + buf.size() > kMaxOutBytes) {
       // Backpressure kill: the peer granted window but stopped
@@ -483,7 +502,7 @@ struct Conn : std::enable_shared_from_this<Conn> {
     }
     outq_bytes += buf.size();
     outq.push_back(std::move(buf));
-    return flush_out_locked();
+    return true;
   }
 
   bool send_all(std::string buf) {
@@ -497,12 +516,17 @@ struct Conn : std::enable_shared_from_this<Conn> {
   // are independent; only the connection window is shared).  DATA is
   // chunked to the default max frame size; a response's trailers go
   // out only once its DATA fully drained.
-  void pump_locked() {
-    for (auto it = blocked.begin(); it != blocked.end() && !dead.load();) {
+  void pump_locked() {  // guberlint: holds write_mu
+    bool stop = false;  // the shared window is spent, or the queue refused
+    for (auto it = blocked.begin();
+         !stop && it != blocked.end() && !dead.load();) {
       PendingSend& p = *it;
       bool stream_blocked = false;
       while (p.off < p.data.size()) {
-        if (conn_send_window <= 0) return;  // shared window: stop all
+        if (conn_send_window <= 0) {  // shared window: stop all
+          stop = true;
+          break;
+        }
         const int64_t allow = std::min(conn_send_window, p.stream_window);
         if (allow <= 0) {  // this stream only: try the next one
           stream_blocked = true;
@@ -515,18 +539,24 @@ struct Conn : std::enable_shared_from_this<Conn> {
         frame_header(out, static_cast<uint32_t>(chunk), kData, 0,
                      p.stream);
         out.append(p.data, p.off, chunk);
-        if (!send_locked(std::move(out))) return;
+        if (!queue_locked(std::move(out))) {
+          stop = true;
+          break;
+        }
         conn_send_window -= static_cast<int64_t>(chunk);
         p.stream_window -= static_cast<int64_t>(chunk);
         p.off += chunk;
       }
-      if (stream_blocked) {
+      if (stop || stream_blocked) {
         ++it;
         continue;
       }
-      send_locked(std::move(p.trailers));  // entry erased next
+      queue_locked(std::move(p.trailers));  // entry erased next
       it = blocked.erase(it);
     }
+    // One flush for whatever was queued here (and by the caller before
+    // it: a response's HEADERS).
+    if (epfd >= 0 && !dead.load()) flush_out_locked();
   }
 
   // Full response path: HEADERS immediately (not flow-controlled),
@@ -534,7 +564,7 @@ struct Conn : std::enable_shared_from_this<Conn> {
   bool send_response(uint32_t stream, const std::string& hdr,
                      std::string data, const std::string& trailers) {
     std::lock_guard<std::mutex> lock(write_mu);
-    if (!send_locked(hdr)) return false;
+    if (!queue_locked(hdr)) return false;  // flushed by pump_locked
     PendingSend p;
     p.stream = stream;
     p.data = std::move(data);
@@ -700,7 +730,30 @@ void send_rpc_response(const std::shared_ptr<Conn>& conn, uint32_t stream,
 struct FeederToken {
   std::shared_ptr<Conn> conn;
   Server* srv;
+  int64_t t0_ns;  // serve_rpc's entry (0 = no ring): rpc_total's start
+  int64_t items;
 };
+
+constexpr int kGrpcUnimplemented = 12;
+
+// One response handed to a live connection's write path: the front's
+// RPC counters (successes only into `rpcs`, failures only into
+// `errors`) and the rpc_total event, on every path alike.
+// guberlint: gil-free
+void count_response(Server* srv, int grpc_status, int64_t t0_ns,
+                    int64_t items) {
+  if (grpc_status == 0) {
+    srv->rpcs.fetch_add(1);
+  } else {
+    srv->errors.fetch_add(1);
+    if (grpc_status == kGrpcUnimplemented) srv->unimplemented.fetch_add(1);
+  }
+  void* ring = srv->ring.load();
+  if (ring && t0_ns) {
+    const int64_t t1 = evr_now_ns();
+    evr_record(ring, kEvRpcTotal, t1, t1 - t0_ns, items);
+  }
+}
 
 static const char kPreface[] = "PRI * HTTP/2.0\r\n\r\nSM\r\n\r\n";
 
@@ -752,7 +805,6 @@ void serve_rpc(Server* srv, const std::shared_ptr<Conn>& conn,
       data.append(reinterpret_cast<char*>(len4), 4);
       data += resp;
       send_rpc_payload(conn, stream, std::move(data), 0);
-      srv->rpcs.fetch_add(1);
       srv->native_rpcs.fetch_add(1);
       srv->native_items.fetch_add(items);
       routed = true;
@@ -760,6 +812,7 @@ void serve_rpc(Server* srv, const std::shared_ptr<Conn>& conn,
         const int64_t t1 = evr_now_ns();
         evr_record(ring, kEvNativeServe, t1, t1 - t0, items);
       }
+      count_response(srv, 0, t0, items);
     }
   }
   // Columnar feeder: fall-through RPCs pack straight into the
@@ -770,7 +823,7 @@ void serve_rpc(Server* srv, const std::shared_ptr<Conn>& conn,
   if (!routed && items > 0) {
     void* feeder = srv->feeder.load();
     if (feeder != nullptr) {
-      auto* token = new FeederToken{conn, srv};
+      auto* token = new FeederToken{conn, srv, t0, items};
       const int64_t fr = cf_pack(
           feeder, reinterpret_cast<const uint8_t*>(body.data()),
           static_cast<int64_t>(body.size()), items, token, stream,
@@ -1077,6 +1130,7 @@ void dispatch_loop(Server* srv) {
       evr_record(ring, kEvWindowServe, t1, t1 - t_cb, total);
     }
     srv->windows.fetch_add(1);
+    srv->window_items.fetch_add(total);
     int64_t offset = 0;
     size_t ridx = 0;
     for (auto& rpc : batch) {
@@ -1088,12 +1142,11 @@ void dispatch_loop(Server* srv) {
       if (st == 0) {
         send_rpc_response(rpc.conn, rpc.stream, cols.data(), offset,
                           rpc.items, total, 0);
-        srv->rpcs.fetch_add(1);
       } else {
         send_rpc_response(rpc.conn, rpc.stream, nullptr, 0, 0, 0,
                           static_cast<int>(st));
-        srv->errors.fetch_add(1);
       }
+      count_response(srv, static_cast<int>(st), rpc.t_enq_ns, rpc.items);
       offset += rpc.items;
     }
   }
@@ -1644,12 +1697,8 @@ void h2s_feeder_respond(void* conn_token, int64_t stream,
     }
     send_rpc_payload(token->conn, static_cast<uint32_t>(stream),
                      std::move(data), grpc_status);
-    if (grpc_status == 0) {
-      token->srv->rpcs.fetch_add(1);
-      token->srv->feeder_rpcs.fetch_add(1);
-    } else {
-      token->srv->errors.fetch_add(1);
-    }
+    if (grpc_status == 0) token->srv->feeder_rpcs.fetch_add(1);
+    count_response(token->srv, grpc_status, token->t0_ns, token->items);
   }
   delete token;
 }
@@ -1676,8 +1725,9 @@ int32_t h2s_port(void* handle) {
 
 // out: [0] rpcs, [1] windows, [2] errors, [3] native_rpcs,
 // [4] native_items, [5] feeder_rpcs, [6] feeder_items,
-// [7] conns_open, [8] idle_reaped, [9] reactors, [10] event_front
-// (callers may pass a larger zeroed buffer; only the first eleven
+// [7] conns_open, [8] idle_reaped, [9] reactors, [10] event_front,
+// [11] unimplemented (of errors), [12] window_items (byte windows)
+// (callers may pass a larger zeroed buffer; only the first thirteen
 // slots are written).
 void h2s_stats(void* handle, int64_t* out) {
   auto* srv = static_cast<Server*>(handle);
@@ -1692,6 +1742,8 @@ void h2s_stats(void* handle, int64_t* out) {
   out[8] = srv->idle_reaped.load();
   out[9] = static_cast<int64_t>(srv->reactors.size());
   out[10] = srv->event_front ? 1 : 0;
+  out[11] = srv->unimplemented.load();
+  out[12] = srv->window_items.load();
 }
 
 void h2s_stop(void* handle) {

@@ -142,7 +142,10 @@ class ReadbackCombiner:
     # -- leader path ---------------------------------------------------
 
     def _stack_program(self, count: int, shape, dtype):
-        key = (count, tuple(shape), str(dtype))
+        # By the dtype's name: warm-up says `jnp.int32`, a handle says
+        # `dtype('int32')`, and `str()` of the two differs — the ladder
+        # warmed programs serving never found (PR 32).
+        key = (count, tuple(shape), np.dtype(dtype).name)
         prog = self._stack_cache.get(key)
         if prog is None:
             # guberlint: shapes fan-in/shape/dtype pinned by the cache key; universe {widths} x {2,4,8,16}, precompiled in warmup_stacks

@@ -331,6 +331,7 @@ class Daemon:
                 self.instance.native_events = NativeEventCollector.from_env(
                     self.h2_fast
                 )
+            log.info("native h2 front serving: %s", self.h2_fast.settings())
 
         # Fleet observability plane (obs/; OBSERVABILITY.md §§9-10):
         # the cluster rollup collector behind /debug/fleet +
@@ -440,10 +441,12 @@ class Daemon:
         measured multi-second p99 spike.
         tests/test_warmup.py pins zero compile-cache misses."""
         conf = self.conf
-        if conf.global_serve_window > 0 or conf.local_batch_wait > 0:
-            engine.warmup(max_width=4096)
-        else:
-            engine.warmup()
+        windows = (
+            conf.global_serve_window > 0
+            or conf.local_batch_wait > 0
+            or bool(conf.h2_fast_address)  # the native front's window
+        )
+        engine.warmup(max_width=4096 if windows else 1024)
 
     # ------------------------------------------------------------------
 

@@ -280,6 +280,9 @@ class _Handler(BaseHTTPRequestHandler):
         ev = getattr(inst, "native_events", None)
         if ev is not None:
             out["native_events"] = ev.stats()
+        front = getattr(inst, "h2_front", None)
+        if front is not None:
+            out["h2_front"] = front.debug_vars()
         out["peer_health"] = {}
         for p in inst.get_peer_list():
             if p.info.is_owner:

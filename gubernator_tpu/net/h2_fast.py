@@ -257,6 +257,8 @@ class H2FastFront:
             raise RuntimeError("native h2 server unavailable")
         self._lib = lib
         self.instance = instance
+        self.window_s = window_s
+        self.flush_items = flush_items
         # Serializes conn_stats() (the metrics collector's scrape
         # thread) against close(): the handle must not be freed while
         # an FFI stats call is in flight.
@@ -634,13 +636,15 @@ class H2FastFront:
         }
 
     def stats(self) -> dict:
-        out = np.zeros(16, dtype=np.int64)
         with self._teardown_mu:
-            handle = self._handle
-            if handle:
-                self._lib.h2s_stats(
-                    handle, out.ctypes.data_as(ctypes.c_void_p)
-                )
+            return self._stats_locked()
+
+    def _stats_locked(self) -> dict:  # guberlint: holds _teardown_mu
+        out = np.zeros(16, dtype=np.int64)
+        if self._handle:
+            self._lib.h2s_stats(
+                self._handle, out.ctypes.data_as(ctypes.c_void_p)
+            )
         stats = {
             "rpcs": int(out[0]),
             "windows": int(out[1]),
@@ -653,13 +657,65 @@ class H2FastFront:
             "conns_idle_reaped": int(out[8]),
             "reactors": int(out[9]),
             "event_front": bool(out[10]),
+            "declined_rpcs": int(out[11]),
+            "window_items": int(out[12]),
             "lanes": self.lanes,
         }
-        if self.plane is not None:
+        # Mid-teardown (handle gone) the plane and the feeder may be
+        # freed already: their counters are read only beside a live
+        # handle.
+        if self._handle and self.plane is not None:
             stats.update(self.plane.stats())
-        if self.feeder is not None:
+        if self._handle and self.feeder is not None:
             stats.update(self.feeder.stats())
         return stats
+
+    def settings(self) -> dict:
+        """What this front serves with — the start line's summary and
+        the `settings` of /debug/vars `h2_front`."""
+        return {
+            "address": self.address,
+            "window_ms": self.window_s * 1e3,
+            "flush_items": self.flush_items,
+            "event_front": self.event_front,
+            "reactors": self.reactors,
+            "lanes": self.lanes,
+            "feeder": self.feeder is not None,
+            "decision_plane": self.plane is not None,
+            "retry_hints": retry_hints_enabled(),
+            "event_ring": self._ring is not None,
+        }
+
+    def debug_vars(self) -> dict:
+        """/debug/vars `h2_front`: the settings and the front's
+        monotonic counters, summed over its paths so that a reader
+        takes a window's difference — `rpcs` answered OK, `errors`
+        answered with a grpc status (of them `declined_rpcs`
+        UNIMPLEMENTED: out of the columnar path's scope), `windows` and
+        `items` entered into Python (byte windows + feeder windows;
+        the decision plane's RPCs enter none), `ring_dropped` events
+        the ring was too full to take."""
+        # One hold of _teardown_mu over every FFI read: close() frees
+        # the feeder and the ring only after it has taken the handle
+        # away under the same lock.
+        with self._teardown_mu:
+            if not self._handle:
+                return {"settings": self.settings(), "closed": True}
+            st = self._stats_locked()
+            dropped = self.ring_stats()["dropped"]
+        return {
+            "settings": self.settings(),
+            "rpcs": st["rpcs"],
+            "errors": st["errors"],
+            "declined_rpcs": st["declined_rpcs"],
+            "windows": st["windows"] + st.get("feeder_windows", 0),
+            "items": st["window_items"] + st.get("feeder_served_rows", 0),
+            "feeder_rpcs": st["feeder_front_rpcs"],
+            "feeder_ring_full": st.get("feeder_ring_full", 0),
+            "feeder_declined": st.get("feeder_declined", 0),
+            "plane_rpcs": st["native_rpcs"],
+            "ring_dropped": dropped,
+        }
 
     def close(self) -> None:
         if self._handle:

@@ -1011,6 +1011,9 @@ class ShardedDecisionEngine:
                 else:
                     t.hits, t.misses = h, m
                     t.evictions, t.unexpired_evictions = ev, un
+            # Wider batches are chunked, not compiled on demand (see
+            # DecisionEngine.warmup); the width is a shard's.
+            self.max_kernel_width = min(self.max_kernel_width, max_width)
         finally:
             # Exception-safety: a failed warmup must not leave
             # persistence disabled (see DecisionEngine.warmup).

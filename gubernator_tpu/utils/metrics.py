@@ -145,20 +145,27 @@ class DurationStat:
             if ex is not None:
                 self.exemplars[b] = ex
 
-    def observe_bucket_counts(self, counts) -> None:
+    def observe_bucket_counts(self, counts, total=None, top=None) -> None:
         """Merge pre-bucketed counts (index-aligned with N_BUCKETS) —
         the native event collector drains per-stage C histograms this
-        way, one lock per drain instead of one per event."""
-        n = total = 0.0
-        top = 0.0
+        way, one lock per drain instead of one per event.  A caller
+        that still holds the raw durations passes their exact `total`
+        and `top` (seconds); without them both are read off the
+        buckets, to an octave."""
+        n = 0
+        mid_total = mid_top = 0.0
         for i, c in enumerate(counts):
             if c:
                 n += c
                 lo, hi = self.bucket_bounds(i)
-                total += c * (lo + hi) / 2.0
-                top = (lo * hi) ** 0.5
+                mid_total += c * (lo + hi) / 2.0
+                mid_top = (lo * hi) ** 0.5
         if not n:
             return
+        if total is None:
+            total = mid_total
+        if top is None:
+            top = mid_top
         with self._lock:
             self.count += int(n)
             self.total += total

@@ -35,8 +35,8 @@ from gubernator_tpu.utils.metrics import DurationStat, record_swallowed
 log = logging.getLogger("gubernator_tpu.native_events")
 
 # kind -> stage name (h2_server.cpp kEvNativeServe/kEvWindowWait/
-# kEvWindowServe; columnar_feeder.cpp kEvFeederPack/kEvFeederRingWait/
-# kEvFeederServe).
+# kEvWindowServe/kEvRpcTotal; columnar_feeder.cpp kEvFeederPack/
+# kEvFeederRingWait/kEvFeederServe/kEvFeederScatter).
 STAGES = {
     1: "native_serve",
     2: "window_wait",
@@ -56,6 +56,15 @@ STAGES = {
     7: "reactor_wake",
     8: "reactor_read",
     9: "reactor_write",
+    # Per RPC, whichever path answered it: body deframed → response
+    # handed to the connection's write path (items = the RPC's items).
+    # On the feeder path it is tiled by feeder_ring_wait (which starts
+    # at the same instant and so contains feeder_pack), the window's
+    # feeder_serve and the RPC's place in feeder_scatter.
+    10: "rpc_total",
+    # Per feeder window: the C response encode + scatter of all its
+    # RPCs, after the columnar callback returned (items = its RPCs).
+    11: "feeder_scatter",
 }
 
 # Span stubs recorded per drain tick, bounded: under a 9k/s native
@@ -128,8 +137,20 @@ class NativeEventCollector:
             record_swallowed("native_events.drain")
 
     def drain_once(self) -> int:
-        """One ring drain: bin durations into the per-stage histograms
-        (vectorized), count events, emit bounded span stubs."""
+        """Drain the ring until it is empty (a tick's worth can exceed
+        one buffer: a single-item herd publishes several events an
+        RPC); returns the records read."""
+        total = 0
+        while True:
+            n = self._drain_buffer()
+            total += max(n, 0)
+            if n < self._max_drain:
+                return total
+
+    def _drain_buffer(self) -> int:
+        """One buffer's worth: bin durations into the per-stage
+        histograms (vectorized) beside their exact sum and maximum,
+        count events, emit bounded span stubs."""
         import numpy as np
 
         n = self._front.drain_events(self._out)
@@ -151,7 +172,11 @@ class NativeEventCollector:
             counts = np.bincount(
                 idx[mask], minlength=DurationStat.N_BUCKETS
             )
-            self._hists[stage].observe_bucket_counts(counts.tolist())
+            of_stage = dur_s[mask]
+            self._hists[stage].observe_bucket_counts(
+                counts.tolist(), total=float(of_stage.sum()),
+                top=float(of_stage.max()),
+            )
             with self._lock:
                 self._counts[stage] += m
         self._emit_stubs(rec)
