@@ -160,7 +160,7 @@ class PendingColumnar:
     copying to host asynchronously.  `.get()` materializes (status,
     limit, remaining, reset_time) in request order."""
 
-    __slots__ = ("_engine", "_pieces", "_limit", "_n", "_result")
+    __slots__ = ("_engine", "_pieces", "_limit", "_n", "_result", "_reads")
 
     def __init__(self, engine, pieces, limit, n):
         self._engine = engine
@@ -168,6 +168,30 @@ class PendingColumnar:
         self._limit = limit
         self._n = n
         self._result = None
+        self._reads = None
+
+    def start_readback(self) -> "PendingColumnar":
+        """Start this batch's own device→host copies now, so that
+        "submitted" means launched and copying for every batch shape:
+        its readback tickets leave the combiner's queue for transfers
+        of their own (`ReadbackCombiner.start_own` — `get()` then waits
+        for this batch's steps and no later one's), and pump rounds,
+        which launch lazily at the first fetch, are flushed (the flush
+        starts its group's copy).  For a caller that launches its next
+        batch before it reads this one: the native front's serve
+        thread."""
+        from gubernator_tpu.core.readback import Ticket
+
+        own = []
+        for piece in self._pieces:
+            ticket = piece[0]
+            if isinstance(ticket, Ticket):
+                own.append(ticket)
+            elif ticket.group is None and ticket.error is None:
+                ticket.pump.flush_for(ticket)
+        if own:
+            self._reads = self._engine.readback.start_own(own)
+        return self
 
     def get(self):
         if self._result is not None:
@@ -175,6 +199,9 @@ class PendingColumnar:
         from gubernator_tpu.ops.bucket_kernel import unpack_out_host
 
         n = self._n
+        if self._reads is not None:
+            reads, self._reads = self._reads, None
+            self._engine.readback.land(reads)
         # The waits first (combined transfers, core/readback.py; each
         # observes device.readback), then ONE engine.unpack for the
         # RPC: a work annotation never spans a blocking read.

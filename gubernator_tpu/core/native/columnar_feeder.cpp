@@ -18,11 +18,29 @@
 // pack_batch_host consumes, so the Python side's only remaining work
 // is the intern-table schedule + the packed-round submit the PR 9
 // double-buffered pump already ingests without a critical-path
-// np.stack).  Python is entered exactly once per WINDOW through the
-// columnar callback, with ZERO-COPY numpy views over the ring slot —
-// no bytes cross the boundary at all, in either direction: verdict
-// columns are written back in place and the feeder thread encodes +
-// scatters the per-RPC responses through the C connection plane.
+// np.stack).  Python is entered per WINDOW, never per RPC, through the
+// columnar callback pair, with ZERO-COPY numpy views over the ring
+// slot — no bytes cross the boundary at all, in either direction:
+// verdict columns are written back in place and the feeder thread
+// encodes + scatters the per-RPC responses through the C connection
+// plane.
+//
+// The serve is double-buffered too (depth two, no setting): a window's
+// Python entry is SUBMIT (everything up to and including the device
+// launches) and, where submit says the answers are still on the
+// device, COMPLETE (read them back, write the verdict lanes).  While
+// window k is in flight the serve thread submits window k+1 — its
+// intern, pack and launch run while k's step runs — and only then
+// completes, scatters and recycles k.  Window k+1 is taken without its
+// group-commit wait only if it is WORTH A DISPATCH of its own: sealed,
+// or holding flush_rows / 8 rows (see kWorthShare).  A thinner window
+// is a herd's: taken early it splits what the group-commit wait
+// gathers into one dispatch (100 single-item callers fell into windows
+// of 44 and twice the dispatches).  With nothing worth one the thread
+// completes k at once: it never sleeps on the condvar or the window
+// timer with a window in flight, so an answer the device has finished
+// is never held for arrivals; a submit that returns finished columns
+// (0) is scattered at once, as before.
 //
 // Concurrency design (same Vyukov-school shape as event_ring.cpp):
 //   * One OPEN window at a time.  Producers claim (rpc, rows, key
@@ -59,6 +77,7 @@
 // (h2s_feeder_respond / h2s_feeder_release) is an ordinary in-image
 // call.
 
+#include <algorithm>
 #include <atomic>
 #include <chrono>
 #include <condition_variable>
@@ -99,9 +118,15 @@ extern "C" int64_t evr_now_ns();
 // h2 front's serve/window kinds).
 constexpr int64_t kEvFeederPack = 4;      // conn thread: decode+pack
 constexpr int64_t kEvFeederRingWait = 5;  // pack → window callback
-constexpr int64_t kEvFeederServe = 6;     // columnar callback wall
+constexpr int64_t kEvFeederServe = 6;     // per window: submit + complete walls
 // 10 is the h2 front's per-RPC total (h2_server.cpp).
 constexpr int64_t kEvFeederScatter = 11;  // per window: encode + scatter
+constexpr int64_t kEvFeederInflight = 12;  // submit's return → complete's entry
+
+// A submit's return value for "launched, answers still on the device":
+// the window stays CLOSED and owes a complete entry.  Any other value
+// is what the single entry always returned (0, or a grpc status).
+constexpr int64_t kInFlight = -1;
 
 namespace {
 
@@ -130,6 +155,8 @@ inline uint64_t cur_gen(uint64_t c) { return (c >> kGenShift) & kGenMask; }
 // columnar path using the PRE-MAPPED zero-copy views of the slot's
 // column arrays, writes the verdict columns + per-RPC status in
 // place, and returns 0 (or a grpc status failing the whole window).
+// The submit entry may instead return kInFlight; the complete entry
+// (same signature, same thread) then owes the verdicts.
 typedef int64_t (*ColumnarCallback)(int64_t slot, int64_t n_rows,
                                     int64_t n_rpcs, int64_t key_bytes);
 
@@ -164,7 +191,7 @@ struct CfWindow {
 };
 
 struct Feeder {
-  // guberlint: guard callback by mu
+  // guberlint: guard callback, complete by mu
   int64_t n_slots, max_rows, key_cap, max_rpcs;
   int64_t disqualify_mask;
   int64_t window_us = 2000;
@@ -180,6 +207,7 @@ struct Feeder {
   // Python window callback; cf_stop nulls it (drain windows answer
   // UNAVAILABLE), so reads and the write serialize on mu.
   ColumnarCallback callback = nullptr;  // guarded by mu
+  ColumnarCallback complete = nullptr;  // guarded by mu
   std::thread serve_thread;
   std::mutex mu;
   std::condition_variable cv;
@@ -194,6 +222,8 @@ struct Feeder {
   std::atomic<int64_t> packed_rpcs{0}, packed_rows{0}, windows{0};
   std::atomic<int64_t> served_rows{0}, ring_full{0}, declined{0};
   std::atomic<int64_t> window_errors{0};
+  // Windows whose submit ran while another window was in flight.
+  std::atomic<int64_t> windows_overlapped{0};
 };
 
 // Thread-local decode scratch: the two-phase pack (decode here, then
@@ -323,10 +353,24 @@ void scatter_window(Feeder* f, CfWindow& w, uint64_t sealed, int64_t rc) {
   }
 }
 
-// Seal `w` (idempotent), wait for in-flight producer copies, serve it
-// through the Python columnar callback, scatter the responses, and
-// recycle the slot.  Only the feeder thread calls this.
-void serve_window(Feeder* f, int64_t idx) {
+// A sealed window between its submit and its scatter.  Lives on the
+// serve thread's stack; at most one is in flight at a time.
+struct Served {
+  int64_t idx = -1;      // -1: none
+  uint64_t sealed = 0;   // the final claim set
+  int64_t rc = 0;        // 0 | a grpc status for the window | kInFlight
+  ColumnarCallback done = nullptr;  // the complete entry that owes the verdicts
+  bool entered = false;  // Python was entered for this window
+  int64_t serve_ns = 0;  // walls of its Python entries so far
+  int64_t t_submitted_ns = 0;  // submit's return: the in-flight span's start
+};
+
+// Seal window `idx` (idempotent), wait for in-flight producer copies
+// and run its SUBMIT entry: the whole serve where Python answers with
+// finished columns, or everything up to the device launches where it
+// answers kInFlight.  False (and the slot reopened) if nothing was
+// claimed.  Only the feeder thread calls this.
+bool submit_window(Feeder* f, int64_t idx, Served* s) {
   CfWindow& w = f->slots[idx];
   const uint64_t sealed = w.cursor.fetch_or(kClosedBit);
   const int64_t rows = static_cast<int64_t>(cur_rows(sealed));
@@ -334,7 +378,7 @@ void serve_window(Feeder* f, int64_t idx) {
     // Nothing claimed since reset: reopen (gen unchanged — no claim
     // ever observed this window, so no ABA exposure).
     w.cursor.store(sealed & (kGenMask << kGenShift));
-    return;
+    return false;
   }
   // Producers that claimed before the seal are mid-copy at most; the
   // gap between claim and commit is a bounded memcpy, so a spin-yield
@@ -342,12 +386,15 @@ void serve_window(Feeder* f, int64_t idx) {
   while (w.committed_rows.load() != rows) std::this_thread::yield();
   void* ring = f->ring.load();
   const int64_t n_rpcs = static_cast<int64_t>(cur_rpcs(sealed));
-  ColumnarCallback cb;
+  ColumnarCallback cb, done;
   {
     std::lock_guard<std::mutex> lock(f->mu);
     cb = f->callback;
+    done = f->complete;
   }
-  int64_t rc = 0;
+  *s = Served();
+  s->idx = idx;
+  s->sealed = sealed;
   if (cb != nullptr) {
     const int64_t t_cb = ring ? evr_now_ns() : 0;
     if (ring) {
@@ -356,55 +403,119 @@ void serve_window(Feeder* f, int64_t idx) {
           evr_record(ring, kEvFeederRingWait, t_cb,
                      t_cb - w.rpc_enq_ns[r], w.rpc_items[r]);
     }
-    rc = cb(idx, rows, n_rpcs, static_cast<int64_t>(cur_bytes(sealed)));
-    if (ring) {
-      const int64_t t1 = evr_now_ns();
-      evr_record(ring, kEvFeederServe, t1, t1 - t_cb, rows);
+    s->rc = cb(idx, rows, n_rpcs, static_cast<int64_t>(cur_bytes(sealed)));
+    s->entered = true;
+    if (s->rc == kInFlight) {
+      if (done != nullptr)
+        s->done = done;
+      else
+        s->rc = 13;  // a pending nobody can complete: INTERNAL
     }
-    f->served_rows.fetch_add(rows);
+    if (ring) {
+      s->t_submitted_ns = evr_now_ns();
+      s->serve_ns = s->t_submitted_ns - t_cb;
+    }
   } else {
-    rc = 14;  // sink mode (bench) / teardown: UNAVAILABLE
+    s->rc = 14;  // sink mode (bench) / teardown: UNAVAILABLE
+  }
+  return true;
+}
+
+// The second half of a window's serve: its COMPLETE entry if submit
+// left it in flight, then the response scatter and the slot's recycle.
+void finish_window(Feeder* f, Served* s) {
+  CfWindow& w = f->slots[s->idx];
+  void* ring = f->ring.load();
+  const int64_t rows = static_cast<int64_t>(cur_rows(s->sealed));
+  const int64_t n_rpcs = static_cast<int64_t>(cur_rpcs(s->sealed));
+  if (s->rc == kInFlight) {
+    const int64_t t0 = ring ? evr_now_ns() : 0;
+    if (ring && s->t_submitted_ns)
+      evr_record(ring, kEvFeederInflight, t0, t0 - s->t_submitted_ns, rows);
+    s->rc = s->done(s->idx, rows, n_rpcs,
+                    static_cast<int64_t>(cur_bytes(s->sealed)));
+    if (s->rc == kInFlight) s->rc = 13;  // complete owes the verdicts
+    if (ring) s->serve_ns += evr_now_ns() - t0;
+  }
+  if (s->entered) {
+    // One serve event a window: the sum of its entries' walls.
+    if (ring) evr_record(ring, kEvFeederServe, evr_now_ns(), s->serve_ns, rows);
+    f->served_rows.fetch_add(rows);
   }
   f->windows.fetch_add(1);
   const int64_t t_sc = ring ? evr_now_ns() : 0;
-  scatter_window(f, w, sealed, rc);
+  scatter_window(f, w, s->sealed, s->rc);
   if (ring) {
     const int64_t t1 = evr_now_ns();
     evr_record(ring, kEvFeederScatter, t1, t1 - t_sc, n_rpcs);
   }
   // Recycle: bump the generation, zero the claims, reopen.
   w.committed_rows.store(0);
-  const uint64_t next_gen = (cur_gen(sealed) + 1) & kGenMask;
+  const uint64_t next_gen = (cur_gen(s->sealed) + 1) & kGenMask;
   w.cursor.store(next_gen << kGenShift);
+  s->idx = -1;
+}
+
+// With a window in flight, a waiting window is submitted at once if
+// it is sealed or holds this share of flush_rows; a thinner one keeps
+// its group-commit wait.  An eighth of an engine batch (512 of 4,096
+// rows) is where a dispatch's fixed cost — one upload and one launch,
+// ~0.9 ms on a v5e host — is about what its rows cost (~2–3 us each,
+// host and device): from there on gathering more buys little, below
+// it the fixed cost is most of the dispatch.
+constexpr int64_t kWorthShare = 8;
+
+// The oldest window, `skip` (the one in flight) left out, that is
+// sealed or holds `min_rows` rows; -1 if none.  The ring opens its
+// slots in order, so the scan starts behind the open window and ends
+// on it.  A flush can seal ANY slot with claims, and serving is
+// single-consumer, so taking one out of ring order is safe.
+int64_t find_window(Feeder* f, int64_t skip, int64_t min_rows) {
+  const int64_t open = f->open.load();
+  for (int64_t k = 1; k <= f->n_slots; ++k) {
+    const int64_t i = (open + k) % f->n_slots;
+    if (i == skip) continue;
+    const uint64_t cur = f->slots[i].cursor.load();
+    if ((cur & kClosedBit) ||
+        static_cast<int64_t>(cur_rows(cur)) >= min_rows)
+      return i;
+  }
+  return -1;
 }
 
 void serve_loop(Feeder* f) {
+  Served fl;  // the window in flight on the device (idx < 0: none)
+  const int64_t worth = std::max<int64_t>(1, f->flush_rows / kWorthShare);
   while (true) {
-    {
-      std::unique_lock<std::mutex> lock(f->mu);
-      f->cv.wait(lock, [&] {
-        if (f->closing.load() || f->kick.load()) return true;
-        if (cur_rows(f->slots[f->open.load()].cursor.load()) != 0)
-          return true;
-        // A sealed NON-open window must also wake the loop: a flush
-        // racing the rotation (seal lands just after `open` moved
-        // past the slot) or a consumed kick would otherwise strand
-        // its rows until the next pack — the PR-12 teardown
-        // row-conservation race.
-        for (int64_t i = 0; i < f->n_slots; ++i)
-          if (f->slots[i].cursor.load() & kClosedBit) return true;
-        return false;
-      });
-      f->kick.store(false);
-    }
-    if (f->closing.load()) break;
-    // Group-commit window: wait up to window_us for concurrent
-    // arrivals unless a producer already sealed (flush threshold).
-    {
+    if (fl.idx < 0) {
+      // Nothing in flight: sleep until a window holds rows, then give
+      // it its group-commit wait.  With a window in flight neither
+      // wait runs — its submit was the gathering time, and only a
+      // window worth a dispatch of its own is taken.
+      {
+        std::unique_lock<std::mutex> lock(f->mu);
+        f->cv.wait(lock, [&] {
+          if (f->closing.load() || f->kick.load()) return true;
+          if (cur_rows(f->slots[f->open.load()].cursor.load()) != 0)
+            return true;
+          // A sealed NON-open window must also wake the loop: a flush
+          // racing the rotation (seal lands just after `open` moved
+          // past the slot) or a consumed kick would otherwise strand
+          // its rows until the next pack — the PR-12 teardown
+          // row-conservation race.
+          for (int64_t i = 0; i < f->n_slots; ++i)
+            if (f->slots[i].cursor.load() & kClosedBit) return true;
+          return false;
+        });
+        f->kick.store(false);
+      }
+      if (f->closing.load()) break;
+      // Group-commit window: wait up to window_us for concurrent
+      // arrivals unless a producer (flush threshold) or a flush
+      // already sealed a window.
       const int64_t idx = f->open.load();
       CfWindow& w = f->slots[idx];
-      if (!(w.cursor.load() & kClosedBit) &&
-          cur_rows(w.cursor.load()) != 0) {
+      if (find_window(f, -1, 1) == idx && !(w.cursor.load() & kClosedBit)) {
         std::unique_lock<std::mutex> lock(f->mu);
         const auto deadline = std::chrono::steady_clock::now() +
                               std::chrono::microseconds(f->window_us);
@@ -414,34 +525,47 @@ void serve_loop(Feeder* f) {
         });
         f->kick.store(false);
       }
-      if (f->closing.load()) break;
-      // Rotate FIRST, then serve: producers keep packing into the
-      // next slot while Python serves this one (the double-buffered
-      // ingest the ring exists for).  If the next slot has not been
-      // recycled yet (possible only with in-flight windows ≥
-      // n_slots), the open window stays sealed and packs fall back to
-      // the byte path until a slot frees.
-      const int64_t next = (idx + 1) % f->n_slots;
-      CfWindow& nw = f->slots[next];
-      const uint64_t ncur = nw.cursor.load();
-      if (!(ncur & kClosedBit) && cur_rows(ncur) == 0 && next != idx)
-        f->open.store(next);
-      serve_window(f, idx);
-      // Sweep sealed windows the open cursor already rotated past
-      // (a flush can seal ANY slot with claims, not just the open
-      // one) — serving is single-consumer, so serving them out of
-      // ring order is safe, and without the sweep they would wait on
-      // the next wake instead of draining now.
-      for (int64_t i = 0; i < f->n_slots; ++i)
-        if (i != idx && (f->slots[i].cursor.load() & kClosedBit))
-          serve_window(f, i);
+    }
+    if (f->closing.load()) break;
+    Served nx;
+    bool submitted = false;
+    const int64_t idx = find_window(f, fl.idx, fl.idx >= 0 ? worth : 1);
+    if (idx >= 0) {
+      if (idx == f->open.load()) {
+        // Rotate FIRST, then serve: producers keep packing into the
+        // next slot while Python serves this one (the double-buffered
+        // ingest the ring exists for).  If the next slot has not been
+        // recycled yet (two slots, one of them in flight), the open
+        // window stays sealed and packs fall back to the byte path
+        // until a slot frees.
+        const int64_t next = (idx + 1) % f->n_slots;
+        const uint64_t ncur = f->slots[next].cursor.load();
+        if (!(ncur & kClosedBit) && cur_rows(ncur) == 0 && next != idx)
+          f->open.store(next);
+      }
+      submitted = submit_window(f, idx, &nx);
+      if (submitted && fl.idx >= 0) f->windows_overlapped.fetch_add(1);
+    }
+    // Window k's answers only after window k+1 is launched: k's step
+    // ran while the thread interned and packed k+1.
+    if (fl.idx >= 0) finish_window(f, &fl);
+    if (submitted) {
+      if (nx.rc == kInFlight && find_window(f, nx.idx, worth) >= 0)
+        fl = nx;  // a window is worth a dispatch: submit it, then read
+      else
+        finish_window(f, &nx);  // nothing is: answer at once
     }
   }
-  // Drain-then-close: serve every window that still has claims so no
-  // RPC strands mid-ring and every conn token is released.  The
-  // Python side has already detached the callback path by contract
-  // (cf_stop nulls it first), so these answer UNAVAILABLE.
-  for (int64_t i = 0; i < f->n_slots; ++i) serve_window(f, i);
+  // Drain-then-close: the in-flight window first (its step has run,
+  // its callers are owed the answers), then every window that still
+  // has claims, so no RPC strands mid-ring and every conn token is
+  // released.  The Python side has already detached the callback path
+  // by contract (cf_stop nulls it first), so these answer UNAVAILABLE.
+  if (fl.idx >= 0) finish_window(f, &fl);
+  for (int64_t i = 0; i < f->n_slots; ++i) {
+    Served s;
+    if (submit_window(f, i, &s)) finish_window(f, &s);
+  }
 }
 
 }  // namespace
@@ -451,11 +575,14 @@ extern "C" {
 // Create a feeder ring: n_slots windows of max_rows rows / key_cap
 // key bytes / max_rpcs RPCs each.  `callback` may be nullptr (sink
 // mode: windows seal and recycle without entering Python — the
-// microbench and overflow tests run the pure pack path).
+// microbench and overflow tests run the pure pack path).  `complete`
+// may be nullptr too: `callback` is then the window's one entry and
+// may not answer kInFlight.
 void* cf_create(int64_t n_slots, int64_t max_rows, int64_t key_cap,
                 int64_t max_rpcs, int64_t disqualify_mask,
                 int64_t window_us, int64_t flush_rows,
-                int32_t over_status, ColumnarCallback callback) {
+                int32_t over_status, ColumnarCallback callback,
+                ColumnarCallback complete) {
   if (n_slots < 2) n_slots = 2;
   if (max_rows < 64) max_rows = 64;
   if (max_rows > static_cast<int64_t>(kRowsMask)) max_rows = kRowsMask;
@@ -473,8 +600,10 @@ void* cf_create(int64_t n_slots, int64_t max_rows, int64_t key_cap,
   if (flush_rows > 0) f->flush_rows = flush_rows;
   f->over_status = over_status;
   // guberlint: ok native — pre-publication init: the serve thread
-  // that reads callback under mu is created two statements below.
+  // that reads the callbacks under mu is created below.
   f->callback = callback;
+  // guberlint: ok native — pre-publication init, as above.
+  f->complete = complete;
   f->slots = std::vector<CfWindow>(n_slots);
   for (auto& w : f->slots) {
     w.key_buf.resize(key_cap);
@@ -649,6 +778,8 @@ void cf_flush(void* handle) {
   // teardown contract — no RPC can remain packed-but-unserved.  The
   // serve thread is re-woken every iteration too: a kick consumed by
   // an earlier pass must not strand a window this flush just sealed.
+  // A window in flight is sealed, so it counts as busy here until the
+  // serve thread — which never sleeps while one is — has completed it.
   for (int spins = 0; spins < 5000 && !f->closing.load(); ++spins) {
     bool busy = false;
     for (int64_t i = 0; i < f->n_slots; ++i) {
@@ -667,34 +798,36 @@ void cf_flush(void* handle) {
   }
 }
 
-// out13: packed_rpcs, packed_rows, windows, served_rows, ring_full,
+// out14: packed_rpcs, packed_rows, windows, served_rows, ring_full,
 // declined, window_errors, open_idx, open_rows, n_slots, max_rows,
-// key_cap, max_rpcs (callers may pass a larger zeroed buffer).  The
+// key_cap, max_rpcs, windows_overlapped (callers may pass a larger
+// zeroed buffer).  The
 // clamped shapes are exported so the Python view layer maps EXACTLY
 // the allocated capacities (a caller-supplied max_rpcs above the
 // cursor field width is clamped here, and a view sized off the raw
 // argument would extend past the C allocation).
-void cf_stats(void* handle, int64_t* out13) {
+void cf_stats(void* handle, int64_t* out14) {
   auto* f = static_cast<Feeder*>(handle);
-  out13[0] = f->packed_rpcs.load();
-  out13[1] = f->packed_rows.load();
-  out13[2] = f->windows.load();
-  out13[3] = f->served_rows.load();
-  out13[4] = f->ring_full.load();
-  out13[5] = f->declined.load();
-  out13[6] = f->window_errors.load();
+  out14[0] = f->packed_rpcs.load();
+  out14[1] = f->packed_rows.load();
+  out14[2] = f->windows.load();
+  out14[3] = f->served_rows.load();
+  out14[4] = f->ring_full.load();
+  out14[5] = f->declined.load();
+  out14[6] = f->window_errors.load();
   const int64_t open = f->open.load();
-  out13[7] = open;
-  out13[8] = static_cast<int64_t>(cur_rows(f->slots[open].cursor.load()));
-  out13[9] = f->n_slots;
-  out13[10] = f->max_rows;
-  out13[11] = f->key_cap;
-  out13[12] = f->max_rpcs;
+  out14[7] = open;
+  out14[8] = static_cast<int64_t>(cur_rows(f->slots[open].cursor.load()));
+  out14[9] = f->n_slots;
+  out14[10] = f->max_rows;
+  out14[11] = f->key_cap;
+  out14[12] = f->max_rpcs;
+  out14[13] = f->windows_overlapped.load();
 }
 
-// Stop the serve thread (drains every claimed window first — pending
-// RPCs answer UNAVAILABLE and their tokens are released, so no conn
-// leaks and no use-after-free).  The caller must have detached the
+// Stop the serve thread (completes the window in flight, then drains
+// every claimed window — pending RPCs answer UNAVAILABLE and their
+// tokens are released, so no conn leaks and no use-after-free).  The caller must have detached the
 // feeder from the h2 server BEFORE stopping (conn threads re-read the
 // feeder pointer per RPC), and frees with cf_free AFTER.
 void cf_stop(void* handle) {
@@ -702,6 +835,7 @@ void cf_stop(void* handle) {
   {
     std::lock_guard<std::mutex> lock(f->mu);
     f->callback = nullptr;  // serve-after-stop answers UNAVAILABLE
+    f->complete = nullptr;  // (an in-flight window keeps the one it took)
     f->closing.store(true);
     f->kick.store(true);
     f->cv.notify_all();

@@ -33,11 +33,19 @@ from gubernator_tpu.types import Algorithm, Behavior, Status
 _lib = None
 
 # Columnar window callback (columnar_feeder.cpp ColumnarCallback):
-# (slot, n_rows, n_rpcs, key_bytes) -> 0 | grpc status for the window.
+# (slot, n_rows, n_rpcs, key_bytes) -> 0 | grpc status for the window
+# | IN_FLIGHT from the submit entry (the complete entry then owes the
+# verdicts).
 _FEEDER_CALLBACK = ctypes.CFUNCTYPE(
     ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
     ctypes.c_int64,
 )
+
+# What a window's submit entry returns when it launched the window's
+# device work and left the answers there (columnar_feeder.cpp
+# kInFlight): the serve thread calls the complete entry for the same
+# slot later, after it has submitted the next window.
+IN_FLIGHT = -1
 
 # Same breaker set as core/ledger._BREAKERS — the two tiers must agree
 # on what falls through, or a native answer could cover a row the
@@ -84,7 +92,7 @@ def load() -> Optional[ctypes.CDLL]:
     # Columnar feeder plane (columnar_feeder.cpp, same .so).
     lib.cf_create.restype = vp
     lib.cf_create.argtypes = [i64, i64, i64, i64, i64, i64, i64, i32,
-                              _FEEDER_CALLBACK]
+                              _FEEDER_CALLBACK, _FEEDER_CALLBACK]
     lib.cf_attach_ring.argtypes = [vp, vp]
     lib.cf_set_hints.argtypes = [vp, i64]
     lib.cf_slot_ptrs.argtypes = [vp, i64, vp]
@@ -263,7 +271,7 @@ class FeederSlot:
         "key_buf", "key_offsets", "algo", "behavior", "hits", "limit",
         "duration", "burst", "fnv1", "fnv1a", "name_lens", "out_status",
         "out_limit", "out_remaining", "out_reset", "rpc_row",
-        "rpc_items", "rpc_status", "hint_now_ms",
+        "rpc_items", "rpc_status", "hint_now_ms", "pending",
     )
 
     _DTYPES = (
@@ -297,6 +305,9 @@ class FeederSlot:
                 shape=(size,),
             )
             object.__setattr__(self, name, arr)
+        # What the owner's submit entry leaves for its complete entry
+        # (the window's in-flight batch); None between windows.
+        self.pending = None
 
 
 class NativeColumnarFeeder:
@@ -308,8 +319,14 @@ class NativeColumnarFeeder:
     key_bytes) -> int` — it serves the window through the engine
     columnar path, writes the verdict lanes + per-RPC status in place,
     and returns 0 (or a grpc status failing the whole window).
-    `window_handler=None` creates a SINK feeder (bench/tests: windows
-    seal and recycle in C, no Python per window)."""
+    With `window_complete` (same signature) the handler is the
+    window's SUBMIT entry and may return IN_FLIGHT instead: the serve
+    thread then submits the next window, if one is worth a dispatch
+    (sealed, or holding `flush_rows` / 8 rows), before it calls
+    `window_complete` for this slot — which owes the
+    verdict lanes and the return value.  The slot stays sealed between
+    the two.  `window_handler=None` creates a SINK feeder (bench/tests:
+    windows seal and recycle in C, no Python per window)."""
 
     def __init__(
         self,
@@ -323,22 +340,19 @@ class NativeColumnarFeeder:
         flush_rows: int = 4096,
         hints: bool = True,
         window_handler=None,
+        window_complete=None,
     ):
         lib = load()
         if lib is None:
             raise RuntimeError("native columnar feeder unavailable")
         self._lib = lib
-        self._handler = window_handler
-        # The ctypes callback object must outlive the ring.
-        self._cb = (
-            _FEEDER_CALLBACK(self._window)
-            if window_handler is not None
-            else ctypes.cast(None, _FEEDER_CALLBACK)
-        )
+        # The ctypes callback objects must outlive the ring.
+        self._cb = self._callback(window_handler, "feeder.window")
+        self._complete_cb = self._callback(window_complete, "feeder.complete")
         self._handle = lib.cf_create(
             n_slots, max_rows, key_cap, max_rpcs, disqualify_mask,
             int(window_s * 1e6), flush_rows, int(Status.OVER_LIMIT),
-            self._cb,
+            self._cb, self._complete_cb,
         )
         if not self._handle:
             raise RuntimeError("cf_create failed")
@@ -357,21 +371,30 @@ class NativeColumnarFeeder:
         ]
         lib.cf_set_hints(self._handle, 1 if hints else 0)
 
-    # -- the per-window trampoline (feeder serve thread → Python) ------
+    # -- the per-window trampolines (feeder serve thread → Python) -----
 
-    def _window(self, slot, n_rows, n_rpcs, key_bytes) -> int:
-        try:
-            return int(
-                self._handler(
-                    self.slots[int(slot)], int(n_rows), int(n_rpcs),
-                    int(key_bytes),
+    def _callback(self, handler, site: str):
+        """One window entry as C calls it: the owner's handler over the
+        slot's views; a handler that raises fails that window's RPCs
+        INTERNAL and nothing else.  No handler: a null pointer."""
+        if handler is None:
+            return ctypes.cast(None, _FEEDER_CALLBACK)
+
+        def entry(slot, n_rows, n_rpcs, key_bytes) -> int:
+            try:
+                return int(
+                    handler(
+                        self.slots[int(slot)], int(n_rows), int(n_rpcs),
+                        int(key_bytes),
+                    )
                 )
-            )
-        except Exception:  # noqa: BLE001 — never unwind into C
-            from gubernator_tpu.utils.metrics import record_swallowed
+            except Exception:  # noqa: BLE001 — never unwind into C
+                from gubernator_tpu.utils.metrics import record_swallowed
 
-            record_swallowed("feeder.window")
-            return 13  # INTERNAL
+                record_swallowed(site)
+                return 13  # INTERNAL
+
+        return _FEEDER_CALLBACK(entry)
 
     # -- test/bench entries --------------------------------------------
 
@@ -424,6 +447,7 @@ class NativeColumnarFeeder:
             "feeder_max_rows": int(out[10]),
             "feeder_key_cap": int(out[11]),
             "feeder_max_rpcs": int(out[12]),
+            "feeder_windows_overlapped": int(out[13]),
         }
 
     @property

@@ -224,6 +224,42 @@ class ReadbackCombiner:
             # Our group may not have included `ticket` only if shapes
             # raced; loop re-checks.
 
+    # -- window-scoped read (the native front's serve thread) ----------
+
+    def start_own(self, tickets: List[Ticket]):
+        """Claim exactly `tickets` — one batch's own, registered during
+        its dispatch — stack the same-shape ones and start their copies
+        to the host; `land` takes what this returns.  Unlike a leader
+        (`_fetch`), it leaves every other queued ticket where it is: a
+        thread that keeps one batch in flight while it launches the
+        next must not wait for the next one's step when it reads the
+        first.  A ticket some leader has already claimed stays that
+        leader's; its `fetch` waits for it as ever."""
+        mine = set(map(id, tickets))
+        with self._lock:
+            own = [t for t in self._queue if id(t) in mine]
+            self._queue = [t for t in self._queue if id(t) not in mine]
+        classes: Dict[Tuple, List[Ticket]] = {}
+        for t in own:
+            classes.setdefault((t.handle.shape, t.handle.dtype), []).append(t)
+        groups = [
+            c[i : i + MAX_GROUP]
+            for c in classes.values()
+            for i in range(0, len(c), MAX_GROUP)
+        ]
+        try:
+            return groups, [self._stack_async(g) for g in groups]
+        except BaseException as e:  # noqa: BLE001
+            for t in own:  # off the queue: fail them closed
+                t.error = e
+                t.event.set()
+            raise
+
+    def land(self, started) -> None:
+        """Materialize what `start_own` started, and nothing else."""
+        groups, staged = started
+        self._materialize_windows(groups, staged)
+
     def _drain_oldest(self) -> None:
         with self._lock:
             group = self._take_group_locked(None)
@@ -233,15 +269,18 @@ class ReadbackCombiner:
     def _materialize(self, group: List[Ticket]) -> None:
         self._materialize_windows([group])
 
-    def _materialize_windows(self, groups: List[List[Ticket]]) -> None:
+    def _materialize_windows(
+        self, groups: List[List[Ticket]], staged: Optional[list] = None
+    ) -> None:
         """Stack every claimed window and start ALL their async device→
-        host copies first, then distribute in order: window N+1's
-        transfer overlaps window N's host-side slicing.  Any failure
-        fails every unfulfilled ticket of every claimed window (they
-        are already off the queue; conservative, matches the old
-        single-group contract)."""
+        host copies first (unless `start_own` already has: `staged`),
+        then distribute in order: window N+1's transfer overlaps window
+        N's host-side slicing.  Any failure fails every unfulfilled
+        ticket of every claimed window (they are already off the queue;
+        conservative, matches the old single-group contract)."""
         try:
-            staged = [self._stack_async(g) for g in groups]
+            if staged is None:
+                staged = [self._stack_async(g) for g in groups]
             for g, stacked in zip(groups, staged):
                 self._distribute(g, stacked)
         except BaseException as e:  # noqa: BLE001

@@ -46,6 +46,7 @@ lanes.
 from __future__ import annotations
 
 import ctypes
+import inspect
 import logging
 import os
 import threading
@@ -311,12 +312,20 @@ class H2FastFront:
                     NativeColumnarFeeder,
                 )
 
+                # An engine whose apply_columnar can hand back its
+                # batch still on the device lets the serve thread keep
+                # one window in flight (_feeder_window); one that cannot
+                # serves each window in its single entry, as ever.
+                self._engine_async = "want_async" in inspect.signature(
+                    instance.engine.apply_columnar
+                ).parameters
                 self.feeder = NativeColumnarFeeder(
                     disqualify_mask=svc.COLUMNAR_DISQUALIFIERS,
                     window_s=window_s,
                     flush_rows=flush_items,
                     hints=retry_hints_enabled(),
                     window_handler=self._feeder_window,
+                    window_complete=self._feeder_complete,
                     **_feeder_ring_params(),
                 )
                 lib.h2s_attach_feeder(self._handle, self.feeder.handle)
@@ -495,15 +504,22 @@ class H2FastFront:
             return None
         return self.instance.serve_decoded_local(dec)
 
-    # -- the per-window feeder entry (columnar_feeder.cpp) --------------
+    # -- the per-window feeder entries (columnar_feeder.cpp) ------------
 
     def _feeder_window(self, slot, n_rows, n_rpcs, key_bytes) -> int:
-        """Serve one sealed ring window: build a DecodedBatch of
+        """A sealed ring window's SUBMIT entry: build a DecodedBatch of
         ZERO-COPY views over the slot's C-resident columns (no decode,
-        no allocation — the C conn threads already packed them), run
-        the shared columnar serve, write the verdict lanes in place.
-        The feeder thread then encodes + scatters the responses in C.
+        no allocation — the C conn threads already packed them) and run
+        the shared columnar serve.  Where that hands back the batch
+        still on the device — launched, its readback started — the
+        window is IN_FLIGHT: the serve thread submits the next window,
+        if one is worth a dispatch, while this one's step runs, then calls
+        `_feeder_complete`.  Where it hands back finished columns (the
+        ledger route, the per-RPC re-serve below) the verdict lanes are
+        written here and the feeder thread encodes + scatters the
+        responses in C at once.
         """
+        from gubernator_tpu.core.native_plane import IN_FLIGHT
         from gubernator_tpu.net.wire_codec import DecodedBatch
 
         # Engine-domain "now" for the scatter's retry-hint encode:
@@ -526,14 +542,16 @@ class H2FastFront:
             fnv1a=slot.fnv1a[:n_rows],
             name_len=slot.name_lens[:n_rows],
         )
-        out = self.instance.serve_decoded_local(dec)
+        out = self.instance.serve_decoded_local(
+            dec, want_async=self._engine_async
+        )
+        if hasattr(out, "get"):
+            # The slot stays sealed until complete: the pending echoes
+            # its `limit` view.
+            slot.pending = out
+            return IN_FLIGHT
         if out is not None:
-            st, lim, rem, rst = out
-            slot.out_status[:n_rows] = st
-            slot.out_limit[:n_rows] = lim
-            slot.out_remaining[:n_rows] = rem
-            slot.out_reset[:n_rows] = rst
-            slot.rpc_status[:n_rpcs] = 0
+            self._write_verdicts(slot, n_rows, n_rpcs, out)
             return 0
         # The combined window declined (ownership, engine guards): one
         # RPC out of scope must not fail its window-mates — re-serve
@@ -572,6 +590,22 @@ class H2FastFront:
                 slot.out_reset[row0 : row0 + k] = rst
                 slot.rpc_status[r] = 0
         return 0
+
+    def _feeder_complete(self, slot, n_rows, n_rpcs, key_bytes) -> int:
+        """An in-flight window's COMPLETE entry: read its own answers
+        back and write the verdict lanes."""
+        pending, slot.pending = slot.pending, None
+        self._write_verdicts(slot, n_rows, n_rpcs, pending.get())
+        return 0
+
+    @staticmethod
+    def _write_verdicts(slot, n_rows, n_rpcs, out) -> None:
+        st, lim, rem, rst = out
+        slot.out_status[:n_rows] = st
+        slot.out_limit[:n_rows] = lim
+        slot.out_remaining[:n_rows] = rem
+        slot.out_reset[:n_rows] = rst
+        slot.rpc_status[:n_rpcs] = 0
 
     # -- event ring (core/native/event_ring.cpp) ------------------------
 
@@ -693,8 +727,10 @@ class H2FastFront:
         answered with a grpc status (of them `declined_rpcs`
         UNIMPLEMENTED: out of the columnar path's scope), `windows` and
         `items` entered into Python (byte windows + feeder windows;
-        the decision plane's RPCs enter none), `ring_dropped` events
-        the ring was too full to take."""
+        the decision plane's RPCs enter none), `windows_overlapped`
+        feeder windows submitted while the one before was still in
+        flight on the device, `ring_dropped` events the ring was too
+        full to take."""
         # One hold of _teardown_mu over every FFI read: close() frees
         # the feeder and the ring only after it has taken the handle
         # away under the same lock.
@@ -711,6 +747,7 @@ class H2FastFront:
             "windows": st["windows"] + st.get("feeder_windows", 0),
             "items": st["window_items"] + st.get("feeder_served_rows", 0),
             "feeder_rpcs": st["feeder_front_rpcs"],
+            "windows_overlapped": st.get("feeder_windows_overlapped", 0),
             "feeder_ring_full": st.get("feeder_ring_full", 0),
             "feeder_declined": st.get("feeder_declined", 0),
             "plane_rpcs": st["native_rpcs"],

@@ -1038,13 +1038,20 @@ class V1Instance:
                 duration=dec.duration,
             )
 
-    def serve_decoded_local(self, dec):
+    def serve_decoded_local(self, dec, want_async: bool = False):
         """Shared post-decode columnar serve for the native fronts —
         the h2 fast front's byte windows AND the columnar feeder's
         ring windows both land here, so the ownership gate, hot-key
         accounting, and ledger semantics cannot drift between them.
         Returns (status, limit, remaining, reset) columns, or None to
         decline (caller answers UNIMPLEMENTED / falls to the pb path).
+
+        With `want_async` the engine's answer may come back still on
+        the device: a `PendingColumnar`, launched and copying, whose
+        `.get()` gives the same columns — the feeder's serve thread
+        launches its next window before it asks.  The ledger route
+        answers with finished columns either way (it learns from them
+        before it returns).
         """
         engine = self.engine
         # Same engine guards as serve_wire_bytes: a write-through
@@ -1065,15 +1072,16 @@ class V1Instance:
         from gubernator_tpu.core.engine import PackedKeys
 
         packed = PackedKeys(dec.key_buf, dec.key_offsets, dec.n)
+        kw = {}
         if hasattr(engine, "tables"):
-            return engine.apply_columnar(
-                packed, dec.algo, dec.behavior, dec.hits, dec.limit,
-                dec.duration, dec.burst, route_hashes=dec.fnv1a,
-            )
-        return engine.apply_columnar(
+            kw["route_hashes"] = dec.fnv1a
+        if want_async:
+            kw["want_async"] = True
+        out = engine.apply_columnar(
             packed, dec.algo, dec.behavior, dec.hits, dec.limit,
-            dec.duration, dec.burst,
+            dec.duration, dec.burst, **kw,
         )
+        return out.start_readback() if want_async else out
 
     def _serve_decoded_ledger(self, dec):
         """Ledger-aware columnar serve for the native fronts: hot-key

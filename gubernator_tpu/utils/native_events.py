@@ -36,7 +36,7 @@ log = logging.getLogger("gubernator_tpu.native_events")
 
 # kind -> stage name (h2_server.cpp kEvNativeServe/kEvWindowWait/
 # kEvWindowServe/kEvRpcTotal; columnar_feeder.cpp kEvFeederPack/
-# kEvFeederRingWait/kEvFeederServe/kEvFeederScatter).
+# kEvFeederRingWait/kEvFeederServe/kEvFeederScatter/kEvFeederInflight).
 STAGES = {
     1: "native_serve",
     2: "window_wait",
@@ -44,7 +44,8 @@ STAGES = {
     # Columnar feeder plane: per-RPC wire→columns pack (conn thread),
     # pack → window-callback queue wait (the feeder's analog of
     # window_wait — the stage the §23 p99 tail lived in), and the
-    # per-window columnar serve wall.
+    # per-window columnar serve wall: one event a window, the sum of
+    # its submit and complete entries' walls.
     4: "feeder_pack",
     5: "feeder_ring_wait",
     6: "feeder_serve",
@@ -60,11 +61,17 @@ STAGES = {
     # handed to the connection's write path (items = the RPC's items).
     # On the feeder path it is tiled by feeder_ring_wait (which starts
     # at the same instant and so contains feeder_pack), the window's
-    # feeder_serve and the RPC's place in feeder_scatter.
+    # feeder_serve, its feeder_inflight where it had one, and the
+    # RPC's place in feeder_scatter.
     10: "rpc_total",
     # Per feeder window: the C response encode + scatter of all its
     # RPCs, after the columnar callback returned (items = its RPCs).
     11: "feeder_scatter",
+    # Per feeder window whose submit left it in flight: its submit's
+    # return → its complete's entry — the next window's submit where
+    # rows were waiting, two clock readings where none were (items =
+    # its rows).  None for a window answered with finished columns.
+    12: "feeder_inflight",
 }
 
 # Span stubs recorded per drain tick, bounded: under a 9k/s native

@@ -404,6 +404,290 @@ def test_flush_observes_late_claims_row_conservation():
         feeder.close()
 
 
+# -- the serve thread's depth of two (submit / complete) -----------------
+
+
+def _body8(tag, n=8):
+    return _payload(
+        [dict(name="ov", unique_key=f"{tag}{i}xyz", hits=1, limit=9,
+              duration=1000) for i in range(n)]
+    )
+
+
+class _Pair:
+    """A stub handler pair: submit leaves every window IN_FLIGHT (or
+    raises / answers at once where told to), complete writes the
+    verdict lanes; both keep a log of (entry, rows' first hash, time).
+    `gate`, where set, holds the FIRST submit inside Python until the
+    test has packed the next window's rows."""
+
+    def __init__(self, gate=None, submit_raises=(), complete_raises=()):
+        self.log, self.gate = [], gate
+        self.entered = threading.Event()
+        self.submit_raises = set(submit_raises)
+        self.complete_raises = set(complete_raises)
+        self.served_rows = 0
+
+    def _nth(self, entry):
+        return sum(1 for e in self.log if e[0] == entry)
+
+    def submit(self, slot, n_rows, n_rpcs, key_bytes):
+        from gubernator_tpu.core.native_plane import IN_FLIGHT
+
+        nth = self._nth("submit")
+        self.log.append(("submit", int(slot.fnv1a[0]), time.monotonic()))
+        if nth == 0 and self.gate is not None:
+            self.entered.set()
+            self.gate.wait(timeout=10)
+        if nth in self.submit_raises:
+            raise RuntimeError("submit stub")
+        slot.pending = n_rows
+        return IN_FLIGHT
+
+    def complete(self, slot, n_rows, n_rpcs, key_bytes):
+        nth = self._nth("complete")
+        self.log.append(("complete", int(slot.fnv1a[0]), time.monotonic()))
+        assert slot.pending == n_rows  # this window's own, nobody else's
+        slot.pending = None
+        if nth in self.complete_raises:
+            raise RuntimeError("complete stub")
+        self.served_rows += n_rows
+        slot.out_status[:n_rows] = 0
+        slot.out_limit[:n_rows] = 9
+        slot.out_remaining[:n_rows] = 8
+        slot.out_reset[:n_rows] = 0
+        slot.rpc_status[:n_rpcs] = 0
+        return 0
+
+    def feeder(self, **kw):
+        from gubernator_tpu.core.native_plane import NativeColumnarFeeder
+
+        kw = dict(dict(n_slots=4, max_rows=64, flush_rows=8, window_s=0.5), **kw)
+        return NativeColumnarFeeder(
+            window_handler=self.submit, window_complete=self.complete, **kw)
+
+    def entries(self):
+        first = {}
+        for _entry, key, _t in self.log:
+            first.setdefault(key, len(first) + 1)
+        return [(entry, first[key]) for entry, key, _t in self.log]
+
+
+def test_a_window_worth_a_dispatch_is_submitted_before_the_read():
+    """Window 2 holds an eighth of flush_rows when window 1's submit
+    returns: the serve thread submits 2 without its group-commit wait
+    (its intern, pack and launch run while 1's step runs), only then
+    completes and scatters 1, and counts the overlap; 2, with nothing
+    waiting behind it, is completed at once."""
+    pair = _Pair(gate=threading.Event())
+    feeder = pair.feeder(flush_rows=32, window_s=5.0)
+    try:
+        for tag in "abcd":
+            assert feeder.pack(_body8(tag)) == 8  # the fourth seals window 1
+        assert pair.entered.wait(timeout=10)
+        assert feeder.pack(_body8("e", 4)) == 4  # 4 of 32 rows: unsealed
+        pair.gate.set()
+        deadline = time.monotonic() + 5.0
+        while len(pair.log) < 4 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert pair.entries() == [
+            ("submit", 1), ("submit", 2), ("complete", 1), ("complete", 2)]
+        st = feeder.stats()
+        assert st["feeder_windows_overlapped"] == 1
+        assert st["feeder_windows"] == 2
+        assert st["feeder_served_rows"] == st["feeder_rows"] == 36
+    finally:
+        feeder.close()
+
+
+def test_a_thin_window_keeps_its_group_commit_wait():
+    """Window 2 holds less than an eighth of flush_rows when window 1's
+    submit returns — a herd's straggler: submitted now it would split
+    what the group-commit wait gathers into one dispatch, so 1 is
+    completed at once and 2 is left to the idle path (here: a flush)."""
+    pair = _Pair(gate=threading.Event())
+    feeder = pair.feeder(flush_rows=32, window_s=5.0)
+    try:
+        for tag in "abcd":
+            assert feeder.pack(_body8(tag)) == 8
+        assert pair.entered.wait(timeout=10)
+        assert feeder.pack(_body8("e", 3)) == 3  # 3 of 32 rows: thin
+        pair.gate.set()
+        deadline = time.monotonic() + 5.0
+        while len(pair.log) < 2 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        time.sleep(0.05)
+        assert pair.entries() == [("submit", 1), ("complete", 1)]
+        feeder.flush()
+        assert pair.entries() == [
+            ("submit", 1), ("complete", 1), ("submit", 2), ("complete", 2)]
+        st = feeder.stats()
+        assert st["feeder_windows_overlapped"] == 0
+        assert st["feeder_served_rows"] == st["feeder_rows"] == 35
+    finally:
+        feeder.close()
+
+
+def test_nothing_waiting_is_answered_at_once():
+    """No other window holds rows: complete follows submit with no
+    sleep on the condvar or the window timer (0.5 s here) — an answer
+    the device has finished is never held for arrivals."""
+    pair = _Pair()
+    feeder = pair.feeder()
+    try:
+        for tag in "abc":
+            assert feeder.pack(_body8(tag)) == 8
+            feeder.flush()
+        assert pair.entries() == [
+            ("submit", 1), ("complete", 1), ("submit", 2), ("complete", 2),
+            ("submit", 3), ("complete", 3)]
+        for sub, com in zip(pair.log[0::2], pair.log[1::2]):
+            assert com[2] - sub[2] < 0.25  # half the window timer
+        assert feeder.stats()["feeder_windows_overlapped"] == 0
+    finally:
+        feeder.close()
+
+
+def test_flush_with_a_window_in_flight_conserves_rows():
+    """Packers and flushers race the overlapped loop: at quiesce every
+    packed row has been submitted once and completed once, in ring
+    order, and nothing is left sealed."""
+    pair = _Pair()
+    feeder = pair.feeder(window_s=0.0005)
+    try:
+        body = _body8("f")
+        packed = [0] * 4
+        stop = threading.Event()
+
+        def packer(t):
+            for _ in range(150):
+                rc = feeder.pack(body)
+                if rc > 0:
+                    packed[t] += rc
+
+        def flusher():
+            while not stop.is_set():
+                feeder.flush()
+
+        ts = [threading.Thread(target=packer, args=(t,)) for t in range(4)]
+        fs = [threading.Thread(target=flusher) for _ in range(2)]
+        for t in ts + fs:
+            t.start()
+        for t in ts:
+            t.join()
+        stop.set()
+        for t in fs:
+            t.join()
+        feeder.flush()
+        st, total = feeder.stats(), sum(packed)
+        assert total > 0 and st["feeder_rows"] == total
+        assert st["feeder_served_rows"] == total == pair.served_rows, st
+        assert st["feeder_open_rows"] == 0
+        submits = [e for e in pair.log if e[0] == "submit"]
+        assert len(submits) == st["feeder_windows"] == len(pair.log) / 2
+        assert st["feeder_windows_overlapped"] < len(submits)
+        # at most one window in flight: a submit is followed by its own
+        # complete, or by one more submit and then the older complete
+        depth = 0
+        for entry, _key, _t in pair.log:
+            depth += 1 if entry == "submit" else -1
+            assert 0 <= depth <= 2
+    finally:
+        feeder.close()
+
+
+def test_stop_completes_the_window_in_flight_first():
+    """stop() while window 1 is in flight and window 2 holds rows: 1
+    is completed (its step has run; its callers are owed the answers),
+    2 is drained without entering Python, and every slot is released."""
+    pair = _Pair(gate=threading.Event())
+    feeder = pair.feeder()
+    try:
+        assert feeder.pack(_body8("a")) == 8
+        assert pair.entered.wait(timeout=10)
+        assert feeder.pack(_body8("b")) == 8
+        stopper = threading.Thread(target=feeder.stop)
+        stopper.start()
+        time.sleep(0.05)  # cf_stop has set `closing` and waits to join
+        pair.gate.set()
+        stopper.join(timeout=10)
+        assert not stopper.is_alive()
+        assert pair.entries() == [("submit", 1), ("complete", 1)]
+        st = feeder.stats()
+        assert st["feeder_windows"] == 2  # one completed, one drained
+        assert st["feeder_served_rows"] == 8 and st["feeder_rows"] == 16
+        assert st["feeder_open_rows"] == 0
+        assert all(s.pending is None for s in feeder.slots)
+    finally:
+        feeder.close()
+
+
+def test_an_entry_that_raises_fails_its_own_window_only():
+    """The second window's submit raises, the fourth's complete raises:
+    each fails its window (INTERNAL for its RPCs) and the loop serves
+    the next one as if nothing had happened."""
+    from gubernator_tpu.utils.metrics import swallowed_counts
+
+    before = swallowed_counts()
+    pair = _Pair(submit_raises={1}, complete_raises={2})
+    feeder = pair.feeder()
+    try:
+        for tag in "abcde":
+            assert feeder.pack(_body8(tag)) == 8
+            feeder.flush()
+        assert pair.entries() == [
+            ("submit", 1), ("complete", 1), ("submit", 2),
+            ("submit", 3), ("complete", 3), ("submit", 4), ("complete", 4),
+            ("submit", 5), ("complete", 5)]
+        st, after = feeder.stats(), swallowed_counts()
+        assert st["feeder_windows"] == 5 and pair.served_rows == 24
+        assert after.get("feeder.window", 0) - before.get("feeder.window", 0) == 1
+        assert after.get("feeder.complete", 0) - before.get("feeder.complete", 0) == 1
+    finally:
+        feeder.close()
+
+
+def test_a_failed_window_answers_internal_and_the_front_serves_on():
+    """Through the front: a serve that raises at submit, then one whose
+    pending raises at complete, each answer INTERNAL for that RPC
+    alone; the RPCs before and after are answered as ever."""
+    import grpc
+
+    d = _spawn_fast_daemon(ledger=False)
+    try:
+        ch, call = _fast_call(d)
+        payload = _payload(
+            [dict(name="boom", unique_key="k1end", hits=1, limit=9,
+                  duration=60_000)]
+        )
+        real = d.instance.serve_decoded_local
+
+        class Boom:
+            def get(self):
+                raise RuntimeError("complete stub")
+
+        def raises(dec, want_async=False):
+            raise RuntimeError("submit stub")
+
+        def pending_raises(dec, want_async=False):
+            real(dec, want_async).get()  # the hit is applied all the same
+            return Boom()
+
+        assert pb.GetRateLimitsResp.FromString(call(payload)).responses[0].remaining == 8
+        for stub in (raises, pending_raises):
+            d.instance.serve_decoded_local = stub
+            with pytest.raises(grpc.RpcError) as err:
+                call(payload)
+            assert err.value.code() == grpc.StatusCode.INTERNAL
+        d.instance.serve_decoded_local = real
+        assert pb.GetRateLimitsResp.FromString(call(payload)).responses[0].remaining == 6
+        st = d.h2_fast.stats()
+        assert st["errors"] == 2 and st["feeder_window_errors"] == 2
+        ch.close()
+    finally:
+        d.close()
+
+
 def test_concurrent_pack_parity():
     """Many Python threads pack concurrently; every packed row must
     appear exactly once across the captured windows (claim/commit

@@ -31,7 +31,7 @@ REPO = Path(__file__).resolve().parents[1]
 # run (fork from a TSan'd multithreaded process deadlocks), so the
 # unpreloaded pytest parent is the one that spawns the client hammer.
 _SERVER_SRC = r"""
-import ctypes, os, sys
+import ctypes, os, sys, time
 import numpy as np
 
 from gubernator_tpu.net import h2_fast
@@ -68,12 +68,23 @@ assert handle, "h2 server failed to bind"
 
 # Columnar feeder attached: the hammer's fall-through RPCs now run
 # the REAL integrated path — conn threads cf_pack into the ring, the
-# feeder serve thread enters this columnar handler, and the scatter
-# rides h2s_feeder_respond back through the connections — all under
-# TSan.  Windows are tiny (flush_rows=8) so seal/rotate churns.
+# feeder serve thread enters this columnar handler pair, and the
+# scatter rides h2s_feeder_respond back through the connections — all
+# under TSan.  Windows are tiny (flush_rows=8) so seal/rotate churns,
+# and every submit leaves its window in flight, so the serve thread
+# submits window k+1 before it completes and scatters window k
+# whenever rows are waiting: the tokens of two windows are live at
+# once, and every one of them is answered or released.
 from gubernator_tpu.core import native_plane
 
-def feeder_window(slot, n_rows, n_rpcs, key_bytes):
+def feeder_submit(slot, n_rows, n_rpcs, key_bytes):
+    time.sleep(0.001)  # an intern + pack's worth: the next window fills
+    slot.pending = n_rows
+    return native_plane.IN_FLIGHT
+
+def feeder_complete(slot, n_rows, n_rpcs, key_bytes):
+    assert slot.pending == n_rows
+    slot.pending = None
     slot.out_status[:n_rows] = 0
     slot.out_limit[:n_rows] = 100
     slot.out_remaining[:n_rows] = 99
@@ -83,7 +94,8 @@ def feeder_window(slot, n_rows, n_rpcs, key_bytes):
 
 feeder = native_plane.NativeColumnarFeeder(
     n_slots=3, max_rows=256, max_rpcs=64, flush_rows=8,
-    window_s=0.0005, window_handler=feeder_window,
+    window_s=0.0005, window_handler=feeder_submit,
+    window_complete=feeder_complete,
 )
 lib.h2s_attach_feeder(handle, feeder.handle)
 
@@ -99,11 +111,15 @@ lib.h2s_stats(handle, stats.ctypes.data_as(ctypes.c_void_p))
 # feeder, stop the server, then free the ring.
 lib.h2s_attach_feeder(handle, None)
 feeder.stop()
+fst = feeder.stats()
 lib.h2s_stop(handle)
 feeder.close()
 assert stats[5] > 0, "hammer never exercised the feeder path"
-print("san stress ok rpcs=%d windows=%d feeder_rpcs=%d"
-      % (stats[0], stats[1], stats[5]), flush=True)
+assert fst["feeder_windows_overlapped"] > 0, fst
+assert fst["feeder_served_rows"] == fst["feeder_rows"], fst
+print("san stress ok rpcs=%d windows=%d feeder_rpcs=%d overlapped=%d"
+      % (stats[0], stats[1], stats[5], fst["feeder_windows_overlapped"]),
+      flush=True)
 """
 
 _CLIENT_SRC = r"""
@@ -304,9 +320,12 @@ print("plane san stress ok admitted=%d" % total, flush=True)
 # Columnar feeder stress, PRELOADED: C bench threads (true
 # multi-producer claim/commit against the lock-free window cursor)
 # race the serve thread's seal/rotate/recycle AND a Python window
-# callback writing verdict lanes, then a mid-traffic flush and a
-# drain-then-close teardown.  Row conservation is asserted: every
-# packed row is either served or drained, never lost or duplicated.
+# callback pair — submit leaves every window in flight, complete
+# writes the verdict lanes, so the serve thread keeps one window in
+# flight while it submits the next — then mid-traffic flushes and a
+# stop that lands on a window in flight.  Row conservation is
+# asserted: every packed row is either served or drained, never lost
+# or duplicated.
 _FEEDER_SRC = r"""
 import threading
 import numpy as np
@@ -330,7 +349,20 @@ for i in range(4):
 body = items
 
 served = [0]
-def handler(slot, n_rows, n_rpcs, key_bytes):
+in_flight = [0]
+hold = threading.Event()
+hold.set()
+def submit(slot, n_rows, n_rpcs, key_bytes):
+    hold.wait(timeout=0.3)
+    in_flight[0] += 1
+    assert in_flight[0] <= 2, in_flight
+    slot.pending = n_rows
+    return native_plane.IN_FLIGHT
+
+def complete(slot, n_rows, n_rpcs, key_bytes):
+    assert slot.pending == n_rows
+    slot.pending = None
+    in_flight[0] -= 1
     served[0] += n_rows
     slot.out_status[:n_rows] = 0
     slot.out_limit[:n_rows] = 100
@@ -341,7 +373,7 @@ def handler(slot, n_rows, n_rpcs, key_bytes):
 
 feeder = native_plane.NativeColumnarFeeder(
     n_slots=3, max_rows=256, max_rpcs=64, flush_rows=64,
-    window_s=0.0005, window_handler=handler,
+    window_s=0.0005, window_handler=submit, window_complete=complete,
 )
 # Phase 1: C-threaded multi-producer hammer (true parallel claims).
 packed = feeder.bench_pack(body, 4, 1500, 4)
@@ -366,6 +398,26 @@ assert served[0] == st["feeder_served_rows"]
 # served_rows excludes sink-mode/drain windows; everything packed must
 # be accounted as served once callbacks were attached the whole run.
 assert st["feeder_served_rows"] == total, (st, total)
+assert st["feeder_windows_overlapped"] > 0, st
+# Phase 3: stop lands on a window in flight.  The first submit is held
+# inside Python (0.3 s) while a second window fills and this thread's
+# cf_stop sets `closing`; it then leaves its window in flight — the
+# drain completes that one (its rows count as served) and answers the
+# other without entering Python.  (No helper thread: one created after
+# the lanes exited frees their cached stacks' TLS inside uninstrumented
+# glibc, which TSan reports as a race with their destructors.)
+hold.clear()
+for _ in range(16):
+    assert feeder.pack(body) == 4   # 64 rows: window A seals, is submitted
+deadline = 200
+while feeder.stats()["feeder_open_rows"] and deadline:
+    deadline -= 1; threading.Event().wait(0.005)   # the loop has rotated past A
+assert feeder.pack(body) == 4       # window B holds rows
+feeder.stop()
+st = feeder.stats()
+assert st["feeder_rows"] == total + 68, (st, total)
+assert st["feeder_served_rows"] == total + 64 == served[0], (st, total, served)
+assert in_flight[0] == 0 and st["feeder_open_rows"] == 0
 feeder.close()
 print("feeder san stress ok rows=%d" % total, flush=True)
 """
