@@ -22,8 +22,8 @@
 // columnar callback pair, with ZERO-COPY numpy views over the ring
 // slot — no bytes cross the boundary at all, in either direction:
 // verdict columns are written back in place and the feeder thread
-// encodes + scatters the per-RPC responses through the C connection
-// plane.
+// encodes the per-RPC responses and queues them on their connections,
+// whose reactors write them (on the event plane).
 //
 // The serve is double-buffered too (depth two, no setting): a window's
 // Python entry is SUBMIT (everything up to and including the device
@@ -109,6 +109,7 @@ extern "C" void h2s_feeder_respond(void* conn_token, int64_t stream,
                                    const uint8_t* payload, int64_t len,
                                    int32_t grpc_status);
 extern "C" void h2s_feeder_release(void* conn_token);
+extern "C" void h2s_kick_posted();
 // From event_ring.cpp (same .so).
 extern "C" int64_t evr_record(void* handle, int64_t kind, int64_t t_end_ns,
                               int64_t dur_ns, int64_t items);
@@ -120,7 +121,7 @@ constexpr int64_t kEvFeederPack = 4;      // conn thread: decode+pack
 constexpr int64_t kEvFeederRingWait = 5;  // pack → window callback
 constexpr int64_t kEvFeederServe = 6;     // per window: submit + complete walls
 // 10 is the h2 front's per-RPC total (h2_server.cpp).
-constexpr int64_t kEvFeederScatter = 11;  // per window: encode + scatter
+constexpr int64_t kEvFeederScatter = 11;  // per window: encode + queue + kicks
 constexpr int64_t kEvFeederInflight = 12;  // submit's return → complete's entry
 
 // A submit's return value for "launched, answers still on the device":
@@ -221,6 +222,9 @@ struct Feeder {
   // h2 server's counters).
   std::atomic<int64_t> packed_rpcs{0}, packed_rows{0}, windows{0};
   std::atomic<int64_t> served_rows{0}, ring_full{0}, declined{0};
+  // Rows of windows answered without entering Python: the stop's
+  // drain (and sink mode).  After cf_stop, served + drained = packed.
+  std::atomic<int64_t> drained_rows{0};
   std::atomic<int64_t> window_errors{0};
   // Windows whose submit ran while another window was in flight.
   std::atomic<int64_t> windows_overlapped{0};
@@ -301,9 +305,10 @@ void copy_into(CfWindow& w, PackScratch& s, int64_t row0, int64_t byte0,
   w.rpc_enq_ns[rpc_idx] = t_enq_ns;
 }
 
-// Encode + send every RPC of a served window from its verdict
-// columns, honoring the per-RPC status lane.  rc != 0 fails the whole
-// window (callback crash / sink teardown).
+// Encode + queue every RPC of a served window from its verdict
+// columns, honoring the per-RPC status lane, then wake the reactors
+// that write them (one kick each).  rc != 0 fails the whole window
+// (callback crash / sink teardown).
 void scatter_window(Feeder* f, CfWindow& w, uint64_t sealed, int64_t rc) {
   const int64_t n_rpcs = static_cast<int64_t>(cur_rpcs(sealed));
   const int64_t hints = f->hints.load();
@@ -351,6 +356,7 @@ void scatter_window(Feeder* f, CfWindow& w, uint64_t sealed, int64_t rc) {
     }
     h2s_feeder_respond(token, w.rpc_stream[r], enc.data(), len, 0);
   }
+  h2s_kick_posted();
 }
 
 // A sealed window between its submit and its scatter.  Lives on the
@@ -441,6 +447,8 @@ void finish_window(Feeder* f, Served* s) {
     // One serve event a window: the sum of its entries' walls.
     if (ring) evr_record(ring, kEvFeederServe, evr_now_ns(), s->serve_ns, rows);
     f->served_rows.fetch_add(rows);
+  } else {
+    f->drained_rows.fetch_add(rows);
   }
   f->windows.fetch_add(1);
   const int64_t t_sc = ring ? evr_now_ns() : 0;
@@ -798,31 +806,32 @@ void cf_flush(void* handle) {
   }
 }
 
-// out14: packed_rpcs, packed_rows, windows, served_rows, ring_full,
+// out15: packed_rpcs, packed_rows, windows, served_rows, ring_full,
 // declined, window_errors, open_idx, open_rows, n_slots, max_rows,
-// key_cap, max_rpcs, windows_overlapped (callers may pass a larger
-// zeroed buffer).  The
+// key_cap, max_rpcs, windows_overlapped, drained_rows (callers may
+// pass a larger zeroed buffer).  The
 // clamped shapes are exported so the Python view layer maps EXACTLY
 // the allocated capacities (a caller-supplied max_rpcs above the
 // cursor field width is clamped here, and a view sized off the raw
 // argument would extend past the C allocation).
-void cf_stats(void* handle, int64_t* out14) {
+void cf_stats(void* handle, int64_t* out15) {
   auto* f = static_cast<Feeder*>(handle);
-  out14[0] = f->packed_rpcs.load();
-  out14[1] = f->packed_rows.load();
-  out14[2] = f->windows.load();
-  out14[3] = f->served_rows.load();
-  out14[4] = f->ring_full.load();
-  out14[5] = f->declined.load();
-  out14[6] = f->window_errors.load();
+  out15[0] = f->packed_rpcs.load();
+  out15[1] = f->packed_rows.load();
+  out15[2] = f->windows.load();
+  out15[3] = f->served_rows.load();
+  out15[4] = f->ring_full.load();
+  out15[5] = f->declined.load();
+  out15[6] = f->window_errors.load();
   const int64_t open = f->open.load();
-  out14[7] = open;
-  out14[8] = static_cast<int64_t>(cur_rows(f->slots[open].cursor.load()));
-  out14[9] = f->n_slots;
-  out14[10] = f->max_rows;
-  out14[11] = f->key_cap;
-  out14[12] = f->max_rpcs;
-  out14[13] = f->windows_overlapped.load();
+  out15[7] = open;
+  out15[8] = static_cast<int64_t>(cur_rows(f->slots[open].cursor.load()));
+  out15[9] = f->n_slots;
+  out15[10] = f->max_rows;
+  out15[11] = f->key_cap;
+  out15[12] = f->max_rpcs;
+  out15[13] = f->windows_overlapped.load();
+  out15[14] = f->drained_rows.load();
 }
 
 // Stop the serve thread (completes the window in flight, then drains

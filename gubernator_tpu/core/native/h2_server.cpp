@@ -241,10 +241,29 @@ struct PendingRpc {
 
 struct Reactor;
 
+// The reactor whose loop runs on this thread (nullptr on every other
+// thread): a connection's own reactor writes its socket itself, any
+// other sender hands the write to it (Conn::flush_locked).
+thread_local Reactor* tls_reactor = nullptr;
+
 // Hand a write-side-killed event-plane conn back to its reactor (a
 // parked peer generates no epoll event, so nothing else would ever
 // reap it).  Defined after Reactor.
 void notify_conn_dead(Conn* c);
+// Put a conn with queued bytes on its reactor's posted list; the
+// reactor is woken by the sender's kick_posted(), once per reactor at
+// the end of a window.  Defined after Reactor.
+void post_conn(Conn* c);
+void kick_posted();
+
+// What a response handed to a reactor owes the front's counters
+// (count_response), settled where its bytes reach writev.
+struct Owed {
+  int status;
+  int64_t t0_ns;
+  int64_t items;
+  bool feeder;  // a columnar-feeder RPC: also `feeder_rpcs`
+};
 
 struct Server {
   // guberlint: guard queue, queued_items by q_mu
@@ -296,6 +315,9 @@ struct Server {
   std::atomic<int64_t> window_items{0};  // items of the byte windows
   std::atomic<int64_t> native_rpcs{0}, native_items{0};
   std::atomic<int64_t> feeder_rpcs{0}, feeder_items{0};
+  // Of `rpcs`, the responses a thread other than their connection's
+  // reactor queued and that reactor wrote (Conn::flush_locked).
+  std::atomic<int64_t> responses_handed_off{0};
   std::atomic<int64_t> conns_open{0}, idle_reaped{0};
   // Threaded plane only: connection threads are DETACHED (a long-
   // lived daemon must not accumulate unjoined thread handles across
@@ -336,6 +358,7 @@ struct ReadState {
 struct Conn : std::enable_shared_from_this<Conn> {
   // guberlint: guard conn_send_window, initial_stream_window, blocked, early_credits by write_mu
   // guberlint: guard outq, outq_off, outq_bytes, want_out by write_mu
+  // guberlint: guard owed, awaiting_reactor by write_mu
   int fd;
   // Event plane: the owning reactor's epoll fd (−1 = threaded plane).
   // Set once before the fd is published to the reactor; read by the
@@ -364,6 +387,11 @@ struct Conn : std::enable_shared_from_this<Conn> {
   size_t outq_off = 0;    // bytes of outq.front() already written
   size_t outq_bytes = 0;  // total queued (backpressure cap)
   bool want_out = false;  // EPOLLOUT armed
+  // Hand-off: another thread queued bytes here for the reactor to
+  // write; `awaiting_reactor` while the conn is on the reactor's
+  // posted list, `owed` the counts of the responses among them.
+  bool awaiting_reactor = false;
+  std::vector<Owed> owed;
   // WINDOW_UPDATE credit that arrived BEFORE the stream's response was
   // queued (the client may grant window while the request is still in
   // the dispatch queue) — it must not be dropped or the response can
@@ -474,20 +502,35 @@ struct Conn : std::enable_shared_from_this<Conn> {
     return true;
   }
 
+  // True where the calling thread must not write this conn's socket:
+  // the event plane, and not the conn's own reactor.
+  bool handing_off() const { return epfd >= 0 && tls_reactor != rx; }
+
+  // Write what is queued.  The conn's own reactor writes through;
+  // any other thread (the feeder's serve thread, the byte-window
+  // dispatch thread) posts the conn to that reactor once and leaves
+  // the writev to it — one thread writing a window's hundred sockets
+  // in a row was the herd's largest cost (PERF.md §5).
+  bool flush_locked() {  // guberlint: holds write_mu
+    if (!handing_off()) return flush_out_locked();
+    if (!awaiting_reactor) {
+      awaiting_reactor = true;
+      post_conn(this);
+    }
+    return true;
+  }
+
   // By value: rvalue call sites (framed temporaries — the common
   // case) MOVE into the egress queue instead of deep-copying every
   // response's wire bytes per send.
   bool send_locked(std::string buf) {  // guberlint: holds write_mu
-    return queue_locked(std::move(buf)) &&
-           (epfd < 0 || flush_out_locked());
+    return queue_locked(std::move(buf)) && (epfd < 0 || flush_locked());
   }
 
   // Accept wire bytes WITHOUT a syscall of their own on the event
   // plane: the caller flushes once it has queued everything it has (a
-  // response is HEADERS + DATA + trailers — three frames, one writev;
-  // on the chip's host a write costs ~40 us, and a herd window
-  // scatters a hundred responses from one thread, PERF.md §5).  The
-  // threaded plane writes through, as before.
+  // response is HEADERS + DATA + trailers — three frames, one
+  // writev).  The threaded plane writes through, as before.
   bool queue_locked(std::string buf) {  // guberlint: holds write_mu
     if (epfd < 0) return send_blocking_locked(buf);
     if (outq_bytes + buf.size() > kMaxOutBytes) {
@@ -556,20 +599,19 @@ struct Conn : std::enable_shared_from_this<Conn> {
     }
     // One flush for whatever was queued here (and by the caller before
     // it: a response's HEADERS).
-    if (epfd >= 0 && !dead.load()) flush_out_locked();
+    if (epfd >= 0 && !dead.load()) flush_locked();
   }
 
   // Full response path: HEADERS immediately (not flow-controlled),
   // DATA+trailers through the window-aware queue.
-  bool send_response(uint32_t stream, const std::string& hdr,
-                     std::string data, const std::string& trailers) {
-    std::lock_guard<std::mutex> lock(write_mu);
-    if (!queue_locked(hdr)) return false;  // flushed by pump_locked
+  bool send_response_locked(uint32_t stream, std::string hdr,
+                            std::string data, std::string trailers) {  // guberlint: holds write_mu
+    if (!queue_locked(std::move(hdr))) return false;  // flushed by pump_locked
     PendingSend p;
     p.stream = stream;
     p.data = std::move(data);
     p.stream_window = initial_stream_window + take_early_credit(stream);
-    p.trailers = trailers;
+    p.trailers = std::move(trailers);
     blocked.push_back(std::move(p));
     pump_locked();
     return !dead.load();
@@ -689,11 +731,39 @@ std::string build_data_payload(const int64_t* cols, int64_t offset,
   return data;
 }
 
+constexpr int kGrpcUnimplemented = 12;
+
+// One response on its connection's write path: the front's RPC
+// counters (successes only into `rpcs`, and a feeder RPC's into
+// `feeder_rpcs` beside it; failures only into `errors`)
+// and the rpc_total event, on every path alike — settled where the
+// response's bytes reach writev (the sender's, or the reactor's for a
+// response handed to it).
+// guberlint: gil-free
+void count_response(Server* srv, const Owed& o) {
+  if (o.status == 0) {
+    if (o.feeder) srv->feeder_rpcs.fetch_add(1);
+    srv->rpcs.fetch_add(1);
+  } else {
+    srv->errors.fetch_add(1);
+    if (o.status == kGrpcUnimplemented) srv->unimplemented.fetch_add(1);
+  }
+  void* ring = srv->ring.load();
+  if (ring && o.t0_ns) {
+    const int64_t t1 = evr_now_ns();
+    evr_record(ring, kEvRpcTotal, t1, t1 - o.t0_ns, o.items);
+  }
+}
+
 // One RPC's full response from a pre-built grpc-framed DATA payload:
 // HEADERS immediately, then DATA under the peer's send-side
-// flow-control windows, trailers after the DATA.
-void send_rpc_payload(const std::shared_ptr<Conn>& conn, uint32_t stream,
-                      std::string data, int grpc_status) {
+// flow-control windows, trailers after the DATA.  `owed`, where given,
+// is counted here once this thread has written the response, or by
+// the reactor it was handed to (Conn::flush_locked), in the same lock
+// hold that queued it.
+void send_rpc_payload(Server* srv, const std::shared_ptr<Conn>& conn,
+                      uint32_t stream, std::string data, int grpc_status,
+                      const Owed* owed = nullptr) {
   static const std::string kHdr = resp_headers_block();
   std::string hdr;
   frame_header(hdr, static_cast<uint32_t>(kHdr.size()), kHeaders,
@@ -704,23 +774,32 @@ void send_rpc_payload(const std::shared_ptr<Conn>& conn, uint32_t stream,
   frame_header(tr, static_cast<uint32_t>(tr_block.size()), kHeaders,
                kFlagEndHeaders | kFlagEndStream, stream);
   tr += tr_block;
-  if (grpc_status == 0) {
-    conn->send_response(stream, hdr, std::move(data), tr);
-  } else {
-    // Error replies carry no DATA — headers-only frames are exempt
-    // from flow control.
-    conn->send_all(hdr + tr);
+  bool handed_off;
+  {
+    std::lock_guard<std::mutex> lock(conn->write_mu);
+    handed_off = conn->handing_off();
+    if (owed && handed_off) conn->owed.push_back(*owed);
+    if (grpc_status == 0) {
+      conn->send_response_locked(stream, std::move(hdr), std::move(data),
+                                 std::move(tr));
+    } else {
+      // Error replies carry no DATA — headers-only frames are exempt
+      // from flow control.
+      conn->send_locked(hdr + tr);
+    }
   }
+  if (owed && !handed_off) count_response(srv, *owed);
 }
 
-void send_rpc_response(const std::shared_ptr<Conn>& conn, uint32_t stream,
-                       const int64_t* cols, int64_t offset, int64_t k,
-                       int64_t total, int grpc_status) {
-  send_rpc_payload(conn, stream,
+void send_rpc_response(Server* srv, const std::shared_ptr<Conn>& conn,
+                       uint32_t stream, const int64_t* cols, int64_t offset,
+                       int64_t k, int64_t total, int grpc_status,
+                       const Owed* owed = nullptr) {
+  send_rpc_payload(srv, conn, stream,
                    grpc_status == 0
                        ? build_data_payload(cols, offset, k, total)
                        : std::string(),
-                   grpc_status);
+                   grpc_status, owed);
 }
 
 // Opaque per-RPC handle the columnar feeder carries from pack to
@@ -733,27 +812,6 @@ struct FeederToken {
   int64_t t0_ns;  // serve_rpc's entry (0 = no ring): rpc_total's start
   int64_t items;
 };
-
-constexpr int kGrpcUnimplemented = 12;
-
-// One response handed to a live connection's write path: the front's
-// RPC counters (successes only into `rpcs`, failures only into
-// `errors`) and the rpc_total event, on every path alike.
-// guberlint: gil-free
-void count_response(Server* srv, int grpc_status, int64_t t0_ns,
-                    int64_t items) {
-  if (grpc_status == 0) {
-    srv->rpcs.fetch_add(1);
-  } else {
-    srv->errors.fetch_add(1);
-    if (grpc_status == kGrpcUnimplemented) srv->unimplemented.fetch_add(1);
-  }
-  void* ring = srv->ring.load();
-  if (ring && t0_ns) {
-    const int64_t t1 = evr_now_ns();
-    evr_record(ring, kEvRpcTotal, t1, t1 - t0_ns, items);
-  }
-}
 
 static const char kPreface[] = "PRI * HTTP/2.0\r\n\r\nSM\r\n\r\n";
 
@@ -804,7 +862,8 @@ void serve_rpc(Server* srv, const std::shared_ptr<Conn>& conn,
       put_u32(len4, static_cast<uint32_t>(resp.size()));
       data.append(reinterpret_cast<char*>(len4), 4);
       data += resp;
-      send_rpc_payload(conn, stream, std::move(data), 0);
+      const Owed owed{0, t0, items, false};
+      send_rpc_payload(srv, conn, stream, std::move(data), 0, &owed);
       srv->native_rpcs.fetch_add(1);
       srv->native_items.fetch_add(items);
       routed = true;
@@ -812,7 +871,6 @@ void serve_rpc(Server* srv, const std::shared_ptr<Conn>& conn,
         const int64_t t1 = evr_now_ns();
         evr_record(ring, kEvNativeServe, t1, t1 - t0, items);
       }
-      count_response(srv, 0, t0, items);
     }
   }
   // Columnar feeder: fall-through RPCs pack straight into the
@@ -918,7 +976,7 @@ void process_input(Server* srv, const std::shared_ptr<Conn>& conn) {
         // with no body ends here — answer UNIMPLEMENTED).
         stream_body(rs, stream);
         if (flags & kFlagEndStream) {
-          send_rpc_response(conn, stream, nullptr, 0, 0, 0, 12);
+          send_rpc_response(srv, conn, stream, nullptr, 0, 0, 0, 12);
           drop_stream(rs, stream);
         }
         break;
@@ -955,12 +1013,12 @@ void process_input(Server* srv, const std::shared_ptr<Conn>& conn) {
         if (flags & kFlagEndStream) {
           // grpc frame: 1-byte compressed flag + u32 length + body.
           if (st_body.size() < 5 || st_body[0] != 0) {
-            send_rpc_response(conn, stream, nullptr, 0, 0, 0, 13);
+            send_rpc_response(srv, conn, stream, nullptr, 0, 0, 0, 13);
           } else {
             const uint32_t mlen = get_u32(
                 reinterpret_cast<const uint8_t*>(st_body.data()) + 1);
             if (5 + mlen > st_body.size()) {
-              send_rpc_response(conn, stream, nullptr, 0, 0, 0, 13);
+              send_rpc_response(srv, conn, stream, nullptr, 0, 0, 0, 13);
             } else {
               std::string body = st_body.substr(5, mlen);
               const int64_t items = count_items(
@@ -968,7 +1026,7 @@ void process_input(Server* srv, const std::shared_ptr<Conn>& conn) {
                   reinterpret_cast<const uint8_t*>(body.data()) +
                       body.size());
               if (items < 0 || items > 1000) {
-                send_rpc_response(conn, stream, nullptr, 0, 0, 0, 13);
+                send_rpc_response(srv, conn, stream, nullptr, 0, 0, 0, 13);
               } else {
                 serve_rpc(srv, conn, stream, std::move(body), items);
               }
@@ -1139,16 +1197,17 @@ void dispatch_loop(Server* srv) {
         offset += rpc.items;
         continue;
       }
+      const Owed owed{static_cast<int>(st), rpc.t_enq_ns, rpc.items, false};
       if (st == 0) {
-        send_rpc_response(rpc.conn, rpc.stream, cols.data(), offset,
-                          rpc.items, total, 0);
+        send_rpc_response(srv, rpc.conn, rpc.stream, cols.data(), offset,
+                          rpc.items, total, 0, &owed);
       } else {
-        send_rpc_response(rpc.conn, rpc.stream, nullptr, 0, 0, 0,
-                          static_cast<int>(st));
+        send_rpc_response(srv, rpc.conn, rpc.stream, nullptr, 0, 0, 0,
+                          static_cast<int>(st), &owed);
       }
-      count_response(srv, static_cast<int>(st), rpc.t_enq_ns, rpc.items);
       offset += rpc.items;
     }
+    kick_posted();  // one wake a reactor for the whole window
   }
 }
 
@@ -1193,6 +1252,7 @@ void accept_loop(Server* srv, int listen_fd) {
 
 struct Reactor {
   // guberlint: guard dead_fds by dead_mu
+  // guberlint: guard posted by post_mu
   int epfd = -1;
   int wake_fd = -1;   // eventfd: h2s_stop (and the write-side death
                       // notifier) kick a parked epoll_wait
@@ -1208,6 +1268,10 @@ struct Reactor {
   // kicks wake_fd; the owning reactor drops them next wake.
   std::mutex dead_mu;
   std::vector<int> dead_fds;
+  // Connections other threads queued responses on (post_conn): the
+  // reactor writes them on its next wake (reactor_drain_posted).
+  std::mutex post_mu;
+  std::vector<std::shared_ptr<Conn>> posted;
   // The destructor owns epfd/wake_fd: a partial h2s_start failure
   // (fd exhaustion on a later lane) or h2s_stop's delete both
   // release them through ~Reactor — no separate close bookkeeping
@@ -1241,6 +1305,62 @@ void notify_conn_dead(Conn* c) {
   uint64_t one = 1;
   const ssize_t r = ::write(rx->wake_fd, &one, sizeof(one));
   (void)r;
+}
+
+// The reactors this thread posted to since its last kick_posted().
+thread_local std::vector<Reactor*> tls_kicks;
+
+void post_conn(Conn* c) {
+  Reactor* rx = c->rx;
+  {
+    std::lock_guard<std::mutex> lock(rx->post_mu);
+    rx->posted.push_back(c->shared_from_this());
+  }
+  if (std::find(tls_kicks.begin(), tls_kicks.end(), rx) == tls_kicks.end())
+    tls_kicks.push_back(rx);
+}
+
+// Wake every reactor this thread posted to: one eventfd write a
+// reactor, however many of its connections the window answered.
+void kick_posted() {
+  for (Reactor* rx : tls_kicks) {
+    uint64_t one = 1;
+    const ssize_t r = ::write(rx->wake_fd, &one, sizeof(one));
+    (void)r;
+  }
+  tls_kicks.clear();
+}
+
+// Write what other threads handed this reactor: each posted conn's
+// queued frames through the flow-control-aware pump and its writev
+// flush (EAGAIN arms EPOLLOUT as ever), then the counts its responses
+// owe — rpc_total ends here, where their bytes reach writev.  A conn
+// dead by now is written nothing and counts nothing, as a response to
+// a dead conn never did.
+void reactor_drain_posted(Server* srv, Reactor* rx) {
+  std::vector<std::shared_ptr<Conn>> posted;
+  {
+    std::lock_guard<std::mutex> lock(rx->post_mu);
+    posted.swap(rx->posted);
+  }
+  std::vector<Owed> owed;
+  for (auto& conn : posted) {
+    bool live;
+    {
+      std::lock_guard<std::mutex> lock(conn->write_mu);
+      conn->awaiting_reactor = false;
+      owed.swap(conn->owed);
+      live = !conn->dead.load();
+      if (live) conn->pump_locked();
+    }
+    if (live)
+      for (const Owed& o : owed) {
+        // Before `rpcs`: a reader that sees `rpcs` sees this too.
+        if (o.status == 0) srv->responses_handed_off.fetch_add(1);
+        count_response(srv, o);
+      }
+    owed.clear();
+  }
 }
 
 void reactor_drop(Server* srv, Reactor* rx, int fd) {
@@ -1422,6 +1542,7 @@ void reactor_sweep_idle(Server* srv, Reactor* rx, int64_t now_ns) {
 // guberlint: gil-free
 // guberlint: epoll-root
 void reactor_loop(Server* srv, Reactor* rx) {
+  tls_reactor = rx;
   epoll_event evs[256];
   while (!srv->closing.load()) {
     // Carryover work pending ⇒ poll without sleeping; otherwise park
@@ -1465,6 +1586,7 @@ void reactor_loop(Server* srv, Reactor* rx) {
         if (conn->dead.load()) reactor_drop(srv, rx, conn->fd);
       }
     }
+    reactor_drain_posted(srv, rx);
     {
       // Write-side deaths (backpressure cap / writev failure from
       // the dispatch or feeder threads): a parked peer fires no
@@ -1673,8 +1795,9 @@ void h2s_attach_feeder(void* handle, void* feeder) {
 }
 
 // Response scatter bridge (called by the feeder's serve thread):
-// wrap one RPC's protobuf payload in a grpc frame and send it through
-// the connection's flow-control-aware write path; consumes the token.
+// wrap one RPC's protobuf payload in a grpc frame and queue it on the
+// connection's flow-control-aware write path — on the event plane the
+// conn's reactor writes it after h2s_kick_posted; consumes the token.
 void h2s_feeder_respond(void* conn_token, int64_t stream,
                         const uint8_t* payload, int64_t len,
                         int32_t grpc_status) {
@@ -1695,13 +1818,16 @@ void h2s_feeder_respond(void* conn_token, int64_t stream,
       data.append(reinterpret_cast<const char*>(payload),
                   static_cast<size_t>(len));
     }
-    send_rpc_payload(token->conn, static_cast<uint32_t>(stream),
-                     std::move(data), grpc_status);
-    if (grpc_status == 0) token->srv->feeder_rpcs.fetch_add(1);
-    count_response(token->srv, grpc_status, token->t0_ns, token->items);
+    const Owed owed{grpc_status, token->t0_ns, token->items, true};
+    send_rpc_payload(token->srv, token->conn, static_cast<uint32_t>(stream),
+                     std::move(data), grpc_status, &owed);
   }
   delete token;
 }
+
+// The end of a scatter: wake each reactor the calling thread handed
+// responses to since its last call, once.
+void h2s_kick_posted() { kick_posted(); }
 
 // Teardown-side token release: free without sending (the feeder was
 // stopped with windows still claimed — cf_free walks them).
@@ -1726,9 +1852,9 @@ int32_t h2s_port(void* handle) {
 // out: [0] rpcs, [1] windows, [2] errors, [3] native_rpcs,
 // [4] native_items, [5] feeder_rpcs, [6] feeder_items,
 // [7] conns_open, [8] idle_reaped, [9] reactors, [10] event_front,
-// [11] unimplemented (of errors), [12] window_items (byte windows)
-// (callers may pass a larger zeroed buffer; only the first thirteen
-// slots are written).
+// [11] unimplemented (of errors), [12] window_items (byte windows),
+// [13] responses_handed_off (of rpcs) (callers may pass a larger
+// zeroed buffer; only the first fourteen slots are written).
 void h2s_stats(void* handle, int64_t* out) {
   auto* srv = static_cast<Server*>(handle);
   out[0] = srv->rpcs.load();
@@ -1744,6 +1870,7 @@ void h2s_stats(void* handle, int64_t* out) {
   out[10] = srv->event_front ? 1 : 0;
   out[11] = srv->unimplemented.load();
   out[12] = srv->window_items.load();
+  out[13] = srv->responses_handed_off.load();
 }
 
 void h2s_stop(void* handle) {

@@ -448,6 +448,7 @@ class NativeColumnarFeeder:
             "feeder_key_cap": int(out[11]),
             "feeder_max_rpcs": int(out[12]),
             "feeder_windows_overlapped": int(out[13]),
+            "feeder_drained_rows": int(out[14]),
         }
 
     @property

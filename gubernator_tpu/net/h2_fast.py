@@ -37,7 +37,9 @@ side multiplexes ALL connections over a small pool of epoll reactor
 threads (GUBER_H2_REACTORS, default ncpu−1 — one core stays reserved
 for the Python serve plane) instead of one detached thread per
 connection, with writev-batched egress and idle-connection reaping
-(GUBER_H2_IDLE_TIMEOUT; GOAWAY + close).  GUBER_H2_EVENT_FRONT=0
+(GUBER_H2_IDLE_TIMEOUT; GOAWAY + close).  A window's responses are
+queued on their connections by the serving thread and written by each
+connection's own reactor, one wake a reactor a window.  GUBER_H2_EVENT_FRONT=0
 restores the thread-per-connection plane, where GUBER_H2_LANES
 (default: CPU count) shards the listener across SO_REUSEPORT accept
 lanes.
@@ -693,6 +695,7 @@ class H2FastFront:
             "event_front": bool(out[10]),
             "declined_rpcs": int(out[11]),
             "window_items": int(out[12]),
+            "responses_handed_off": int(out[13]),
             "lanes": self.lanes,
         }
         # Mid-teardown (handle gone) the plane and the feeder may be
@@ -729,8 +732,10 @@ class H2FastFront:
         `items` entered into Python (byte windows + feeder windows;
         the decision plane's RPCs enter none), `windows_overlapped`
         feeder windows submitted while the one before was still in
-        flight on the device, `ring_dropped` events the ring was too
-        full to take."""
+        flight on the device, `responses_handed_off` of `rpcs` those a
+        thread other than their connection's reactor queued and that
+        reactor wrote, `ring_dropped` events the ring was too full to
+        take."""
         # One hold of _teardown_mu over every FFI read: close() frees
         # the feeder and the ring only after it has taken the handle
         # away under the same lock.
@@ -748,6 +753,7 @@ class H2FastFront:
             "items": st["window_items"] + st.get("feeder_served_rows", 0),
             "feeder_rpcs": st["feeder_front_rpcs"],
             "windows_overlapped": st.get("feeder_windows_overlapped", 0),
+            "responses_handed_off": st["responses_handed_off"],
             "feeder_ring_full": st.get("feeder_ring_full", 0),
             "feeder_declined": st.get("feeder_declined", 0),
             "plane_rpcs": st["native_rpcs"],

@@ -616,7 +616,7 @@ def test_stop_completes_the_window_in_flight_first():
         st = feeder.stats()
         assert st["feeder_windows"] == 2  # one completed, one drained
         assert st["feeder_served_rows"] == 8 and st["feeder_rows"] == 16
-        assert st["feeder_open_rows"] == 0
+        assert st["feeder_drained_rows"] == 8 and st["feeder_open_rows"] == 0
         assert all(s.pending is None for s in feeder.slots)
     finally:
         feeder.close()

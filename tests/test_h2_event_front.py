@@ -4,8 +4,9 @@ RPC correctness through the reactors (parity with the thread-per-conn
 plane, across the native decision plane and the columnar feeder),
 partial/coalesced frame delivery under edge-triggered reads, writev
 short-write resumption and backpressure when a client stops reading,
-idle-connection reaping, teardown under live load, and the reactor
-stages in the event ring.
+idle-connection reaping, teardown under live load, the reactor
+stages in the event ring, and the hand-off of a window's responses to
+the reactors that own their connections.
 """
 
 import socket
@@ -145,6 +146,9 @@ def test_event_vs_threaded_parity(daemon):
             got = stub.GetRateLimits(_req("par", "x"))
             remaining.append(got.responses[0].remaining)
         assert remaining == [99, 98, 97, 96, 95, 94]
+        # the threaded plane's serving threads write through: no hand-off
+        st = _settled(threaded, "rpcs", 3)
+        assert st["rpcs"] == 3 and st["responses_handed_off"] == 0
     finally:
         threaded.close()
 
@@ -398,3 +402,216 @@ def test_connscale_client_against_event_front(daemon):
     assert out["alive_at_end"] == 200
     assert out["errors"] == 0
     assert out["rpcs"] > 0
+
+
+# -- the hand-off: a window's responses are written by their reactors ----
+
+
+def _settled(front, key, want, timeout=5.0):
+    """front.stats() once `key` has reached `want`: a reactor counts a
+    response after its writev, so a client may read it first."""
+    deadline = time.monotonic() + timeout
+    while True:
+        st = front.stats()
+        if st[key] >= want or time.monotonic() > deadline:
+            return st
+        time.sleep(0.01)
+
+
+def _open_streams(port, streams):
+    """A raw h2 connection with `streams` ({id: request}) sent in one
+    write."""
+    wire = b"PRI * HTTP/2.0\r\n\r\nSM\r\n\r\n"
+    for sid, req in streams.items():
+        wire += _frame(1, 0x4, sid) + _frame(
+            0, 0x1, sid, _grpc_frame(req.SerializeToString()))
+    sock = socket.create_connection(("127.0.0.1", port), timeout=5)
+    sock.sendall(wire)
+    return sock
+
+
+def _resp(data):
+    (ln,) = struct.unpack(">I", data[1:5])
+    return pb.GetRateLimitsResp.FromString(data[5 : 5 + ln])
+
+
+@pytest.mark.parametrize("feeder", [True, False], ids=["feeder", "bytepath"])
+def test_window_responses_are_written_by_their_reactors(daemon, feeder):
+    """A window's RPCs on 24 connections over four reactors, three
+    streams each: the thread that serves the window (the feeder's, or
+    the byte path's dispatch thread) queues every response on its
+    connection and that connection's reactor writes it.  Each stream
+    gets its own body, its frames in order (HEADERS, DATA, trailers),
+    and `responses_handed_off` counts each response once."""
+    front = H2FastFront(daemon.instance, window_s=0.02, native_feeder=feeder,
+                        native_ledger=False, event_front=True, reactors=4)
+    tag = "ho" + str(int(feeder))
+    try:
+        assert front.conn_stats()["reactors"] == 4
+        before = front.stats()
+        sids = (1, 3, 5)
+        hits = {(c, sid): 1 + (3 * c + sid) % 7 for c in range(24) for sid in sids}
+        socks = [
+            _open_streams(front.port, {
+                sid: _req(tag, f"c{c}s{sid}_", hits=hits[c, sid], n=2)
+                for sid in sids})
+            for c in range(24)
+        ]
+        try:
+            for c, sock in enumerate(socks):
+                frames = {sid: [] for sid in sids}
+                data = {sid: b"" for sid in sids}
+                done = set()
+                for ftype, flags, sid, payload in _h2_frames(
+                        sock, time.monotonic() + 5.0):
+                    if sid not in frames:
+                        continue
+                    trailers = ftype == 1 and bool(flags & 0x1)
+                    frames[sid].append("trailers" if trailers else
+                                       {0: "data", 1: "headers"}[ftype])
+                    data[sid] += payload if ftype == 0 else b""
+                    if trailers:
+                        done.add(sid)
+                        if done == set(sids):
+                            break
+                for sid in sids:
+                    assert frames[sid] == ["headers", "data", "trailers"], (c, sid)
+                    assert [r.remaining for r in _resp(data[sid]).responses] == [
+                        100 - hits[c, sid]] * 2, (c, sid)
+        finally:
+            for sock in socks:
+                sock.close()
+        n = len(hits)
+        after = _settled(front, "rpcs", before["rpcs"] + n)
+        assert after["rpcs"] - before["rpcs"] == n
+        assert after["responses_handed_off"] - before["responses_handed_off"] == n
+        assert after["errors"] == before["errors"]
+    finally:
+        front.close()
+
+
+def test_a_response_past_the_peers_window_completes_through_its_reactor(daemon):
+    """A response larger than the peer's stream window, handed off by
+    the serve thread: its reactor writes HEADERS and what the window
+    allows, and the rest once the peer's WINDOW_UPDATE reaches that
+    reactor."""
+    front = H2FastFront(daemon.instance, window_s=0.001, native_ledger=False)
+    try:
+        before = front.stats()
+        sock = socket.create_connection(("127.0.0.1", front.port), timeout=5)
+        try:
+            sock.sendall(
+                b"PRI * HTTP/2.0\r\n\r\nSM\r\n\r\n"
+                # SETTINGS: INITIAL_WINDOW_SIZE = 64
+                + _frame(4, 0, 0, struct.pack(">HI", 4, 64))
+                + _frame(1, 0x4, 1)
+                + _frame(0, 0x1, 1, _grpc_frame(
+                    _req("fc_ho", "k", n=200).SerializeToString())))
+            data, trailers = b"", False
+            for ftype, flags, sid, payload in _h2_frames(
+                    sock, time.monotonic() + 1.0):
+                if sid == 1 and ftype == 0:
+                    data += payload
+                trailers |= sid == 1 and ftype == 1 and bool(flags & 0x1)
+            assert 0 < len(data) <= 64 and not trailers
+            mid = _settled(front, "responses_handed_off",
+                           before["responses_handed_off"] + 1)
+            assert mid["responses_handed_off"] - before["responses_handed_off"] == 1
+            sock.sendall(_frame(8, 0, 1, struct.pack(">I", 1 << 20)))
+            for ftype, flags, sid, payload in _h2_frames(
+                    sock, time.monotonic() + 5.0):
+                if sid == 1 and ftype == 0:
+                    data += payload
+                if sid == 1 and ftype == 1 and flags & 0x1:
+                    trailers = True
+                    break
+            assert trailers
+            assert [r.remaining for r in _resp(data).responses] == [99] * 200
+        finally:
+            sock.close()
+    finally:
+        front.close()
+
+
+@pytest.mark.parametrize("read_first", [False, True],
+                         ids=["reset-at-once", "reset-after-a-read"])
+def test_connections_reset_around_the_hand_off(daemon, read_first):
+    """Peers that send three RPCs and reset (SO_LINGER 0) at once, or
+    after the first DATA frame of an answer, while their responses are queued
+    and handed to their reactors: whichever comes first, the death or
+    the reactor's flush, a response to a dead connection is written and
+    counted nowhere — `errors` does not move, each response counted is
+    one its reactor wrote (`rpcs` = `responses_handed_off` =
+    `feeder_front_rpcs`, all three settled at the writev) — every
+    connection and its socket are released, and the reactors go on
+    serving."""
+    psutil = pytest.importorskip("psutil")
+    front = H2FastFront(daemon.instance, window_s=0.001, native_ledger=False,
+                        event_front=True, reactors=2)
+
+    def front_sockets():
+        return [c for c in psutil.Process().net_connections(kind="tcp")
+                if c.raddr and c.laddr.port == front.port]
+
+    tag = "rst" + str(int(read_first))
+    n_conns = 60
+    try:
+        before = front.stats()
+        for c in range(n_conns):
+            sock = _open_streams(front.port, {
+                sid: _req(tag, f"c{c}s{sid}_") for sid in (1, 3, 5)})
+            if read_first:  # the first DATA frame of an answer, then reset
+                next(f for f in _h2_frames(sock, time.monotonic() + 5.0)
+                     if f[0] == 0)
+            sock.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                            struct.pack("ii", 1, 0))
+            sock.close()
+        deadline = time.monotonic() + 10.0
+        while ((front.conn_stats()["conns_open"] or front_sockets())
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        assert front.conn_stats()["conns_open"] == 0
+        assert front_sockets() == []
+        after = front.stats()
+        d = {k: after[k] - before[k] for k in
+             ("rpcs", "errors", "responses_handed_off", "feeder_front_rpcs")}
+        assert d["errors"] == 0, d
+        assert d["rpcs"] == d["responses_handed_off"] == d["feeder_front_rpcs"], d
+        assert d["rpcs"] <= 3 * n_conns, d
+        if read_first:
+            assert d["rpcs"] > 0, d  # every peer read an answer first
+        stub = V1Stub(dial(f"127.0.0.1:{front.port}"))
+        got = stub.GetRateLimits(_req(tag, "after"), timeout=10)
+        assert got.responses[0].remaining == 99
+    finally:
+        front.close()
+
+
+def test_the_reactors_own_responses_write_through():
+    """A response the reactor produces itself — the native decision
+    plane answering a delegated hot key inside the reactor — is written
+    by that reactor at once: `responses_handed_off` counts the window
+    path's responses and none of the plane's."""
+    conf = DaemonConfig(
+        grpc_listen_address="127.0.0.1:0",
+        http_listen_address="127.0.0.1:0",
+        cache_size=1 << 12,
+        peer_discovery_type="none",
+        device_count=1,
+        sweep_interval=0.0,
+        h2_fast_address="127.0.0.1:0",
+        h2_fast_window=0.001,
+        ledger_hot_threshold=2,
+        ledger_lease_ttl=30.0,
+    )
+    d = spawn_daemon(conf)
+    try:
+        assert d.h2_fast.plane is not None
+        stub = V1Stub(dial(d.h2_fast_address))
+        for _ in range(40):
+            stub.GetRateLimits(_req("wt", "hot", limit=10**6), timeout=10)
+        st = _settled(d.h2_fast, "rpcs", 40)
+        assert st["rpcs"] == 40 and st["native_rpcs"] > 0
+        assert st["responses_handed_off"] == st["rpcs"] - st["native_rpcs"] > 0
+    finally:
+        d.close()

@@ -1,13 +1,14 @@
-"""ThreadSanitizer stress of the native h2 server (guberlint's native
+"""ThreadSanitizer (and, for the reactor plane's hand-off,
+AddressSanitizer) stress of the native h2 server (guberlint's native
 runtime companion, STATIC_ANALYSIS.md).
 
-Builds core/native/h2_server.cpp with GUBER_NATIVE_SAN=thread (separate
-cache tag, -fsanitize=thread -O1 -g) and hammers it from concurrent
-gRPC clients in a SUBPROCESS with the TSan runtime LD_PRELOADed — a
-sanitizer runtime cannot initialize inside an already-running
-uninstrumented python, so in-process loading is not an option.  Any
-data race inside the instrumented .so fails the subprocess
-(halt_on_error=1, exitcode=66).
+Builds core/native/h2_server.cpp with GUBER_NATIVE_SAN=thread|address
+(separate cache tags, -fsanitize=... -O1 -g) and hammers it from
+concurrent gRPC clients in a SUBPROCESS with the sanitizer runtime
+LD_PRELOADed — a sanitizer runtime cannot initialize inside an
+already-running uninstrumented python, so in-process loading is not an
+option.  Any data race (TSan) or bad access (ASan) inside the
+instrumented .so fails the subprocess (halt_on_error=1, exitcode=66).
 
 Marked slow: TSan startup + the hammer take tens of seconds; run it
 with `GUBER_NATIVE_SAN=1 pytest -m slow tests/test_h2_server_san.py`
@@ -115,8 +116,23 @@ fst = feeder.stats()
 lib.h2s_stop(handle)
 feeder.close()
 assert stats[5] > 0, "hammer never exercised the feeder path"
+if event:
+    # The scatter handed its responses to the connections' reactors:
+    # the posted lists and their kicks ran under the hammer and the
+    # churn client's resets, and run on through this teardown.
+    assert 0 < stats[13] <= stats[0], list(stats)
 assert fst["feeder_windows_overlapped"] > 0, fst
-assert fst["feeder_served_rows"] == fst["feeder_rows"], fst
+assert fst["feeder_open_rows"] == 0, fst
+if event:
+    # The churn client packs on into the teardown, so the stop drains
+    # claimed windows without entering Python: every packed row is
+    # served or drained, none lost.
+    assert (fst["feeder_served_rows"] + fst["feeder_drained_rows"]
+            == fst["feeder_rows"]), fst
+else:
+    # No client runs into the teardown: every packed row was served.
+    assert fst["feeder_served_rows"] == fst["feeder_rows"], fst
+    assert fst["feeder_drained_rows"] == 0, fst
 print("san stress ok rpcs=%d windows=%d feeder_rpcs=%d overlapped=%d"
       % (stats[0], stats[1], stats[5], fst["feeder_windows_overlapped"]),
       flush=True)
@@ -167,20 +183,54 @@ if errs:
 print("client ok: %d rpcs" % (N_THREADS * N_RPCS))
 """
 
+# Runs unsanitized beside the hammer and on through the server's
+# teardown, until killed: raw connections that send three RPCs and
+# reset the socket (SO_LINGER 0) at once or after a first read — peers
+# that die between a response's hand-off and its reactor's flush.
+_CHURN_SRC = r"""
+import socket, struct, sys, time
+from gubernator_tpu.net.pb import gubernator_pb2 as pb
+
+port = int(sys.argv[1])
+body = pb.GetRateLimitsReq(requests=[pb.RateLimitReq(
+    name="churn", unique_key="k", hits=1, limit=100, duration=60000)]
+).SerializeToString()
+def frame(ftype, flags, sid, payload=b""):
+    return (struct.pack(">I", len(payload))[1:] + bytes([ftype, flags])
+            + struct.pack(">I", sid) + payload)
+wire = b"PRI * HTTP/2.0\r\n\r\nSM\r\n\r\n" + b"".join(
+    frame(1, 4, sid) + frame(0, 1, sid, b"\x00" + struct.pack(">I", len(body)) + body)
+    for sid in (1, 3, 5))
+i = 0
+while True:
+    i += 1
+    try:
+        s = socket.create_connection(("127.0.0.1", port), timeout=1)
+        s.sendall(wire)
+        if i % 2:
+            s.recv(64)
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER, struct.pack("ii", 1, 0))
+        s.close()
+    except OSError:
+        time.sleep(0.005)  # the server is going or gone: keep on until killed
+"""
+
 
 @pytest.mark.slow
-@pytest.mark.parametrize("event_front", [0, 1], ids=["threaded", "reactor"])
-def test_h2_server_threaded_stress_under_tsan(event_front):
+@pytest.mark.parametrize("event_front,san", [
+    (0, "thread"), (1, "thread"), (1, "address")],
+    ids=["threaded", "reactor", "reactor-asan"])
+def test_h2_server_threaded_stress_under_tsan(event_front, san):
     if os.environ.get("GUBER_NATIVE_SAN", "") in ("", "0"):
-        pytest.skip("set GUBER_NATIVE_SAN=1 to run the TSan stress")
-    preload = sanitizer_preload("thread")
+        pytest.skip("set GUBER_NATIVE_SAN=1 to run the sanitizer stress")
+    preload = sanitizer_preload(san)
     if preload is None:
-        pytest.skip("libtsan not available from this toolchain")
+        pytest.skip(f"the {san} sanitizer runtime is not available from this toolchain")
     # Build the instrumented .so in-process (compilation needs no
     # preload); the subprocess then dlopens the cached artifact.
     orig_san = os.environ.get("GUBER_NATIVE_SAN")
-    env = dict(os.environ, GUBER_NATIVE_SAN="thread")
-    os.environ["GUBER_NATIVE_SAN"] = "thread"
+    env = dict(os.environ, GUBER_NATIVE_SAN=san)
+    os.environ["GUBER_NATIVE_SAN"] = san
     try:
         so = ensure_built("h2_server")
     finally:
@@ -205,11 +255,15 @@ def test_h2_server_threaded_stress_under_tsan(event_front):
             "halt_on_error=1 exitcode=66 report_thread_leaks=0 "
             f"report_mutex_bugs=0 detect_deadlocks=0 suppressions={supp}"
         ),
+        # Leaks are python's own at exit; what ASan is here for is a
+        # bad access: a posted conn or a kicked reactor used after free.
+        ASAN_OPTIONS="halt_on_error=1 exitcode=66 detect_leaks=0",
         # Import gubernator_tpu without jax: TSan instruments every
         # malloc; the XLA runtime under TSan is noise we don't want.
         GUBERNATOR_TPU_X64="0",
         GUBERNATOR_TPU_COMPILE_CACHE="0",
     )
+    plain_env = dict(env, GUBERNATOR_TPU_X64="0", GUBERNATOR_TPU_COMPILE_CACHE="0")
     server = subprocess.Popen(
         [sys.executable, "-c", _SERVER_SRC],
         cwd=REPO,
@@ -219,6 +273,7 @@ def test_h2_server_threaded_stress_under_tsan(event_front):
         stderr=subprocess.PIPE,
         text=True,
     )
+    churn = None
     try:
         port_line = server.stdout.readline()
         assert port_line.startswith("PORT "), (
@@ -226,11 +281,15 @@ def test_h2_server_threaded_stress_under_tsan(event_front):
             + server.stderr.read()[-4000:]
         )
         port = int(port_line.split()[1])
+        if event_front:  # the hand-off exists on the reactor plane only
+            churn = subprocess.Popen(
+                [sys.executable, "-c", _CHURN_SRC, str(port)], cwd=REPO,
+                env=plain_env, stdout=subprocess.DEVNULL,
+                stderr=subprocess.DEVNULL)
         client = subprocess.run(
             [sys.executable, "-c", _CLIENT_SRC, str(port)],
             cwd=REPO,
-            env=dict(env, GUBERNATOR_TPU_X64="0",
-                     GUBERNATOR_TPU_COMPILE_CACHE="0"),
+            env=plain_env,
             capture_output=True,
             text=True,
             timeout=240,
@@ -239,12 +298,18 @@ def test_h2_server_threaded_stress_under_tsan(event_front):
             f"client hammer failed rc={client.returncode}\n"
             f"{client.stdout[-1000:]}\n{client.stderr[-2000:]}"
         )
+        # The teardown runs while the churn client (reactor plane)
+        # still connects, sends and resets.
         out, err = server.communicate(input="", timeout=120)
     except Exception:
         server.kill()
         raise
-    assert "ThreadSanitizer" not in err, (
-        "TSan report from h2_server:\n" + err[-4000:]
+    finally:
+        if churn is not None:
+            churn.kill()
+            churn.wait()
+    assert "ThreadSanitizer" not in err and "AddressSanitizer" not in err, (
+        "sanitizer report from h2_server:\n" + err[-4000:]
     )
     assert server.returncode == 0, (
         f"san server failed rc={server.returncode}\n"
