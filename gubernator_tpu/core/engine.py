@@ -53,7 +53,13 @@ from gubernator_tpu.ops.bucket_kernel import (
 )
 from gubernator_tpu.ops.expiry import windowed_sweep
 from gubernator_tpu.core.interning import InternTable
-from gubernator_tpu.utils.metrics import DurationStat, engine_stages, stage
+from gubernator_tpu.utils.metrics import (
+    CPU_MIN_ITEMS,
+    CPU_STAGES,
+    DurationStat,
+    engine_stages,
+    stage,
+)
 from gubernator_tpu.utils.tracing import span
 from gubernator_tpu.types import (
     Algorithm,
@@ -518,6 +524,10 @@ class DecisionEngine:
         # engine.load: a piece of the columnar restore (the sharded
         # engine has it too, beside its router's mesh.route).
         self.stages = engine_stages(extra=("engine.load",))
+        # True while the lock's holder serves a section that reads its
+        # CPU clock (apply_columnar); only CPU_STAGES, all of them under
+        # the lock, act on it.
+        self._cpu_section = False  # guberlint: guarded-by _lock
         # Engine-wide d2h transfer batching (core/readback.py): every
         # dispatched output registers a ticket; readers share one
         # stacked transfer instead of paying a device→host read each.
@@ -526,7 +536,12 @@ class DecisionEngine:
         self.readback = ReadbackCombiner()
 
     def _stage(self, name: str, work: bool = True) -> stage:
-        return stage(name, self.stages[name], work)
+        return stage(
+            name, self.stages[name], work,
+            # guberlint: ok lock — read for CPU_STAGES only, each of
+            # them entered under _lock
+            cpu=name in CPU_STAGES and self._cpu_section,
+        )
 
     def _h2d(self, buf):
         """Upload one packed round; a round the pump pre-staged is on
@@ -1032,6 +1047,12 @@ class DecisionEngine:
         with self._lock:
             wait.stop()
             t_held = _time.monotonic()
+            # A section of CPU_MIN_ITEMS items or more reads the
+            # holder's CPU clock around the hold and in the CPU_STAGES
+            # inside it (utils/metrics.py); the same thread takes and
+            # lets go of the lock.
+            self._cpu_section = n >= CPU_MIN_ITEMS
+            c_held = _time.thread_time() if self._cpu_section else None
             try:
                 with span("engine.columnar", batch=n):
                     pending = self._apply_columnar_locked(
@@ -1044,8 +1065,10 @@ class DecisionEngine:
             finally:
                 # The serial section: histogram only — it is the parent
                 # of the leaf stages that tile it.
+                self._cpu_section = False
+                cpu = None if c_held is None else _time.thread_time() - c_held
                 self.stages["engine.lock_hold"].observe(
-                    _time.monotonic() - t_held
+                    _time.monotonic() - t_held, cpu
                 )
         return pending if want_async else pending.get()
 

@@ -77,6 +77,10 @@
 // (h2s_feeder_respond / h2s_feeder_release) is an ordinary in-image
 // call.
 
+#include <sys/syscall.h>
+#include <time.h>
+#include <unistd.h>
+
 #include <algorithm>
 #include <atomic>
 #include <chrono>
@@ -123,6 +127,7 @@ constexpr int64_t kEvFeederServe = 6;     // per window: submit + complete walls
 // 10 is the h2 front's per-RPC total (h2_server.cpp).
 constexpr int64_t kEvFeederScatter = 11;  // per window: encode + queue + kicks
 constexpr int64_t kEvFeederInflight = 12;  // submit's return → complete's entry
+constexpr int64_t kEvFeederWindowWait = 13;  // serve thread blocked → a submit
 
 // A submit's return value for "launched, answers still on the device":
 // the window stays CLOSED and owes a complete entry.  Any other value
@@ -228,7 +233,20 @@ struct Feeder {
   std::atomic<int64_t> window_errors{0};
   // Windows whose submit ran while another window was in flight.
   std::atomic<int64_t> windows_overlapped{0};
+  // The serve thread's CPU inside its Python entries (submit and
+  // complete), summed over the windows counted in `windows`.
+  std::atomic<int64_t> serve_cpu_ns{0};
+  // The serve thread's kernel id (0 until it runs): /debug/vars
+  // gives its CPU the role front_serve.
+  std::atomic<int64_t> serve_tid{0};
 };
+
+// The calling thread's CPU clock (user + system), in ns.
+int64_t thread_cpu_ns() {
+  timespec ts;
+  clock_gettime(CLOCK_THREAD_CPUTIME_ID, &ts);
+  return static_cast<int64_t>(ts.tv_sec) * 1000000000LL + ts.tv_nsec;
+}
 
 // Thread-local decode scratch: the two-phase pack (decode here, then
 // claim EXACT sizes and copy) is what keeps the claim protocol
@@ -368,6 +386,7 @@ struct Served {
   ColumnarCallback done = nullptr;  // the complete entry that owes the verdicts
   bool entered = false;  // Python was entered for this window
   int64_t serve_ns = 0;  // walls of its Python entries so far
+  int64_t serve_cpu_ns = 0;  // the thread's CPU in them so far
   int64_t t_submitted_ns = 0;  // submit's return: the in-flight span's start
 };
 
@@ -409,7 +428,9 @@ bool submit_window(Feeder* f, int64_t idx, Served* s) {
           evr_record(ring, kEvFeederRingWait, t_cb,
                      t_cb - w.rpc_enq_ns[r], w.rpc_items[r]);
     }
+    const int64_t c0 = thread_cpu_ns();
     s->rc = cb(idx, rows, n_rpcs, static_cast<int64_t>(cur_bytes(sealed)));
+    s->serve_cpu_ns = thread_cpu_ns() - c0;
     s->entered = true;
     if (s->rc == kInFlight) {
       if (done != nullptr)
@@ -438,8 +459,10 @@ void finish_window(Feeder* f, Served* s) {
     const int64_t t0 = ring ? evr_now_ns() : 0;
     if (ring && s->t_submitted_ns)
       evr_record(ring, kEvFeederInflight, t0, t0 - s->t_submitted_ns, rows);
+    const int64_t c0 = thread_cpu_ns();
     s->rc = s->done(s->idx, rows, n_rpcs,
                     static_cast<int64_t>(cur_bytes(s->sealed)));
+    s->serve_cpu_ns += thread_cpu_ns() - c0;
     if (s->rc == kInFlight) s->rc = 13;  // complete owes the verdicts
     if (ring) s->serve_ns += evr_now_ns() - t0;
   }
@@ -447,6 +470,7 @@ void finish_window(Feeder* f, Served* s) {
     // One serve event a window: the sum of its entries' walls.
     if (ring) evr_record(ring, kEvFeederServe, evr_now_ns(), s->serve_ns, rows);
     f->served_rows.fetch_add(rows);
+    f->serve_cpu_ns.fetch_add(s->serve_cpu_ns);
   } else {
     f->drained_rows.fetch_add(rows);
   }
@@ -492,10 +516,16 @@ int64_t find_window(Feeder* f, int64_t skip, int64_t min_rows) {
 }
 
 void serve_loop(Feeder* f) {
+  f->serve_tid.store(static_cast<int64_t>(::syscall(SYS_gettid)));
   Served fl;  // the window in flight on the device (idx < 0: none)
   const int64_t worth = std::max<int64_t>(1, f->flush_rows / kWorthShare);
+  // The thread's time blocked in the two waits below since its last
+  // submit, and the end of the last one: one feeder_window_wait event
+  // a submitted window (0 for a window taken with another in flight).
+  int64_t waited_ns = 0, t_waited_ns = 0;
   while (true) {
     if (fl.idx < 0) {
+      const int64_t t_wait = evr_now_ns();
       // Nothing in flight: sleep until a window holds rows, then give
       // it its group-commit wait.  With a window in flight neither
       // wait runs — its submit was the gathering time, and only a
@@ -533,6 +563,8 @@ void serve_loop(Feeder* f) {
         });
         f->kick.store(false);
       }
+      t_waited_ns = evr_now_ns();
+      waited_ns += t_waited_ns - t_wait;
     }
     if (f->closing.load()) break;
     Served nx;
@@ -553,6 +585,13 @@ void serve_loop(Feeder* f) {
       }
       submitted = submit_window(f, idx, &nx);
       if (submitted && fl.idx >= 0) f->windows_overlapped.fetch_add(1);
+      void* ring = f->ring.load();
+      if (submitted && ring) {
+        evr_record(ring, kEvFeederWindowWait,
+                   t_waited_ns ? t_waited_ns : evr_now_ns(), waited_ns,
+                   static_cast<int64_t>(cur_rpcs(nx.sealed)));
+      }
+      if (submitted) waited_ns = t_waited_ns = 0;
     }
     // Window k's answers only after window k+1 is launched: k's step
     // ran while the thread interned and packed k+1.
@@ -806,32 +845,39 @@ void cf_flush(void* handle) {
   }
 }
 
-// out15: packed_rpcs, packed_rows, windows, served_rows, ring_full,
+// out16: packed_rpcs, packed_rows, windows, served_rows, ring_full,
 // declined, window_errors, open_idx, open_rows, n_slots, max_rows,
-// key_cap, max_rpcs, windows_overlapped, drained_rows (callers may
-// pass a larger zeroed buffer).  The
+// key_cap, max_rpcs, windows_overlapped, drained_rows, serve_cpu_ns
+// (callers may pass a larger zeroed buffer).  The
 // clamped shapes are exported so the Python view layer maps EXACTLY
 // the allocated capacities (a caller-supplied max_rpcs above the
 // cursor field width is clamped here, and a view sized off the raw
 // argument would extend past the C allocation).
-void cf_stats(void* handle, int64_t* out15) {
+void cf_stats(void* handle, int64_t* out) {
   auto* f = static_cast<Feeder*>(handle);
-  out15[0] = f->packed_rpcs.load();
-  out15[1] = f->packed_rows.load();
-  out15[2] = f->windows.load();
-  out15[3] = f->served_rows.load();
-  out15[4] = f->ring_full.load();
-  out15[5] = f->declined.load();
-  out15[6] = f->window_errors.load();
+  out[0] = f->packed_rpcs.load();
+  out[1] = f->packed_rows.load();
+  out[2] = f->windows.load();
+  out[3] = f->served_rows.load();
+  out[4] = f->ring_full.load();
+  out[5] = f->declined.load();
+  out[6] = f->window_errors.load();
   const int64_t open = f->open.load();
-  out15[7] = open;
-  out15[8] = static_cast<int64_t>(cur_rows(f->slots[open].cursor.load()));
-  out15[9] = f->n_slots;
-  out15[10] = f->max_rows;
-  out15[11] = f->key_cap;
-  out15[12] = f->max_rpcs;
-  out15[13] = f->windows_overlapped.load();
-  out15[14] = f->drained_rows.load();
+  out[7] = open;
+  out[8] = static_cast<int64_t>(cur_rows(f->slots[open].cursor.load()));
+  out[9] = f->n_slots;
+  out[10] = f->max_rows;
+  out[11] = f->key_cap;
+  out[12] = f->max_rpcs;
+  out[13] = f->windows_overlapped.load();
+  out[14] = f->drained_rows.load();
+  out[15] = f->serve_cpu_ns.load();
+}
+
+// The serve thread's kernel thread id, 0 before it ran (h2_server.cpp's
+// h2s_thread_ids reads it for an attached feeder).
+int64_t cf_serve_tid(void* handle) {
+  return static_cast<Feeder*>(handle)->serve_tid.load();
 }
 
 // Stop the serve thread (completes the window in flight, then drains

@@ -57,6 +57,7 @@
 #include <sys/epoll.h>
 #include <sys/eventfd.h>
 #include <sys/socket.h>
+#include <sys/syscall.h>
 #include <sys/uio.h>
 #include <unistd.h>
 
@@ -87,6 +88,8 @@ extern "C" int64_t dp_try_serve(void* handle, const uint8_t* body,
 extern "C" int64_t evr_record(void* handle, int64_t kind, int64_t t_end_ns,
                               int64_t dur_ns, int64_t items);
 extern "C" int64_t evr_now_ns();
+// From columnar_feeder.cpp (same .so): the feeder's serve thread id.
+extern "C" int64_t cf_serve_tid(void* handle);
 // Columnar feeder plane (columnar_feeder.cpp, same .so): wire bytes →
 // device-ready columns inside the CALLING thread (a conn thread on
 // the threaded plane, a reactor on the event plane — the pack scratch
@@ -292,6 +295,9 @@ struct Server {
   std::vector<std::thread> reactor_threads;
   std::vector<std::thread> accept_threads;
   std::thread dispatch_thread;
+  // The dispatch thread's kernel id (0 until it runs): /debug/vars
+  // gives its CPU the role front_dispatch (h2s_thread_ids).
+  std::atomic<int64_t> dispatch_tid{0};
   std::mutex q_mu;
   std::condition_variable q_cv;
   std::deque<PendingRpc> queue;
@@ -1112,7 +1118,10 @@ void conn_loop(Server* srv, std::shared_ptr<Conn> conn) {
   conn->dead.store(true);
 }
 
+int64_t current_tid() { return static_cast<int64_t>(::syscall(SYS_gettid)); }
+
 void dispatch_loop(Server* srv) {
+  srv->dispatch_tid.store(current_tid());
   while (!srv->closing.load()) {
     std::vector<PendingRpc> batch;
     {
@@ -1293,6 +1302,9 @@ struct Reactor {
   // epoll_wait so edge-triggered reads never stall.
   std::vector<std::shared_ptr<Conn>> pending;
   int64_t last_sweep_ns = 0;
+  // The reactor thread's kernel id (0 until it runs): /debug/vars
+  // gives its CPU the role front_reactor (h2s_thread_ids).
+  std::atomic<int64_t> tid{0};
 };
 
 void notify_conn_dead(Conn* c) {
@@ -1543,6 +1555,7 @@ void reactor_sweep_idle(Server* srv, Reactor* rx, int64_t now_ns) {
 // guberlint: epoll-root
 void reactor_loop(Server* srv, Reactor* rx) {
   tls_reactor = rx;
+  rx->tid.store(current_tid());
   epoll_event evs[256];
   while (!srv->closing.load()) {
     // Carryover work pending ⇒ poll without sleeping; otherwise park
@@ -1871,6 +1884,22 @@ void h2s_stats(void* handle, int64_t* out) {
   out[11] = srv->unimplemented.load();
   out[12] = srv->window_items.load();
   out[13] = srv->responses_handed_off.load();
+}
+
+// The kernel thread ids of the front's long-lived threads, for the
+// daemon's CPU by thread role: out[0] the attached feeder's serve
+// thread, out[1] the dispatch thread, out[2..] one a reactor (0 for a
+// thread not yet running).  Writes at most `cap` slots; returns the
+// number written.
+int64_t h2s_thread_ids(void* handle, int64_t* out, int64_t cap) {
+  auto* srv = static_cast<Server*>(handle);
+  int64_t n = 0;
+  void* feeder = srv->feeder.load();
+  if (n < cap) out[n++] = feeder ? cf_serve_tid(feeder) : 0;
+  if (n < cap) out[n++] = srv->dispatch_tid.load();
+  for (auto& rx : srv->reactors)
+    if (n < cap) out[n++] = rx->tid.load();
+  return n;
 }
 
 void h2s_stop(void* handle) {

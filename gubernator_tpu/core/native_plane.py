@@ -449,6 +449,9 @@ class NativeColumnarFeeder:
             "feeder_max_rpcs": int(out[12]),
             "feeder_windows_overlapped": int(out[13]),
             "feeder_drained_rows": int(out[14]),
+            # The serve thread's CPU in its Python entries, over the
+            # windows of feeder_windows.
+            "feeder_serve_cpu_ns": int(out[15]),
         }
 
     @property

@@ -32,6 +32,7 @@ from gubernator_tpu.net import serde
 from gubernator_tpu.net.pb import gubernator_pb2 as pb
 from gubernator_tpu.net.pb import peers_pb2 as peers_pb
 from gubernator_tpu.service import ServiceError, V1Instance
+from gubernator_tpu.utils.metrics import threads_cpu
 
 
 # jax's profiler is one per process, so is this: a second capture
@@ -283,6 +284,11 @@ class _Handler(BaseHTTPRequestHandler):
         front = getattr(inst, "h2_front", None)
         if front is not None:
             out["h2_front"] = front.debug_vars()
+        # The process's CPU by thread role, read now (never on the
+        # serving path): threads_cpu_s and threads_n.
+        out.update(threads_cpu(
+            front.thread_ids() if front is not None else {}
+        ))
         out["peer_health"] = {}
         for p in inst.get_peer_list():
             if p.info.is_owner:

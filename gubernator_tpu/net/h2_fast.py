@@ -96,6 +96,10 @@ def load() -> Optional[ctypes.CDLL]:
     lib.h2s_reactors.restype = ctypes.c_int32
     lib.h2s_reactors.argtypes = [ctypes.c_void_p]
     lib.h2s_stats.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
+    lib.h2s_thread_ids.restype = ctypes.c_int64
+    lib.h2s_thread_ids.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+    ]
     lib.h2s_attach_plane.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
     lib.h2s_attach_ring.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
     lib.h2s_attach_feeder.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
@@ -707,6 +711,27 @@ class H2FastFront:
             stats.update(self.feeder.stats())
         return stats
 
+    def thread_ids(self) -> dict:
+        """{role: [kernel thread id, ...]} of the front's long-lived C
+        threads — `front_serve` (the feeder's serve thread),
+        `front_dispatch` (the byte path's dispatch thread) and
+        `front_reactor` (the epoll reactors) — for /debug/vars
+        `threads_cpu_s`; a thread not yet running is left out."""
+        out = np.zeros(2 + max(self.reactors, 0), dtype=np.int64)
+        with self._teardown_mu:
+            if not self._handle:
+                return {}
+            n = int(self._lib.h2s_thread_ids(
+                self._handle, out.ctypes.data_as(ctypes.c_void_p), len(out)
+            ))
+        tids = [int(t) for t in out[:n]]
+        roles = {
+            "front_serve": tids[:1],
+            "front_dispatch": tids[1:2],
+            "front_reactor": tids[2:],
+        }
+        return {role: [t for t in ts if t] for role, ts in roles.items()}
+
     def settings(self) -> dict:
         """What this front serves with — the start line's summary and
         the `settings` of /debug/vars `h2_front`."""
@@ -732,10 +757,11 @@ class H2FastFront:
         `items` entered into Python (byte windows + feeder windows;
         the decision plane's RPCs enter none), `windows_overlapped`
         feeder windows submitted while the one before was still in
-        flight on the device, `responses_handed_off` of `rpcs` those a
-        thread other than their connection's reactor queued and that
-        reactor wrote, `ring_dropped` events the ring was too full to
-        take."""
+        flight on the device, `serve_cpu_ns` the feeder serve thread's
+        CPU inside its Python entries (submit and complete) summed over
+        its windows, `responses_handed_off` of `rpcs` those a thread
+        other than their connection's reactor queued and that reactor
+        wrote, `ring_dropped` events the ring was too full to take."""
         # One hold of _teardown_mu over every FFI read: close() frees
         # the feeder and the ring only after it has taken the handle
         # away under the same lock.
@@ -753,6 +779,7 @@ class H2FastFront:
             "items": st["window_items"] + st.get("feeder_served_rows", 0),
             "feeder_rpcs": st["feeder_front_rpcs"],
             "windows_overlapped": st.get("feeder_windows_overlapped", 0),
+            "serve_cpu_ns": st.get("feeder_serve_cpu_ns", 0),
             "responses_handed_off": st["responses_handed_off"],
             "feeder_ring_full": st.get("feeder_ring_full", 0),
             "feeder_declined": st.get("feeder_declined", 0),

@@ -60,7 +60,13 @@ from gubernator_tpu.parallel.mesh import (
     shard_map as _shard_map,
 )
 from gubernator_tpu.types import Behavior, RateLimitReq, RateLimitResp, Status
-from gubernator_tpu.utils.metrics import DurationStat, engine_stages, stage
+from gubernator_tpu.utils.metrics import (
+    CPU_MIN_ITEMS,
+    CPU_STAGES,
+    DurationStat,
+    engine_stages,
+    stage,
+)
 from gubernator_tpu.utils.tracing import span
 
 _I32 = np.int32
@@ -163,6 +169,10 @@ class ShardedDecisionEngine:
         # the router that exists only here and the columnar restore's
         # pieces (`_load_columns`), as DecisionEngine has them.
         self.stages = engine_stages(extra=("mesh.route", "engine.load"))
+        # True while the lock's holder serves a section that reads its
+        # CPU clock (apply_columnar); only CPU_STAGES, all of them under
+        # the lock, act on it.
+        self._cpu_section = False  # guberlint: guarded-by _lock
         # Rows of one columnar restore piece, and the lanes of its
         # scatter on every shard: as wide as a shard up to 2^20, so
         # that a restore compiles one `_load_step` (DecisionEngine's
@@ -481,7 +491,12 @@ class ShardedDecisionEngine:
         return fnv1a_64(key.encode()) % self.n_shards
 
     def _stage(self, name: str, work: bool = True) -> stage:
-        return stage(name, self.stages[name], work)
+        return stage(
+            name, self.stages[name], work,
+            # guberlint: ok lock — read for CPU_STAGES only, each of
+            # them entered under _lock
+            cpu=name in CPU_STAGES and self._cpu_section,
+        )
 
     def _host_state(self) -> BucketState:
         """The state's columns on the host, seen [n_shards,
@@ -1073,6 +1088,12 @@ class ShardedDecisionEngine:
         with self._lock:
             wait.stop()
             t_held = _time.monotonic()
+            # A section of CPU_MIN_ITEMS items or more reads the
+            # holder's CPU clock around the hold and in the CPU_STAGES
+            # inside it (utils/metrics.py); the same thread takes and
+            # lets go of the lock.
+            self._cpu_section = n >= CPU_MIN_ITEMS
+            c_held = _time.thread_time() if self._cpu_section else None
             try:
                 # guberlint: ok drift — sharded twin of engine.py's
                 # engine.columnar site
@@ -1086,8 +1107,10 @@ class ShardedDecisionEngine:
                     self.batches_total += 1
             finally:
                 # Histogram only: the parent of the leaf stages.
+                self._cpu_section = False
+                cpu = None if c_held is None else _time.thread_time() - c_held
                 self.stages["engine.lock_hold"].observe(
-                    _time.monotonic() - t_held
+                    _time.monotonic() - t_held, cpu
                 )
         return pending if want_async else pending.get()
 

@@ -39,7 +39,7 @@ from gubernator_tpu.cluster.health import backoff_delay
 from gubernator_tpu.cluster.multiregion import MultiRegionManager
 from gubernator_tpu.cluster.peer_client import PeerClient, PeerError
 from gubernator_tpu.config import BehaviorConfig, Config
-from gubernator_tpu.utils.metrics import DurationStat, stage
+from gubernator_tpu.utils.metrics import CPU_MIN_ITEMS, DurationStat, stage
 from gubernator_tpu.types import (
     MAX_BATCH_SIZE,
     Algorithm,
@@ -1024,8 +1024,11 @@ class V1Instance:
             return
         # On the RPC's own thread, before the engine lock: a numpy
         # grouping, then one native call over the unique keys with the
-        # interpreter lock released (utils/hotkeys.py; PERF.md §5).
-        with stage("service.hotkeys", self.stage_timers["service.hotkeys"]):
+        # interpreter lock released (utils/hotkeys.py; PERF.md §5).  A
+        # batch of CPU_MIN_ITEMS items or more reads the thread's CPU.
+        n = dec.n if idx is None else len(idx)
+        with stage("service.hotkeys", self.stage_timers["service.hotkeys"],
+                   cpu=n >= CPU_MIN_ITEMS):
             lim = np.asarray(dec.limit)
             elig = (
                 (np.asarray(dec.algo) == _TOKEN_I)

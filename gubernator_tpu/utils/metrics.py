@@ -21,7 +21,7 @@ import os
 import threading
 import time
 from math import frexp as _frexp
-from typing import TYPE_CHECKING, Iterable, Sequence
+from typing import TYPE_CHECKING, Iterable, Optional, Sequence
 
 from prometheus_client.core import (
     CounterMetricFamily,
@@ -89,19 +89,30 @@ class DurationStat:
     [2^i µs, 2^(i+1) µs), 36 buckets reaching ~19h, so one observe is
     a frexp + an increment.  Observations happen on flush/round
     boundaries (ms-scale work), so a tiny lock is fine; the
-    per-decision hot path never touches one."""
+    per-decision hot path never touches one.
 
-    __slots__ = ("count", "total", "max", "buckets", "exemplars", "_lock")
+    Beside the wall, a stat keeps the CPU its observer's thread spent
+    in the same intervals (`cpu_total` over `cpu_count` observations),
+    for the observations that read the thread's CPU clock (a `stage`
+    built with `cpu=True`; the engine lock's hold, read by hand).
+    Wall − CPU is then the interval's waiting — for the interpreter
+    lock, a core, a lock or the device."""
+
+    __slots__ = ("count", "total", "max", "buckets", "exemplars",
+                 "cpu_count", "cpu_total", "_lock")
 
     N_BUCKETS = 36
     _BASE = 1e-6  # bucket 0 lower bound: 1µs
 
     # guberlint: guard count, total, max, buckets, exemplars by _lock
+    # guberlint: guard cpu_count, cpu_total by _lock
 
     def __init__(self) -> None:
         self.count = 0
         self.total = 0.0
         self.max = 0.0
+        self.cpu_count = 0
+        self.cpu_total = 0.0
         self.buckets = [0] * self.N_BUCKETS
         # bucket index -> (trace_id, seconds): the LAST sampled trace
         # that landed in the bucket (bounded by N_BUCKETS entries by
@@ -124,7 +135,9 @@ class DurationStat:
     def bucket_bounds(cls, i: int) -> tuple:
         return (cls._BASE * (1 << i), cls._BASE * (1 << (i + 1)))
 
-    def observe(self, seconds: float) -> None:
+    def observe(self, seconds: float, cpu: Optional[float] = None) -> None:
+        """One interval's wall `seconds`; `cpu`, where given, is the
+        observing thread's CPU seconds inside the same interval."""
         b = self.bucket_of(seconds)
         ex = None
         # Exemplar capture: observations happen at flush/window
@@ -144,6 +157,9 @@ class DurationStat:
             self.buckets[b] += 1
             if ex is not None:
                 self.exemplars[b] = ex
+            if cpu is not None:
+                self.cpu_count += 1
+                self.cpu_total += cpu
 
     def observe_bucket_counts(self, counts, total=None, top=None) -> None:
         """Merge pre-bucketed counts (index-aligned with N_BUCKETS) —
@@ -261,20 +277,45 @@ class DurationStat:
     def snapshot_ms(self, digits: int = 3) -> dict:
         """The canonical {count, mean_ms, p50_ms, p99_ms, max_ms}
         rendering — shared by stage_budget(), /debug/vars, and the
-        bench artifacts so the shape cannot drift between them."""
+        bench artifacts so the shape cannot drift between them — plus
+        `cpu_ms`, the mean CPU of an observation, where CPU was read."""
         with self._lock:
             count = self.count
             mean_s = self.total / count if count else 0.0
             max_s = self.max
+            cpu_count, cpu_total = self.cpu_count, self.cpu_total
         # The quantiles take the lock themselves; an observation
         # landing between the reads skews one scrape by one event.
-        return {
+        out = {
             "count": count,
             "mean_ms": round(mean_s * 1e3, digits),
             "p50_ms": round(self.p50() * 1e3, digits),
             "p99_ms": round(self.p99() * 1e3, digits),
             "max_ms": round(max_s * 1e3, digits),
         }
+        if cpu_count:
+            out["cpu_ms"] = round(cpu_total / cpu_count * 1e3, digits)
+        return out
+
+    def cpu_snapshot(self) -> tuple:
+        """(observations that carried a CPU reading, their CPU
+        seconds), one consistent pair."""
+        with self._lock:
+            return self.cpu_count, self.cpu_total
+
+
+# Thread CPU beside the wall (PERF.md §3).  The thread CPU clock is a
+# system call where the kernel gives it no fast path — 5.9 µs a read on
+# a v5e host, ~20 µs in a loaded daemon there, against 0.07 µs for the
+# wall clock — and on that host it advances in 10 ms ticks charged to
+# whichever thread runs at the tick.  So it is read only where a
+# benchmark metric reads the result: the engine lock's hold and the
+# stages of CPU_STAGES, and only in a serial section (or, for the
+# sketch, an RPC or window) of at least CPU_MIN_ITEMS items.  There
+# every observation reads it, so a window's sum rests on hundreds of
+# ticks; a single-item RPC of the herd pays no read.
+CPU_STAGES = frozenset(("engine.pack", "device.launch", "service.hotkeys"))
+CPU_MIN_ITEMS = 512
 
 
 # The served path's stage vocabulary (OBSERVABILITY.md §3, PERF.md §3):
@@ -324,7 +365,9 @@ def _trace_annotation(name: str):
 
 class stage:
     """One measured site of the served path, three sinks under one
-    name: it always observes `stat` (two clock reads and one observe);
+    name: it always observes `stat` — its wall (two reads of the wall
+    clock and one observe) and, with `cpu`, the thread's CPU inside it
+    (two reads of the thread's CPU clock);
     while the tracer is active it is a child span of the RPC's tree
     (utils/tracing.py, the flight recorder's feed); and when `work`
     is true the body runs under a `jax.profiler.TraceAnnotation`.
@@ -340,12 +383,15 @@ class stage:
     `with` cannot: an interval that ends inside the block that follows
     it (waiting for a lock that a `with` then holds)."""
 
-    __slots__ = ("_name", "_stat", "_work", "_cm", "span", "_ann", "_t0")
+    __slots__ = ("_name", "_stat", "_work", "_cpu", "_cm", "span", "_ann",
+                 "_t0", "_c0")
 
-    def __init__(self, name: str, stat: DurationStat, work: bool = True):
+    def __init__(self, name: str, stat: DurationStat, work: bool = True,
+                 cpu: bool = False):
         self._name = name
         self._stat = stat
         self._work = work
+        self._cpu = cpu
         self._cm = None
         self.span = None  # the open request span, for attributes
         self._ann = None
@@ -354,7 +400,9 @@ class stage:
         # The clock first and last: what the instrumentation itself
         # costs belongs to the stage it instruments, not to nobody
         # (the leaf stages under the engine lock tile engine.lock_hold).
+        # The wall's interval encloses the CPU clock's.
         self._t0 = time.monotonic()
+        self._c0 = time.thread_time() if self._cpu else None
         if tracing.active():
             self._cm = tracing.span(self._name)
             self.span = self._cm.__enter__()
@@ -369,7 +417,8 @@ class stage:
             self._ann.__exit__(exc_type, exc, tb)
         # Observed inside the span: the histogram's exemplar links the
         # bucket to this RPC's trace.
-        self._stat.observe(time.monotonic() - self._t0)
+        cpu = None if self._c0 is None else time.thread_time() - self._c0
+        self._stat.observe(time.monotonic() - self._t0, cpu)
         if self._cm is not None:
             self._cm.__exit__(exc_type, exc, tb)
 
@@ -378,6 +427,76 @@ class stage:
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.stop(exc_type, exc, tb)
         return False
+
+
+# The daemon's CPU by thread role (/debug/vars `threads_cpu_s`,
+# `threads_n`).  A role names what a thread is; no role holds a dot
+# (the benchmark's readers split /debug/vars paths on dots).  The front's
+# C threads report their kernel ids (H2FastFront.thread_ids); Python
+# threads are known by name; `other` is every other task of the process,
+# exited ones included, so the roles add up to `all`.
+THREAD_ROLES = (
+    "front_serve", "front_reactor", "front_dispatch", "grpc_listener",
+    "native_events", "sweep", "other",
+)
+_PY_THREAD_ROLES = (
+    ("guber-grpc", "grpc_listener"),  # grpc-python's executor workers
+    ("guber-native-events", "native_events"),  # utils/native_events.py
+    ("guber-sweep", "sweep"),  # daemon.py's sweeper
+)
+
+
+def _cpu_ticks(stat_path: str) -> Optional[int]:
+    """utime + stime of one /proc stat file, in clock ticks; None for a
+    task that has exited."""
+    try:
+        with open(stat_path) as f:
+            fields = f.read().rsplit(")", 1)[1].split()
+    except OSError:
+        return None
+    return int(fields[11]) + int(fields[12])
+
+
+def threads_cpu(native_tids: dict) -> dict:
+    """{"threads_cpu_s": {role: seconds, ..., "all": seconds},
+    "threads_n": {role: live threads}} for this process, read now from
+    /proc/self/task/<tid>/stat (utime + stime ÷ SC_CLK_TCK, so to a
+    tick a thread).  `native_tids` is {role: [tid, ...]} of threads
+    Python cannot name.  `all` is /proc/self/stat: every task, live or
+    exited; `other` is what the named roles leave of it.  A role with
+    no thread reads 0."""
+    role_of = {}
+    for role, tids in native_tids.items():
+        for tid in tids:
+            role_of[tid] = role
+    for t in threading.enumerate():
+        if t.native_id is None or t.native_id in role_of:
+            continue
+        for prefix, role in _PY_THREAD_ROLES:
+            if t.name.startswith(prefix):
+                role_of[t.native_id] = role
+                break
+    ticks = dict.fromkeys(THREAD_ROLES, 0)
+    count = dict.fromkeys(THREAD_ROLES, 0)
+    for tid, role in role_of.items():
+        n = _cpu_ticks(f"/proc/self/task/{tid}/stat")
+        if n is not None:
+            ticks[role] += n
+            count[role] += 1
+    # The process last: every task's ticks read above are inside it.
+    total = _cpu_ticks("/proc/self/stat")
+    try:
+        live = len(os.listdir("/proc/self/task"))
+    except OSError:
+        live = threading.active_count()
+    hz = os.sysconf("SC_CLK_TCK")
+    if total is None:
+        total = int(time.process_time() * hz)
+    ticks["other"] = max(0, total - sum(ticks.values()))
+    count["other"] = max(0, live - sum(count.values()))
+    cpu_s = {role: n / hz for role, n in ticks.items()}
+    cpu_s["all"] = total / hz
+    return {"threads_cpu_s": cpu_s, "threads_n": count}
 
 
 class InstanceCollector(Collector):
@@ -854,6 +973,25 @@ class InstanceCollector(Collector):
         )
         for stage, stat in inst.stage_timers.items():
             s.add_metric([stage], count_value=stat.count, sum_value=stat.total)
+        yield s
+
+        # The thread CPU of the observations that read it (the engine
+        # lock's hold and CPU_STAGES, in sections of CPU_MIN_ITEMS
+        # items or more).  Wall − CPU is the stage's waiting: for the
+        # interpreter lock, a core, a lock or the device.
+        s = SummaryMetricFamily(
+            "gubernator_stage_cpu_seconds",
+            "Thread CPU seconds per pipeline stage, read inside the "
+            "same intervals as gubernator_stage_duration, over the "
+            "observations that read the thread's CPU clock (count): "
+            "the engine lock's hold, engine.pack, device.launch and "
+            "service.hotkeys, in sections of 512 items or more.",
+            labels=["stage"],
+        )
+        for stage, stat in inst.stage_timers.items():
+            n, cpu = stat.cpu_snapshot()
+            if n:
+                s.add_metric([stage], count_value=n, sum_value=cpu)
         yield s
 
         # Streaming stage quantiles (DurationStat's fixed-bucket

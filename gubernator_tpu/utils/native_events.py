@@ -36,7 +36,8 @@ log = logging.getLogger("gubernator_tpu.native_events")
 
 # kind -> stage name (h2_server.cpp kEvNativeServe/kEvWindowWait/
 # kEvWindowServe/kEvRpcTotal; columnar_feeder.cpp kEvFeederPack/
-# kEvFeederRingWait/kEvFeederServe/kEvFeederScatter/kEvFeederInflight).
+# kEvFeederRingWait/kEvFeederServe/kEvFeederScatter/kEvFeederInflight/
+# kEvFeederWindowWait).
 STAGES = {
     1: "native_serve",
     2: "window_wait",
@@ -72,6 +73,12 @@ STAGES = {
     # rows were waiting, two clock readings where none were (items =
     # its rows).  None for a window answered with finished columns.
     12: "feeder_inflight",
+    # Per submitted feeder window: the serve thread's time blocked in
+    # its two waits (for a window to hold rows, then the group-commit
+    # window) since its last submit — 0 for a window taken while
+    # another was in flight (items = its RPCs).  A wait: histogram only.
+    # With feeder_serve and feeder_scatter it tiles the thread's cycle.
+    13: "feeder_window_wait",
 }
 
 # Span stubs recorded per drain tick, bounded: under a 9k/s native

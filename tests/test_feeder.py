@@ -500,6 +500,75 @@ def test_a_window_worth_a_dispatch_is_submitted_before_the_read():
         feeder.close()
 
 
+def _ring():
+    import ctypes
+
+    lib = h2_fast.load()
+    return lib, ctypes.c_void_p(lib.evr_create(1024))
+
+
+def _drain(lib, ring, kind):
+    import ctypes
+
+    out = np.zeros(4 * 1024, dtype=np.int64)
+    n = lib.evr_drain(ring, out.ctypes.data_as(ctypes.c_void_p), 1024)
+    rec = out[: 4 * n].reshape(n, 4)
+    return rec[rec[:, 0] == kind]
+
+
+def test_every_submitted_window_records_its_wait_and_the_serve_cpu():
+    """One feeder_window_wait event (kind 13) a submitted window, items
+    its RPCs, the time the serve thread sat in its waits before taking
+    it; the serve thread's CPU in its Python entries grows with the
+    windows it enters."""
+    lib, ring = _ring()
+    pair = _Pair()
+    feeder = pair.feeder(flush_rows=64, window_s=0.5)
+    feeder.attach_ring(ring)
+    try:
+        cpu = [feeder.stats()["feeder_serve_cpu_ns"]]
+        for n, tag in enumerate("abc", start=1):
+            for i in range(n):
+                assert feeder.pack(_body8(f"{tag}{i}")) == 8
+            time.sleep(0.02)  # the serve thread waits in the window
+            feeder.flush()
+            st = feeder.stats()
+            assert st["feeder_windows"] == n
+            cpu.append(st["feeder_serve_cpu_ns"])
+        assert cpu[0] < cpu[1] < cpu[2] < cpu[3], cpu
+        waits = _drain(lib, ring, 13)
+        assert waits[:, 3].tolist() == [1, 2, 3]  # RPCs a window
+        assert (waits[:, 2] >= 10_000_000).all(), waits  # >= 10 ms blocked
+    finally:
+        feeder.close()
+        lib.evr_free(ring)
+
+
+def test_a_window_taken_with_another_in_flight_waited_for_nothing():
+    """Window 2 is submitted while window 1 is in flight: neither of
+    the serve thread's waits ran before it, so its event reads 0."""
+    lib, ring = _ring()
+    pair = _Pair(gate=threading.Event())
+    feeder = pair.feeder(flush_rows=32, window_s=5.0)
+    feeder.attach_ring(ring)
+    try:
+        for tag in "abcd":
+            assert feeder.pack(_body8(tag)) == 8  # the fourth seals window 1
+        assert pair.entered.wait(timeout=10)
+        assert feeder.pack(_body8("e", 4)) == 4
+        pair.gate.set()
+        deadline = time.monotonic() + 5.0
+        while feeder.stats()["feeder_windows"] < 2 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert feeder.stats()["feeder_windows_overlapped"] == 1
+        waits = _drain(lib, ring, 13)
+        assert waits[:, 3].tolist() == [4, 1]
+        assert waits[0, 2] > 0 and waits[1, 2] == 0
+    finally:
+        feeder.close()
+        lib.evr_free(ring)
+
+
 def test_a_thin_window_keeps_its_group_commit_wait():
     """Window 2 holds less than an eighth of flush_rows when window 1's
     submit returns — a herd's straggler: submitted now it would split

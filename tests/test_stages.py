@@ -6,6 +6,7 @@ import glob
 import json
 import os
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -17,6 +18,7 @@ from gubernator_tpu.daemon import spawn_daemon
 from gubernator_tpu.net import wire_codec
 from gubernator_tpu.net.pb import gubernator_pb2 as pb
 from gubernator_tpu.utils import tracing
+from gubernator_tpu.utils import metrics
 from gubernator_tpu.utils.metrics import ENGINE_STAGES, DurationStat, stage
 
 METHOD = "/pb.gubernator.V1/GetRateLimits"
@@ -120,6 +122,85 @@ def test_stage_is_a_child_span_while_tracing():
     assert intern.attributes == {"keys": 3} and stat.count == 2
 
 
+def _spin(seconds: float) -> None:
+    end = time.monotonic() + seconds
+    while time.monotonic() < end:
+        pass
+
+
+def test_a_stage_that_spins_reads_its_wall_as_cpu():
+    stat = DurationStat()
+    with stage("engine.pack", stat, cpu=True):
+        _spin(0.05)
+    n, cpu = stat.cpu_snapshot()
+    assert n == stat.count == 1
+    # the thread may lose its core for a moment on a loaded machine
+    assert 0.5 * stat.total <= cpu <= stat.total, (cpu, stat.total)
+
+
+def test_a_stage_that_sleeps_reads_no_cpu():
+    stat = DurationStat()
+    with stage("device.readback", stat, work=False, cpu=True):
+        time.sleep(0.05)
+    n, cpu = stat.cpu_snapshot()
+    assert n == 1 and stat.total >= 0.05
+    assert cpu < 0.005, cpu
+
+
+def test_a_stage_waiting_for_the_interpreter_lock_reads_cpu_under_its_wall():
+    """A second thread spins in Python, so each time the stage's body
+    lets go of the interpreter lock it queues to get it back: the wall
+    holds that queue, the thread's CPU clock does not."""
+    stop = threading.Event()
+
+    def spinner():
+        while not stop.is_set():
+            pass
+
+    t = threading.Thread(target=spinner, daemon=True)
+    t.start()
+    stat = DurationStat()
+    try:
+        with stage("service.hotkeys", stat, cpu=True):
+            for _ in range(20):
+                time.sleep(0)  # let go of the lock, then queue for it
+    finally:
+        stop.set()
+        t.join()
+    _n, cpu = stat.cpu_snapshot()
+    assert stat.total > 0.01, stat.total
+    assert cpu < 0.5 * stat.total, (cpu, stat.total)
+
+
+def test_a_stage_reads_the_cpu_clock_only_where_asked(monkeypatch):
+    """Without `cpu` a stage never calls the thread's CPU clock (a
+    system call on some hosts); with it, each observation reads it
+    twice and carries its own reading."""
+    reads = []
+    real = time.thread_time
+    monkeypatch.setattr(metrics.time, "thread_time",
+                        lambda: reads.append(1) or real())
+    plain, read = DurationStat(), DurationStat()
+    for _ in range(3):
+        with stage("engine.intern", plain):
+            _spin(0.002)
+        with stage("engine.pack", read, cpu=True):
+            _spin(0.002)
+    assert plain.cpu_snapshot() == (0, 0.0) and plain.count == 3
+    assert len(reads) == 6
+    n, cpu = read.cpu_snapshot()
+    assert n == read.count == 3 and 0 < cpu <= read.total
+
+
+def test_a_stat_observed_without_cpu_exports_none():
+    stat = DurationStat()
+    stat.observe(0.002)
+    assert stat.cpu_snapshot() == (0, 0.0)
+    assert "cpu_ms" not in stat.snapshot_ms()
+    stat.observe(0.002, 0.001)
+    assert stat.snapshot_ms()["cpu_ms"] == 1.0
+
+
 def test_every_engine_stage_is_registered(daemon):
     timers = daemon.instance.stage_timers
     assert set(ENGINE_STAGES) <= set(timers)
@@ -207,6 +288,52 @@ def test_pack_slices_interleave_with_the_dispatches(engine_name, path):
         return  # the pump queues the rounds and fuses them at its flush
     # a pack slice between every two dispatches
     assert "device.launch device.launch" not in " ".join(order), order
+
+
+def test_lock_hold_reads_its_cpu_after_a_served_batch(daemon):
+    """The engine lock's hold is observed by hand, not by `stage`: in a
+    section of CPU_MIN_ITEMS items or more its CPU rides beside its
+    wall in both engines, and so does that of the CPU stages inside it
+    and of the RPC's sketch pass; /metrics and /debug/vars carry it."""
+    timers = daemon.instance.stage_timers
+    hold = timers["engine.lock_hold"]
+    n0, cpu0 = hold.cpu_snapshot()
+    wall0, count0 = hold.total, hold.count
+    read0 = {name: timers[name].cpu_snapshot()[0] for name in metrics.CPU_STAGES}
+    daemon.call(payload("batch", salt=4), timeout=30)
+    n, cpu = hold.cpu_snapshot()
+    assert n - n0 == hold.count - count0 >= 1
+    assert 0.0 < cpu - cpu0 <= hold.total - wall0 + 0.001
+    for name in metrics.CPU_STAGES:
+        assert timers[name].cpu_snapshot()[0] > read0[name], name
+    base = f"http://{daemon.http_address}"
+    body = urllib.request.urlopen(f"{base}/metrics", timeout=10).read().decode()
+    for name in ("engine.lock_hold", *sorted(metrics.CPU_STAGES)):
+        line = next(
+            ln for ln in body.splitlines()
+            if ln.startswith(f'gubernator_stage_cpu_seconds_count{{stage="{name}"}}')
+        )
+        assert float(line.split()[-1]) == timers[name].cpu_snapshot()[0] > 0, name
+    assert 'gubernator_stage_cpu_seconds_count{stage="engine.intern"}' not in body
+    budget = json.loads(urllib.request.urlopen(
+        f"{base}/debug/vars", timeout=10).read())["stage_budget"]
+    assert budget["engine.lock_hold"]["cpu_ms"] > 0
+    assert "cpu_ms" not in budget["listener.queue_wait"]  # another thread's wait
+
+
+def test_a_single_item_rpc_reads_no_cpu_clock(daemon):
+    """Below CPU_MIN_ITEMS neither the hold, nor a stage inside it, nor
+    the sketch's pass reads the thread's CPU clock: the herd's
+    single-item RPCs pay no system call for it."""
+    daemon.call(payload("single", salt=5), timeout=30)  # shapes warm
+    timers = daemon.instance.stage_timers
+    names = ("engine.lock_hold", *sorted(metrics.CPU_STAGES))
+    before = {name: timers[name].cpu_snapshot() for name in names}
+    count = timers["engine.lock_hold"].count
+    for i in range(3):
+        daemon.call(payload("single", salt=10 + i), timeout=30)
+    assert timers["engine.lock_hold"].count >= count + 3
+    assert {name: timers[name].cpu_snapshot() for name in names} == before
 
 
 def test_sweep_is_a_stage(daemon):
