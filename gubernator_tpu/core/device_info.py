@@ -72,6 +72,12 @@ def describe(engine) -> dict:
             "batches_total": engine.batches_total,
             "rounds_total": engine.rounds_total,
             "dispatches_total": engine.dispatches_total,
+            # the dense engine's alone: the sharded one does not count it
+            **(
+                {"row_write_dispatches_total": engine.row_write_dispatches_total}
+                if hasattr(engine, "row_write_dispatches_total")
+                else {}
+            ),
             "over_limit_total": engine.over_limit_total,
             "evictions_total": sum(t.evictions for t in tables),
             "unexpired_evictions_total": sum(

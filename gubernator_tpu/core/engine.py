@@ -39,7 +39,9 @@ from gubernator_tpu.gregorian import (
 )
 from gubernator_tpu.ops.bucket_kernel import (
     BucketState,
+    ProbeVerdict,
     SlotRecord,
+    _scatter_form,
     clear_occupied,
     collapsed_step,
     fused_step,
@@ -510,6 +512,10 @@ class DecisionEngine:
         # stacks) — the numerator of the dispatches-per-batch gauge the
         # fused plane pins to 1 in steady state (PERF.md §24).
         self.dispatches_total = 0  # guberlint: guarded-by _lock
+        # Of those, the step dispatches whose program writes the state
+        # by rows (`write_form`): the share of the steps that take the
+        # row write on this table.
+        self.row_write_dispatches_total = 0  # guberlint: guarded-by _lock
         # Rows restored through load(), either path.
         self.rows_loaded_total = 0  # guberlint: guarded-by _lock
         # Rows of one columnar restore scatter (_load_columns): as wide
@@ -534,6 +540,25 @@ class DecisionEngine:
         from gubernator_tpu.core.readback import ReadbackCombiner
 
         self.readback = ReadbackCombiner()
+        self._platform = (
+            device if device is not None else jax.devices()[0]
+        ).platform
+        self._record_write_forms()
+
+    def write_form(self, width: int) -> str:
+        """How this engine's step program writes the state at `width`
+        lanes (`bucket_kernel._scatter_form`: "pass", "rows" or
+        "loop"), read off the two shapes and the platform it serves on."""
+        return _scatter_form(self.capacity, width, self._platform)
+
+    def _record_write_forms(self) -> None:
+        """The write form at every width of the ladder up to
+        `max_kernel_width`, under `probes` (no fault: always ok)."""
+        forms, width = [], 64
+        while width <= self.max_kernel_width:
+            forms.append(f"{width}:{self.write_form(width)}")
+            width *= 2
+        self.probes["write_form"] = ProbeVerdict(True, " ".join(forms))
 
     def _stage(self, name: str, work: bool = True) -> stage:
         return stage(
@@ -558,6 +583,8 @@ class DecisionEngine:
         with self._stage("device.launch"):
             self._state, pout = program(self._state, pin)
             self.dispatches_total += 1
+            if self.write_form(pin.shape[-1]) == "rows":
+                self.row_write_dispatches_total += 1
         return pout
 
     # ------------------------------------------------------------------
@@ -1672,6 +1699,7 @@ class DecisionEngine:
                 self.batches_total,
                 self.rounds_total,
                 self.dispatches_total,
+                self.row_write_dispatches_total,
                 self.table.hits,
                 self.table.misses,
                 self.table.evictions,
@@ -1765,6 +1793,7 @@ class DecisionEngine:
                     self.batches_total,
                     self.rounds_total,
                     self.dispatches_total,
+                    self.row_write_dispatches_total,
                     *saved_table,
                 ) = saved
                 t = self.table
@@ -1787,6 +1816,7 @@ class DecisionEngine:
                 # the device runs chunk k — compiled on demand at 100 M
                 # rows it cost a client its deadline (chip, PR 32).
                 self.max_kernel_width = min(self.max_kernel_width, max_width)
+                self._record_write_forms()
             finally:
                 # Exception-safety: a failed warmup (backend or
                 # compile error) must not leave persistence disabled.

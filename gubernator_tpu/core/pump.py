@@ -308,6 +308,8 @@ class StepPump:
         with eng._stage("device.launch"):
             eng._state, pouts = step(eng._state, pins)
             eng.dispatches_total += 1
+            if eng.write_form(shape[-1]) == "rows":
+                eng.row_write_dispatches_total += 1
         eng.round_duration.observe(_time.monotonic() - t0)
         pouts.copy_to_host_async()  # background transfer starts now
         self.fused_rounds += k

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from gubernator_tpu.ops import row_write
 from gubernator_tpu.types import Algorithm, Behavior, Status
 
 _I64 = jnp.int64
@@ -339,32 +340,56 @@ def split_remf(v: jax.Array) -> tuple[jax.Array, jax.Array]:
     return wc.astype(_I32), jnp.floor((v - w) * (2.0**32)).astype(_U32)
 
 
-# How a scatter into a state column is declared to XLA.  With
-# `indices_are_sorted` + `unique_indices` XLA:TPU merges the updates
-# into ONE STREAMING PASS over the whole column, ~12 ps a row of the
-# table whatever the width: 29-69 µs at 1 M rows, where round 2 measured
-# it "~200x faster" than the loop (PERF_HISTORY §4), and 1.18-1.24 ms a
-# column, 14.3 ms a twelve-column step, at 100 M rows (ledger, PR 24).
-# Without them it is, on a large table, an in-place loop over the lanes:
-# 15 / 109 / 748 µs at 64 / 1,024 / 8,192 lanes and 100 M rows; on a
-# small one XLA finds the pass's price by itself (1 M rows: equal at
-# every width).  The hints are a promise about the indices, never a
-# requirement, so each scatter makes it only where the pass is the
-# cheaper form — a rule on the two shapes the program is compiled for,
-# no setting — which was the cheaper form, or within 3 µs of it, at all twelve
-# points of 1 M-100 M rows × 64-8,192 lanes (chip run, PR 25,
-# scripts/probe_state_access.py; PERF.md §6).  Gathers are no pass in
-# either form (15-31 µs) and keep the hints.
+# How a step writes its slots into a state column: one of three forms,
+# chosen by the two shapes the program is compiled for and the platform
+# it is lowered for — no setting.
+#
+# * **pass** — `column.at[slots].set` with `indices_are_sorted` +
+#   `unique_indices`: XLA:TPU merges the updates into ONE STREAMING
+#   PASS over the whole column, ~12 ps a row of the table whatever the
+#   width: 29-69 µs at 1 M rows, where round 2 measured it "~200x
+#   faster" than the loop (PERF_HISTORY §4), and 1.18-1.24 ms a column,
+#   14.3 ms a twelve-column step, at 100 M rows on a v5e (PERF.md).
+# * **loop** — the same scatter without the hints: on a large table an
+#   in-place loop over the lanes, padding included: 15 / 109 / 748 µs at
+#   64 / 1,024 / 8,192 lanes and 100 M rows, twelve times a step; on a
+#   small one XLA finds the pass's price by itself (1 M rows: equal at
+#   every width).
+# * **rows** — `row_write.write_rows`: each 128-word row the step
+#   touched is written once, by one DMA, from one Pallas kernel for all
+#   twelve columns: 16.6 / 63.6 / 184.7 µs a column at 64 / 1,024 /
+#   4,096 lanes against the loop's 18.1 / 108.1 / 384.7 on one 100 M-row
+#   column of a v5e (scripts/probe_state_access.py; PERF.md §6).
+#
+# The pass where `rows < 8,192 × lanes` — the cheaper form, or within
+# 3 µs of the loop, at all twelve points of 1 M-100 M rows × 64-8,192
+# lanes on a v5e (scripts/probe_state_access.py; PERF.md §6) —
+# which is every table of the tests and of round 2.  Above it the rows
+# where the column is whole rows (`rows % 128 == 0`) and the program is
+# lowered for a TPU (`lax.platform_dependent`: the kernel is traced
+# everywhere and lowered there alone), the loop otherwise.  Gathers are
+# no pass in either form (15-31 µs) and keep the hints.
 _SCATTER_PASS_ROWS_PER_LANE = 8192
+
+
+def _scatter_form(rows: int, lanes: int, platform: str = "tpu") -> str:
+    """"pass", "rows" or "loop": how a step writes `lanes` sorted,
+    unique slots into a column of `rows` rows, lowered for `platform`."""
+    # guberlint: ok trace — two shapes and a platform's name: static under trace
+    if rows < _SCATTER_PASS_ROWS_PER_LANE * lanes:
+        return "pass"
+    # guberlint: ok trace — two shapes and a platform's name: static under trace
+    if rows % row_write.ROW == 0 and platform == "tpu":
+        return "rows"
+    return "loop"
 
 
 def _scatter_hints(rows: int, lanes: int) -> dict:
     """Keyword hints for `column.at[slots].set(...)`, `slots` sorted
-    and unique: `column` has `rows` rows, `slots` has `lanes` lanes."""
-    pass_is_cheaper = rows < _SCATTER_PASS_ROWS_PER_LANE * lanes
-    return dict(
-        indices_are_sorted=pass_is_cheaper, unique_indices=pass_is_cheaper
-    )
+    and unique: the pass or the loop of `_scatter_form` (the clear and
+    the restore have no row form)."""
+    hinted = _scatter_form(rows, lanes) == "pass"
+    return dict(indices_are_sorted=hinted, unique_indices=hinted)
 
 
 # guberlint: shapes meta [capacity] fixed at engine build; slots [C], C in the pow2 clear ladder (warmup)
@@ -791,31 +816,44 @@ def _scatter_values(
     """Scatter of computed slot values into the state — the write half
     of every step program.
 
-    The steps gather from and scatter into the same donated buffers.
+    `slot` is sorted with distinct out-of-range padding, which is
+    dropped.  The form is `_scatter_form`'s: on a small table the
+    hinted pass a column; on a large one, lowered for a TPU, the row
+    write (`row_write.write_rows`: one DMA a touched row a column, one
+    kernel for the twelve), else the unhinted loop over the lanes.
+
+    The steps gather from and write into the same donated buffers.
     Where XLA's copy-insertion answers that by cloning every state
     array (measured once, on an early backend: 18 full-capacity copies,
     ~41 ms at 2 M slots, O(capacity) a batch) a step is unusable, which
     is why the engines compile `fused_step_ok`'s probe at start and, on
     an accelerator, refuse to serve on a no.
-    `slot` is sorted with distinct out-of-range padding → the hints
-    hold wherever `_scatter_hints` gives them (a small table: one
-    streaming pass a column; at 100 M rows that pass was 1.19 ms a
-    column and the whole of the step's 14 ms, so there the scatter
-    goes without them, ~0.1 µs a lane); out-of-range (padding) lanes
-    are dropped.
     """
-
-    def sc(arr, v):
-        return arr.at[slot].set(
-            v.astype(arr.dtype),
-            mode="drop",
-            **_scatter_hints(arr.shape[0], slot.shape[0]),
-        )
-
-    words = encode_slot_values(vals)
-    return BucketState(
-        *(sc(arr, w) for arr, w in zip(state, words))
+    words = tuple(
+        w.astype(c.dtype) for c, w in zip(state, encode_slot_values(vals))
     )
+
+    def scatter(hinted: bool):
+        def run(columns, slot, words):
+            return tuple(
+                c.at[slot].set(
+                    w, mode="drop", indices_are_sorted=hinted,
+                    unique_indices=hinted,
+                )
+                for c, w in zip(columns, words)
+            )
+
+        return run
+
+    form = _scatter_form(state.meta.shape[0], slot.shape[0])
+    if form == "rows":
+        return BucketState(
+            *jax.lax.platform_dependent(
+                tuple(state), slot, words,
+                tpu=row_write.write_rows, default=scatter(False),
+            )
+        )
+    return BucketState(*scatter(form == "pass")(tuple(state), slot, words))
 
 
 # ---------------------------------------------------------------------------

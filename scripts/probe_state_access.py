@@ -1,4 +1,4 @@
-"""Step 0 of ISSUE 25: which access of a step is the pass over the table?
+"""What each access of a step to the state costs on the chip.
 
 At 100 M rows a step costs 14 ms whatever its width (ledger, PR 24).
 This times, on the chip, one `u32[PROBE_ROWS]` column (100 M), slots
@@ -15,9 +15,11 @@ is written, widths 64 and 1,024:
 
 then (`probe_crossover`) b against e's scatter at 1 M, 8 M, 25 M and
 100 M rows and widths 64, 1,024 and 8,192 — what
-`ops/bucket_kernel.py` `_scatter_hints` chooses between, and where —
-and the step programs themselves (`_collapsed_step_core` at 1,024,
-`_uniform_step_core` at 64) with each form of the scatter, and reads
+`ops/bucket_kernel.py` `_scatter_form` chooses between, and where —
+(`probe_row_write`) the row write (`ops/row_write.py`: one DMA a
+touched row) beside b and e at widths 64, 1,024 and 4,096, and the
+step programs themselves (`_collapsed_step_core` at 1,024 and 4,096,
+`_uniform_step_core` at 64) with each of the three forms, and reads
 from the compiled collapsed step what touches the table.  Every timing
 is K iterations inside ONE program (a scan over K index sets), so a
 dispatch's host cost is not in it.  One JSON line; the full record also
@@ -44,6 +46,7 @@ from jax import lax
 
 import gubernator_tpu  # noqa: F401 — x64 config
 from gubernator_tpu.ops import bucket_kernel as bk
+from gubernator_tpu.ops import row_write
 
 N = int(os.environ.get("PROBE_ROWS", "100000000"))
 # PROBE_REHEARSE=1 debugs the script off the chip (tiny PROBE_ROWS); its
@@ -231,7 +234,7 @@ CROSSOVER_WIDTHS = (64, 1024, 8192)
 
 def probe_crossover(rec):
     """us a word scatter with the hints (`pass`) and without (`loop`),
-    and what `_scatter_hints` chooses there."""
+    and what `_scatter_form` chooses there."""
     rng = np.random.default_rng(27)
     out = rec.setdefault("crossover", {})
     for n in CROSSOVER_ROWS if not _REHEARSE else (N,):
@@ -240,13 +243,58 @@ def probe_crossover(rec):
             idx = jnp.asarray(_slots(rng, w, "uniform", n))
             vals = jnp.asarray(
                 rng.integers(0, 2**32, size=(K, w), dtype=np.uint32))
-            r = out[f"rows{n}.w{w}"] = {
-                "rule": "pass" if all(bk._scatter_hints(n, w).values()) else "loop"}
+            r = out[f"rows{n}.w{w}"] = {"rule": bk._scatter_form(n, w)}
             r["pass_us"], col = time_writes(
                 lambda c, i, v: word_scatter(c, i, v, **_FLAGS),
                 col, idx, vals)
             r["loop_us"], col = time_writes(word_scatter, col, idx, vals)
         del col
+
+
+ROW_WRITE_WIDTHS = (64, 1024, 4096)
+
+
+def probe_row_write(rec):
+    """us a write of `w` sorted, unique slots into one column: the
+    hinted pass (b), the loop (e) and the row write (r), K in one
+    program, the column donated; and whether r's words are there."""
+    rng = np.random.default_rng(38)
+    out = rec.setdefault("row_write", {})
+    col = jnp.asarray(rng.integers(0, 2**32, size=N, dtype=np.uint32))
+
+    def rows(c, i, v):
+        return row_write.write_rows((c,), i, (v,), interpret=_REHEARSE)[0]
+
+    for w in ROW_WRITE_WIDTHS:
+        r = out[f"w{w}"] = {}
+        for kind in ("uniform", "skewed"):
+            # a few padding lanes, as served
+            slots = _slots(rng, w, kind)
+            slots[:, w - w // 32:] = N + np.arange(w // 32, dtype=np.int32)
+            idx = jnp.asarray(slots)
+            vals = jnp.asarray(
+                rng.integers(0, 2**32, size=(K, w), dtype=np.uint32))
+            for form, write in (
+                ("b_pass", lambda c, i, v: word_scatter(c, i, v, **_FLAGS)),
+                ("e_loop", word_scatter),
+                ("r_rows", rows),
+            ):
+                try:
+                    r[f"{kind}.{form}_us"], col = time_writes(
+                        write, col, idx, vals)
+                except Exception as e:  # noqa: BLE001 — a refusal is a finding
+                    r[f"{kind}.{form}_us"] = bk.first_line(e)
+                    if col.is_deleted():
+                        col = jnp.asarray(
+                            rng.integers(0, 2**32, size=N, dtype=np.uint32))
+            # the row write ran last: the last index set's words are there
+            live = slots[K - 1] < N
+            got = np.asarray(word_gather(col, idx[K - 1], **_FLAGS))
+            r[f"{kind}.r_rows_agree"] = bool(
+                (got[live] == np.asarray(vals[K - 1])[live]).all())
+            r[f"{kind}.distinct_rows"] = int(
+                np.mean([len(np.unique(s[s < N] >> 7)) for s in slots]))
+    del col
 
 
 def _pins(rng, w, kind, collapsed):
@@ -276,11 +324,12 @@ def _pins(rng, w, kind, collapsed):
 
 def probe_steps(rec):
     """us a step, K steps scanned in one program, with each form of the
-    scatter; the state is donated and threaded through."""
+    write; the state is donated and threaded through."""
     rng = np.random.default_rng(26)
     state = bk.make_state(N)
-    shipped = bk._SCATTER_PASS_ROWS_PER_LANE
+    shipped = bk._scatter_form
     for name, core, w, collapsed in (
+        ("collapsed_w4096", bk._collapsed_step_core, 4096, True),
         ("collapsed_w1024", bk._collapsed_step_core, 1024, True),
         ("uniform_w64", bk._uniform_step_core, 64, False),
     ):
@@ -288,8 +337,8 @@ def probe_steps(rec):
         # served shape (uniform slots read 15 % more in the first run)
         for kind in ("skewed",):
             pins = _pins(rng, w, kind, collapsed)
-            for form, per_lane in (("loop", 0), ("pass", 1 << 40)):
-                bk._SCATTER_PASS_ROWS_PER_LANE = per_lane
+            for form in ("rows", "loop", "pass"):
+                bk._scatter_form = lambda *a, form=form: form
                 try:
                     # (a fresh body each time: scan caches a body's
                     # trace by the function, form and all)
@@ -298,19 +347,25 @@ def probe_steps(rec):
                             lambda s, p: core(s, p), st, pins),
                         donate_argnums=(0,),
                     )
-                    hinted = len(re.findall(
-                        r"stablehlo\.scatter[^\n]*unique_indices = true",
-                        f.lower(state, pins).as_text()))
-                    assert hinted == (12 if form == "pass" else 0), hinted
+                    text = f.lower(state, pins).as_text()
+                    custom = "tpu_custom_call" in text
+                    if form == "rows":  # (a rehearsal lowers the loop)
+                        assert custom or _REHEARSE
+                    else:
+                        hinted = len(re.findall(
+                            r"stablehlo\.scatter[^\n]*unique_indices = true",
+                            text))
+                        assert not custom
+                        assert hinted == (12 if form == "pass" else 0), hinted
                     dt, out = _timed(f, state, pins)
                 finally:
-                    bk._SCATTER_PASS_ROWS_PER_LANE = shipped
+                    bk._scatter_form = shipped
                 state = out[0]
                 rec.setdefault("step_us", {})[
                     f"{name}.{kind}.{form}"] = dt / K * 1e6
     rec["step_form_shipped"] = {
-        f"w{w}": "pass" if all(bk._scatter_hints(N, w).values()) else "loop"
-        for w in WIDTHS
+        f"w{w}": bk._scatter_form(N, w, jax.devices()[0].platform)
+        for w in (64, 1024, 4096)
     }
     del state
 
@@ -335,12 +390,19 @@ def read_compiled(rec):
     scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
     rec["compiled_collapsed_step"] = {
         "table_ops": ops,
+        "row_write": "tpu_custom_call" in text or "row_write" in text,
         "column_copied": any(k.startswith("copy") for k in ops),
         "scatters_hinted": sum(
             "indices_are_sorted=true" in ln or "unique_indices=true" in ln
             for ln in scatters),
         "scatters": len(scatters),
         "temp_bytes": int(compiled.memory_analysis().temp_size_in_bytes),
+    }
+    # the in-place probes the engines start on, at this table
+    rec["probes"] = {
+        name: probe(N)._asdict()
+        for name, probe in (("fused_step", bk.fused_step_ok),
+                            ("multi_step", bk.multi_step_ok))
     }
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/collapsed_step_100m.hlo.txt", "w") as fh:
@@ -360,7 +422,8 @@ def main() -> int:
     # PROBE_PARTS=probe_steps runs the named parts alone (a second look
     # at the steps costs no second pass over the accesses).
     only = os.environ.get("PROBE_PARTS", "").split(",")
-    for part in (read_compiled, probe_accesses, probe_crossover, probe_steps):
+    for part in (read_compiled, probe_accesses, probe_crossover,
+                 probe_row_write, probe_steps):
         if only != [""] and part.__name__ not in only:
             continue
         try:

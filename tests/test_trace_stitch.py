@@ -462,9 +462,12 @@ def test_debug_endpoints_serve_live_data(tracer, monkeypatch):
         assert dev["engine"] == "DecisionEngine" and dev["rows"] == 1024
         assert dev["pump"] is True
         assert dev["pump_scan"] is False  # singles on the CPU backend
-        assert set(dev["probes"]) == {"fused_step", "multi_step"}
+        assert set(dev["probes"]) == {"fused_step", "multi_step", "write_form"}
         assert all(v["ok"] and v["reason"] for v in dev["probes"].values())
         assert dev["counters"]["dispatches_total"] >= 1
+        # a 1,024-row table: the hinted pass at every width, no row write
+        assert dev["probes"]["write_form"]["reason"].startswith("64:pass ")
+        assert dev["counters"]["row_write_dispatches_total"] == 0
         assert dev["compiles"]["backend_compiles"] >= 1
         # PR 13/14 planes (ISSUE 15 satellite): the replication and
         # multiregion managers' stats in the one-stop snapshot —
